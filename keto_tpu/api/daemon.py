@@ -291,8 +291,8 @@ class Daemon:
 
     def _make_batcher(self, pending_total=None, drain_ways: int = 1):
         # pipeline depth bounds launched-but-unresolved device batches
-        # (in-flight cap = 2x depth); raise it for remote/tunneled TPUs
-        # where the device round-trip dwarfs per-batch compute
+        # (in-flight cap = 2x depth); raise it where the launch-to-
+        # readback latency dwarfs per-batch compute
         registry = self.registry
         cfg = registry.config
         return CheckBatcher(

@@ -5,8 +5,8 @@ an empty dict when nothing is armed — nanoseconds on the hot path):
 
   - ``device_launch``   — runs at the top of
     `TPUCheckEngine.check_batch_submit`, BEFORE any state build or
-    kernel launch: `stall` holds the launch thread (a wedged device /
-    TPU tunnel), `error` raises (a dying device). Exercises the
+    kernel launch: `stall` holds the launch thread (a wedged
+    device), `error` raises (a dying device). Exercises the
     caller-side deadline, the launch watchdog, and the circuit breaker.
   - ``store_read``      — runs in every store's `get_relation_tuples`
     (memory / sqlite / columnar): `stall` models a slow persistence

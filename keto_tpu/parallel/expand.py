@@ -26,10 +26,8 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map with the check_vma/check_rep compat shim (see parallel/kernel)
-from .kernel import _shard_map
 
 from ..engine.delta import DIRTY_FOR_EXPAND
 from ..engine.expand_kernel import _ExpandState
@@ -243,7 +241,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             final.stats,
         )
 
-    mapped = _shard_map(
+    mapped = shard_map(
         run,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P(), P()),

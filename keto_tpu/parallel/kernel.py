@@ -23,25 +23,8 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-if "check_vma" not in _inspect.signature(_shard_map).parameters:
-    # older jax (e.g. 0.4.x) names the replication check `check_rep`;
-    # call sites use the modern `check_vma` spelling and this shim maps
-    # it down so one codebase runs on both
-    _raw_shard_map = _shard_map
-
-    def _shard_map(f, **kw):  # noqa: F811 - deliberate compat override
-        kw["check_rep"] = kw.pop("check_vma", False)
-        return _raw_shard_map(f, **kw)
-
 
 from ..engine.kernel import (
     Expansion,
@@ -174,7 +157,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
         final = run_bfs_loop(step_fn, init, max_steps, B)
         return finalize(final, max_steps, B)
 
-    mapped = _shard_map(
+    mapped = shard_map(
         run,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P()),

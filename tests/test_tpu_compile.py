@@ -1,0 +1,305 @@
+"""The chip's compiler, asked from a machine that has no chip.
+
+Every device program `chip_smoke.py` launches is compiled here for a
+described (not attached) TPU v5e, at the smoke's own shapes: the store is
+the smoke's 1,000,000-tuple drive store, built on the CPU, and each
+kernel's arguments and statics are captured where the engine passes them.
+What the TPU compiler would refuse on the chip — a program that does not
+fit its memory above all — it refuses here, at no chip time.
+
+The code picks its TPU branches (counted loops, bucketized tables) from
+`jax.default_backend()`, which says `cpu` during such a compile, so the
+`tpu_branches` fixture steers it. Nothing runs on a device: a compile that
+passes is not a chip run.
+
+This is the only file that describes a topology, and it does so inside a
+fixture: see /opt/skills/guides/on-chip-measurement section 2 for why no
+import, `skipif`, `parametrize` or conftest hook may.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke  # also puts tools/ on sys.path
+import tpu_test_tier
+from keto_tpu.engine import (
+    closure_kernel,
+    closure_power,
+    expand_kernel,
+    filter_kernel,
+    kernel,
+    reverse_kernel,
+    snapshot,
+)
+from keto_tpu.engine.tpu_engine import TPUCheckEngine
+from keto_tpu.ketoapi import RelationTuple, SubjectSet
+from keto_tpu.parallel import default_mesh
+from keto_tpu.parallel import expand as parallel_expand
+from keto_tpu.parallel import kernel as parallel_kernel
+from keto_tpu.storage.columnar import ColumnarStore
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices[:4]), ("x",))
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but never read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def tpu_branches():
+    """Build and trace as on the chip: bucketized probe tables, counted
+    BFS loops, the scan-based segment map."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(kernel, "tpu_class_backend", lambda: True)
+    mp.setattr(snapshot, "_TABLE_LAYOUT", "bucketized")
+    yield
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def drive():
+    return chip_smoke.build_drive(chip_smoke.DEFAULT_SEED, chip_smoke.DEFAULT_TUPLES)
+
+
+@pytest.fixture(scope="module")
+def store(drive):
+    store = ColumnarStore()
+    store.bulk_load(drive.cols)
+    return store
+
+
+@pytest.fixture(scope="module")
+def engine(tpu_branches, store):
+    return TPUCheckEngine(store, chip_smoke.drive_config(serve=False))
+
+
+class _Launch(Exception):
+    """Ends an engine call at the kernel it was about to launch."""
+
+
+def capture_launch(module, name: str, call) -> tuple[tuple, dict]:
+    """(args, kwargs) of the first call of `module.name` under `call()`:
+    what the engine hands the kernel at its call site. The kernel itself
+    does not run (where the engine catches the abort and answers from the
+    host, as the closure builder does, the call simply returns)."""
+    launches = []
+
+    def record(*args, **kwargs):
+        launches.append((args, kwargs))
+        raise _Launch(name)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(module, name, record)
+    try:
+        call()
+    except _Launch:
+        pass
+    finally:
+        mp.undo()
+    assert launches, f"{name} was never launched"
+    return launches[0]
+
+
+def described(tree, sharding_of):
+    """Shapes in place of arrays: a described device holds no array."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding_of(a)),
+        tree,
+    )
+
+
+def compile_for_chip(name, jitted, args, statics, sharding_of):
+    compiled = jitted.lower(*described(args, sharding_of), **statics).compile()
+    memory = compiled.memory_analysis()
+    print(
+        f"\n{name}: "
+        f"argument={memory.argument_size_in_bytes} "
+        f"temp={memory.temp_size_in_bytes} "
+        f"output={memory.output_size_in_bytes} "
+        f"code={memory.generated_code_size_in_bytes}"
+    )
+    return compiled, memory
+
+
+def smoke_batch(drive):
+    queries, _ = chip_smoke.view_queries(drive, np.random.default_rng(1), 2048)
+    return queries
+
+
+def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
+    """The smoke's largest check launch, its 2,048-item BatchCheck (bucket
+    4,096, frontier 16,384), with tables and working set inside one v5e's
+    16 GiB. Today most of `temp` is the per-launch relayout of dh_pack and
+    rh_pack (ROADMAP S3); the layout fix has this number to beat."""
+    tables_and_queries, statics = capture_launch(
+        kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
+    )
+    tables, qpack = tables_and_queries
+    assert tables["dh_pack"].shape == (1 << 23, 8)
+    assert qpack.shape == (7, 4096) and statics["frontier_cap"] == 16384
+    _, memory = compile_for_chip(
+        "check_kernel_packed", kernel.check_kernel_packed, tables_and_queries,
+        statics, lambda a: one_chip,
+    )
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"argument + temp = {total} of {V5E_HBM_BYTES}")
+    assert total < V5E_HBM_BYTES
+
+
+@pytest.fixture(scope="module")
+def closure_engine(tpu_branches):
+    """The differential tier's closure set (tools/tpu_test_tier.py): a
+    32-deep chain behind the Leopard index, device powering on. The index
+    is off by default, so the served smoke never launches these two
+    kernels; its phase 5 does, at these shapes."""
+    namespaces, tuples, _ = tpu_test_tier.deep_chain(32)
+    return tpu_test_tier.engine_for(
+        namespaces, tuples, max_depth=64,
+        closure={"enabled": True, "powering": "device"},
+    )
+
+
+def _expand(engine, drive):
+    engine.expand(SubjectSet("rbac", "role1", "member"), chip_smoke.EXPAND_DEPTH)
+
+
+def _list_objects(engine, drive):
+    engine.list_objects("videos", "view", str(drive.owners[0]))
+
+
+def _list_subjects(engine, drive):
+    engine.list_subjects("videos", f"{drive.f_names[0]}/v1", "view")
+
+
+def _filter(engine, drive):
+    name = str(drive.f_names[0])
+    candidates = [name] + [f"{name}/v{j}" for j in range(drive.files_per)]
+    engine.filter_objects("videos", "view", str(drive.owners[0]), candidates)
+
+
+def _closure_powering(engine, drive):
+    engine.closure_ensure_built()
+
+
+def _closure_probe(engine, drive):
+    assert engine.closure_ensure_built()
+    engine.check_batch([RelationTuple.from_string("deep:f0#viewer@alice")], 64)
+
+
+@pytest.mark.parametrize(
+    "engine_fixture, module, name, call",
+    [
+        ("engine", expand_kernel, "expand_kernel_packed", _expand),
+        ("engine", reverse_kernel, "list_objects_kernel_packed", _list_objects),
+        ("engine", reverse_kernel, "list_subjects_kernel_packed", _list_subjects),
+        ("engine", filter_kernel, "filter_kernel_packed", _filter),
+        ("closure_engine", closure_power, "closure_power_wave", _closure_powering),
+        ("closure_engine", closure_kernel, "closure_kernel_packed", _closure_probe),
+    ],
+    ids=["expand", "list_objects", "list_subjects", "filter",
+         "closure_powering", "closure_probe"],
+)
+def test_kernel_compiles_for_the_chip(
+    request, no_compile_cache, one_chip, drive, engine_fixture, module, name, call
+):
+    """Every other kernel of the smoke, as its path launches it for one
+    request: the verbs on the 1e6 store, the closure pair on the tier's."""
+    engine = request.getfixturevalue(engine_fixture)
+    args, statics = capture_launch(module, name, lambda: call(engine, drive))
+    _, memory = compile_for_chip(
+        name, getattr(module, name), args, statics, lambda a: one_chip
+    )
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.fixture(scope="module")
+def sharded_engine(tpu_branches, store):
+    return TPUCheckEngine(
+        store, chip_smoke.drive_config(serve=False), mesh=default_mesh(4)
+    )
+
+
+def _sharded_check(engine, drive):
+    engine.check_batch(smoke_batch(drive))
+
+
+def _sharded_expand(engine, drive):
+    roles = [SubjectSet("rbac", f"role{r}", "member") for r in range(16)]
+    engine.expand_batch(roles, chip_smoke.EXPAND_DEPTH)
+
+
+@pytest.mark.parametrize(
+    "module, name, get_kernel, statics_of, call",
+    [
+        (
+            parallel_kernel, "sharded_check_kernel",
+            parallel_kernel.get_sharded_kernel,
+            lambda kw: kw["statics"], _sharded_check,
+        ),
+        (
+            parallel_expand, "sharded_expand_kernel",
+            parallel_expand.get_sharded_expand_kernel,
+            lambda kw: (kw["fh_probes"], kw["max_steps"], kw["frontier_cap"],
+                        kw["edge_cap"]),
+            _sharded_expand,
+        ),
+    ],
+    ids=["check", "expand"],
+)
+def test_sharded_kernel_compiles_for_four_chips(
+    no_compile_cache, four_chips, drive, sharded_engine,
+    module, name, get_kernel, statics_of, call,
+):
+    """The shard_map kernels of `chip_smoke.py --mesh4` on the described
+    2x2 host: the sharded tables split over the mesh axis, everything else
+    replicated, and collectives present in what the compiler emits."""
+    args, kwargs = capture_launch(module, name, lambda: call(sharded_engine, drive))
+    _, sharded_tables, replicated_tables, *queries = args
+    assert all(v.shape[0] == 4 for v in sharded_tables.values())
+
+    def sharding_of(a):
+        split = any(a is v for v in sharded_tables.values())
+        spec = P("x", *([None] * (np.ndim(a) - 1))) if split else P()
+        return NamedSharding(four_chips, spec)
+
+    compiled, memory = compile_for_chip(
+        name, get_kernel(four_chips, statics_of(kwargs)),
+        (sharded_tables, replicated_tables, *queries), {}, sharding_of,
+    )
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert "all-reduce" in hlo or "all-gather" in hlo

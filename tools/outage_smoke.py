@@ -674,7 +674,7 @@ def run_ab(record: dict, windows: int = 30, per_window: int = 60) -> list[str]:
     """The healthy-path A/B, two backend arms:
 
     - memory (the bench's standard served check leg, the backend every
-      committed A/B artifact measures — CACHE_AB_r07 / FLIGHTREC_AB_r08
+      committed A/B artifact measures — FLIGHTREC_AB_r08
       / EXPLAIN_AB_r14): store.health on means the inline guard only
       (breaker check + fault probe, ~3 us/op — dict stores cannot hang,
       so no executor). THE 2% BAR APPLIES HERE.

@@ -9,6 +9,8 @@ host reference engine on sampled queries.
 
     python tools/scale_bench.py [--tuples 10000000] [--platform cpu]
 
+Without `--platform cpu` a run that finds no TPU exits 2.
+
 Prints one JSON line:
   {"tuples", "ingest_s", "snapshot_build_s", "device_table_bytes",
    "check_batch_s", "check_qps", "spot_checks", "spot_failures",
@@ -135,6 +137,7 @@ def main() -> int:
     )
     args = ap.parse_args()
     if args.platform == "cpu":
+        # the caller's explicit choice; nothing else selects the CPU
         os.environ["JAX_PLATFORMS"] = "cpu"
         if args.mesh:
             flags = os.environ.get("XLA_FLAGS", "")
@@ -146,8 +149,14 @@ def main() -> int:
 
     import jax
 
-    if args.platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    found = jax.devices()[0].platform
+    if args.platform != "cpu" and found != "tpu":
+        print(
+            f"scale_bench: no TPU (jax.devices() reports {found!r}); "
+            "pass --platform cpu to run on the CPU backend by choice",
+            file=sys.stderr,
+        )
+        return 2
 
     from keto_tpu.config import Config
     from keto_tpu.engine import Membership

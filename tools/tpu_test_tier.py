@@ -1,20 +1,21 @@
-"""TPU-hardware correctness tier (VERDICT round-1 item 2).
+"""Differential correctness tier for the attached TPU.
 
-Runs the differential fixture sets on the REAL attached backend — the
-same code paths the CPU suite exercises, now with actual TPU
-compilation/execution semantics: cat-videos, deep-chain-32, the AND/NOT
-island fixtures, and a randomized differential sweep, each compared
-against the exact host reference engine.
+Runs the differential fixture sets on the chip: the same code paths the
+CPU suite exercises, now with the TPU compiler's and the device's
+semantics. The sets are cat-videos, deep-chain-32 (through the BFS kernel
+and again through the device-built closure index), the AND/NOT island
+fixtures, a randomized differential sweep and an expand differential,
+each compared against the exact host reference engine.
 
-Invoked by tests/test_tpu_hardware.py (pytest marker `tpu`, subprocess
-so a wedged backend can time out without hanging the suite) and runnable
-standalone on the bench machine:
+`chip_smoke.py` runs `main()` as its phase 5, in the process that holds
+the chip. Standalone, on a machine with a chip:
 
     python tools/tpu_test_tier.py
 
 Prints one JSON line per fixture set plus a final summary line
 {"tier": "tpu", "device", "sets", "cases", "failures"}; exit 0 iff
-failures == 0 AND the device is a real TPU.
+failures == 0. Anything but a TPU is refused before any work: only
+chip_smoke.py's CPU rehearsal calls `main(require_tpu=False)`.
 """
 
 from __future__ import annotations
@@ -30,17 +31,75 @@ sys.path.insert(0, os.path.join(
 ))
 
 
-def main() -> int:
+# the upstream cat-videos example (contrib/cat-videos-example)
+CAT_VIDEOS_TUPLES = [
+    "videos:/cats/1.mp4#owner@(videos:/cats#owner)",
+    "videos:/cats/1.mp4#view@(videos:/cats/1.mp4#owner)",
+    "videos:/cats/1.mp4#view@*",
+    "videos:/cats/2.mp4#owner@(videos:/cats#owner)",
+    "videos:/cats/2.mp4#view@(videos:/cats/2.mp4#owner)",
+    "videos:/cats#owner@cat lady",
+    "videos:/cats#view@(videos:/cats#owner)",
+]
+
+
+def engine_for(namespaces, tuples, max_depth=5, **planes):
+    """A TPUCheckEngine over a fresh in-memory store holding `tuples`;
+    `planes` are further config sections (closure=...)."""
+    from keto_tpu.config import Config
+    from keto_tpu.engine.tpu_engine import TPUCheckEngine
+    from keto_tpu.ketoapi import RelationTuple
+    from keto_tpu.storage import MemoryManager
+
+    cfg = Config({"limit": {"max_read_depth": max_depth}, **planes})
+    cfg.set_namespaces(namespaces)
+    m = MemoryManager()
+    m.write_relation_tuples([RelationTuple.from_string(s) for s in tuples])
+    return TPUCheckEngine(m, cfg)
+
+
+def deep_chain(depth: int):
+    """(namespaces, tuples, cases) of one parent chain of `depth` hops with
+    an owner at its end (the bench_test.go:56-86 topology)."""
+    from keto_tpu.namespace import Namespace
+    from keto_tpu.namespace.ast import (
+        ComputedSubjectSet,
+        Relation,
+        SubjectSetRewrite,
+        TupleToSubjectSet,
+    )
+
+    namespaces = [Namespace(name="deep", relations=[
+        Relation(name="owner"),
+        Relation(name="parent"),
+        Relation(name="viewer", subject_set_rewrite=SubjectSetRewrite(children=[
+            ComputedSubjectSet(relation="owner"),
+            TupleToSubjectSet(relation="parent",
+                              computed_subject_set_relation="viewer"),
+        ])),
+    ])]
+    tuples = [
+        f"deep:f{i}#parent@(deep:f{i + 1}#...)" for i in range(depth)
+    ] + [f"deep:f{depth}#owner@alice"]
+    cases = [
+        ("deep:f0#viewer@alice", True),
+        ("deep:f0#viewer@bob", False),
+        (f"deep:f{depth}#owner@alice", True),
+    ]
+    return namespaces, tuples, cases
+
+
+def main(require_tpu: bool = True) -> int:
     import jax
 
     device = jax.devices()[0]
-    if device.platform == "cpu":
-        print(json.dumps({"tier": "tpu", "error": "no TPU (resolved to cpu)"}))
+    if require_tpu and device.platform != "tpu":
+        print(json.dumps({
+            "tier": "tpu", "error": f"no TPU (found {device.platform})",
+        }))
         return 2
 
-    from keto_tpu.config import Config
     from keto_tpu.engine import Membership
-    from keto_tpu.engine.tpu_engine import TPUCheckEngine
     from keto_tpu.ketoapi import RelationTuple
     from keto_tpu.namespace import Namespace
     from keto_tpu.namespace.ast import (
@@ -49,18 +108,10 @@ def main() -> int:
         SubjectSetRewrite,
         TupleToSubjectSet,
     )
-    from keto_tpu.storage import MemoryManager
 
     total_cases = 0
     total_failures = 0
     sets = 0
-
-    def engine_for(namespaces, tuples, max_depth=5):
-        cfg = Config({"limit": {"max_read_depth": max_depth}})
-        cfg.set_namespaces(namespaces)
-        m = MemoryManager()
-        m.write_relation_tuples([RelationTuple.from_string(s) for s in tuples])
-        return TPUCheckEngine(m, cfg)
 
     def report(name, cases, failures, extra=None):
         nonlocal total_cases, total_failures, sets
@@ -72,16 +123,7 @@ def main() -> int:
         print(json.dumps(line), flush=True)
 
     # ---- cat-videos (the reference's own example fixture) ----------------
-    import glob
-
-    tuples = []
-    for f in sorted(glob.glob(
-        "/root/reference/contrib/cat-videos-example/relation-tuples/*.json"
-    )):
-        d = json.load(open(f))
-        d.pop("$schema", None)
-        tuples.append(str(RelationTuple.from_dict(d)))
-    e = engine_for([Namespace(name="videos")], tuples)
+    e = engine_for([Namespace(name="videos")], CAT_VIDEOS_TUPLES)
     queries = [
         "videos:/cats/1.mp4#view@*",
         "videos:/cats/1.mp4#view@cat lady",
@@ -99,26 +141,9 @@ def main() -> int:
     report("cat-videos", len(rts), fails, {"host_checks": e.stats["host_checks"]})
 
     # ---- deep chain, depth 32 (bench_test.go:56-86 topology) -------------
-    namespaces = [Namespace(name="deep", relations=[
-        Relation(name="owner"),
-        Relation(name="parent"),
-        Relation(name="viewer", subject_set_rewrite=SubjectSetRewrite(children=[
-            ComputedSubjectSet(relation="owner"),
-            TupleToSubjectSet(relation="parent",
-                              computed_subject_set_relation="viewer"),
-        ])),
-    ])]
     depth = 32
-    tuples = ["deep:f0#parent@(deep:f1#...)"]
-    for i in range(1, depth):
-        tuples.append(f"deep:f{i}#parent@(deep:f{i + 1}#...)")
-    tuples.append(f"deep:f{depth}#owner@alice")
+    namespaces, tuples, cases = deep_chain(depth)
     e = engine_for(namespaces, tuples, max_depth=2 * depth)
-    cases = [
-        ("deep:f0#viewer@alice", True),
-        ("deep:f0#viewer@bob", False),
-        (f"deep:f{depth}#owner@alice", True),
-    ]
     got = e.check_batch(
         [RelationTuple.from_string(c) for c, _ in cases], 2 * depth
     )
@@ -129,6 +154,37 @@ def main() -> int:
     )
     report("deep-chain-32", len(cases), fails,
            {"host_checks": e.stats["host_checks"]})
+
+    # ---- the same chain through the Leopard closure index ----------------
+    # built by the device powering kernel and answered by the closure
+    # probe: the builder falls back to the host builder when the device
+    # fails (engine/closure.py), so a fallback or a batch the probe did
+    # not answer counts as a failure beside the verdicts
+    e = engine_for(
+        namespaces, tuples, max_depth=2 * depth,
+        closure={"enabled": True, "powering": "device"},
+    )
+    built = e.closure_ensure_built()
+    got = e.check_batch(
+        [RelationTuple.from_string(c) for c, _ in cases], 2 * depth
+    )
+    fails = sum(
+        1
+        for (c, want), g in zip(cases, got)
+        if (g.membership == Membership.IS_MEMBER) != want
+    )
+    index = e.closure_index().stats
+    on_device = (
+        built and index["device_builds"] > 0
+        and index["device_fallbacks"] == 0
+        and e.stats.get("closure_hits", 0) == len(cases)
+    )
+    report("closure-deep-chain-32", len(cases), fails + (not on_device), {
+        "device_builds": index["device_builds"],
+        "device_fallbacks": index["device_fallbacks"],
+        "power_steps": index["power_steps"],
+        "closure_hits": e.stats.get("closure_hits", 0),
+    })
 
     # ---- AND/NOT islands (ported rewrites_test fixtures) -----------------
     from test_reference_engine import (
