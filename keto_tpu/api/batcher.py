@@ -55,6 +55,9 @@ def note_queue_wait(riders, queue_size: int, metrics, tracer, depth_gauge) -> No
         n += 1
         if rt is not None:
             rt.add_stage("queue", w)
+            # the device-feed account (engine/device_feed.py) charges a
+            # device that stood empty meanwhile to `starved_queue`
+            rt.enqueued = enq_t
             if spans:
                 tracer.record("batcher.queue", ctx=rt.ctx, duration_s=w)
     if metrics is not None and n:

@@ -328,46 +328,51 @@ class _Services:
         from ..engine.snaptoken import encode_snaptoken
         from ..resilience import admit_check
 
-        # draining/expired gate (no queue bound: the batch rides one
-        # direct engine launch, not the batcher queue)
-        admit_check(self.registry, None, current_request_trace())
-        nid = self._nid(context)
-        version = self._enforce_snaptoken(req.snaptoken, nid)
-        idx: list[int] = []
-        tuples: list[RelationTuple] = []
-        out = [None] * len(req.tuples)
-        for i, pt in enumerate(req.tuples):
-            sub = subject_from_proto(pt.subject)
-            if sub is None:
-                out[i] = pb.BatchCheckResult(
-                    allowed=False, error="subject is not allowed to be nil"
-                )
-                continue
-            t = RelationTuple.make(pt.namespace, pt.object, pt.relation, sub)
-            try:
-                # same per-tuple namespace semantics as the single-check
-                # gRPC plane (an ERROR, not a silent deny) — but scoped
-                # to the item
-                self.registry.validate_namespaces(t)
-            except KetoError as e:
-                out[i] = pb.BatchCheckResult(allowed=False, error=e.message)
-                continue
-            idx.append(i)
-            tuples.append(t)
-        engine = self.registry.check_engine(nid)
+        rt = current_request_trace()
+        with self.metrics.stage("decode", rt):
+            # draining/expired gate (no queue bound: the batch rides one
+            # direct engine launch, not the batcher queue)
+            admit_check(self.registry, None, rt)
+            nid = self._nid(context)
+            version = self._enforce_snaptoken(req.snaptoken, nid)
+            idx: list[int] = []
+            tuples: list[RelationTuple] = []
+            out = [None] * len(req.tuples)
+            for i, pt in enumerate(req.tuples):
+                sub = subject_from_proto(pt.subject)
+                if sub is None:
+                    out[i] = pb.BatchCheckResult(
+                        allowed=False, error="subject is not allowed to be nil"
+                    )
+                    continue
+                t = RelationTuple.make(pt.namespace, pt.object, pt.relation, sub)
+                try:
+                    # same per-tuple namespace semantics as the
+                    # single-check gRPC plane (an ERROR, not a silent
+                    # deny) — but scoped to the item
+                    self.registry.validate_namespaces(t)
+                except KetoError as e:
+                    out[i] = pb.BatchCheckResult(allowed=False, error=e.message)
+                    continue
+                idx.append(i)
+                tuples.append(t)
+            engine = self.registry.check_engine(nid)
+        # the engine adds assemble / dispatch / device_wait / resolve to
+        # this RPC's trace itself (check_batch reads the contextvar)
         results = engine.check_batch(tuples, int(req.max_depth))
-        obs = self.registry.workload_observatory()
-        for pos, (i, r) in enumerate(zip(idx, results)):
-            if r.error is not None:
-                out[i] = pb.BatchCheckResult(allowed=False, error=str(r.error))
-            else:
-                out[i] = pb.BatchCheckResult(allowed=r.allowed)
-                if obs is not None:
-                    # per-item workload accounting (the batch bypasses
-                    # the single-check serve gate; no per-item tier)
-                    obs.record_check(nid, tuples[pos], r.allowed)
-        resp = pb.BatchCheckResponse(snaptoken=encode_snaptoken(version, nid))
-        resp.results.extend(out)
+        with self.metrics.stage("respond", rt):
+            obs = self.registry.workload_observatory()
+            for pos, (i, r) in enumerate(zip(idx, results)):
+                if r.error is not None:
+                    out[i] = pb.BatchCheckResult(allowed=False, error=str(r.error))
+                else:
+                    out[i] = pb.BatchCheckResult(allowed=r.allowed)
+                    if obs is not None:
+                        # per-item workload accounting (the batch bypasses
+                        # the single-check serve gate; no per-item tier)
+                        obs.record_check(nid, tuples[pos], r.allowed)
+            resp = pb.BatchCheckResponse(snaptoken=encode_snaptoken(version, nid))
+            resp.results.extend(out)
         return resp
 
     # -- ExpandService --------------------------------------------------------

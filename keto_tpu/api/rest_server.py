@@ -598,75 +598,84 @@ class _Handler(BaseHTTPRequestHandler):
         subject, unknown names via host replay) never fail the batch."""
         from ..resilience import admit_check
 
+        metrics = self.registry.metrics()
         # draining/expired gate (no queue bound: the batch rides one
         # direct engine launch, not the batcher queue)
-        admit_check(self.registry, None, self._ingest_deadline())
-        params = self._params()
-        body = self._body_json()
-        if isinstance(body, dict):
-            raw = body.get("tuples")
-            raw_depth = body.get("max_depth")
-            if raw_depth is None:
-                # ABSENCE, not falsiness: an explicit JSON max_depth of 0
-                # must override a non-zero ?max-depth query param
+        rt = self._ingest_deadline()
+        with metrics.stage("decode", rt):
+            admit_check(self.registry, None, rt)
+            params = self._params()
+            body = self._body_json()
+            if isinstance(body, dict):
+                raw = body.get("tuples")
+                raw_depth = body.get("max_depth")
+                if raw_depth is None:
+                    # ABSENCE, not falsiness: an explicit JSON max_depth
+                    # of 0 must override a non-zero ?max-depth query param
+                    max_depth = _get_max_depth(params)
+                else:
+                    try:
+                        max_depth = int(raw_depth)
+                    except (TypeError, ValueError):
+                        raise MalformedInputError(
+                            "max_depth must be an integer"
+                        )
+            else:
+                raw = body
                 max_depth = _get_max_depth(params)
-            else:
-                try:
-                    max_depth = int(raw_depth)
-                except (TypeError, ValueError):
-                    raise MalformedInputError("max_depth must be an integer")
-        else:
-            raw = body
-            max_depth = _get_max_depth(params)
-        if not isinstance(raw, list):
-            raise MalformedInputError(
-                "could not unmarshal json: expected array of relation tuples"
-            )
-        from ..engine.snaptoken import encode_snaptoken
+            if not isinstance(raw, list):
+                raise MalformedInputError(
+                    "could not unmarshal json: expected array of relation tuples"
+                )
+            from ..engine.snaptoken import encode_snaptoken
 
-        nid = self._nid()
-        req_token = params.get("snaptoken", "")
-        if isinstance(body, dict):
-            req_token = body.get("snaptoken") or req_token
-        version = self._enforce_snaptoken(req_token, nid)
-        idx: list[int] = []
-        tuples: list[RelationTuple] = []
-        out: list[dict] = [None] * len(raw)  # type: ignore[list-item]
-        for i, d in enumerate(raw):
-            try:
-                if not isinstance(d, dict):
-                    raise MalformedInputError(
-                        "could not unmarshal json: expected object"
-                    )
-                t = RelationTuple.from_dict(d)
-                # unlike the single-check REST route (which swallows
-                # unknown namespaces to allowed=false for parity), the
-                # batch extension reports them per item — strictly more
-                # information, and consistent with the gRPC batch plane
-                self.registry.validate_namespaces(t)
-            except KetoError as e:
-                out[i] = {"allowed": False, "error": e.message}
-                continue
-            idx.append(i)
-            tuples.append(t)
-        engine = self.registry.check_engine(nid)
-        obs = self.registry.workload_observatory()
-        for pos, (i, res) in enumerate(
-            zip(idx, engine.check_batch(tuples, max_depth))
-        ):
-            if res.error is not None:
-                out[i] = {"allowed": False, "error": str(res.error)}
-            else:
-                out[i] = {"allowed": res.allowed}
-                if obs is not None:
-                    # per-item workload accounting (the batch bypasses
-                    # the single-check serve gate); the whole batch rode
-                    # one launch, so no per-item tier stamp exists here
-                    obs.record_check(nid, tuples[pos], res.allowed)
-        self._json(
-            200,
-            {"results": out, "snaptoken": encode_snaptoken(version, nid)},
-        )
+            nid = self._nid()
+            req_token = params.get("snaptoken", "")
+            if isinstance(body, dict):
+                req_token = body.get("snaptoken") or req_token
+            version = self._enforce_snaptoken(req_token, nid)
+            idx: list[int] = []
+            tuples: list[RelationTuple] = []
+            out: list[dict] = [None] * len(raw)  # type: ignore[list-item]
+            for i, d in enumerate(raw):
+                try:
+                    if not isinstance(d, dict):
+                        raise MalformedInputError(
+                            "could not unmarshal json: expected object"
+                        )
+                    t = RelationTuple.from_dict(d)
+                    # unlike the single-check REST route (which swallows
+                    # unknown namespaces to allowed=false for parity), the
+                    # batch extension reports them per item — strictly
+                    # more information, and consistent with the gRPC
+                    # batch plane
+                    self.registry.validate_namespaces(t)
+                except KetoError as e:
+                    out[i] = {"allowed": False, "error": e.message}
+                    continue
+                idx.append(i)
+                tuples.append(t)
+            engine = self.registry.check_engine(nid)
+        # the engine adds assemble / dispatch / device_wait / resolve to
+        # this request's trace itself (check_batch reads the contextvar)
+        results = engine.check_batch(tuples, max_depth)
+        with metrics.stage("respond", rt):
+            obs = self.registry.workload_observatory()
+            for pos, (i, res) in enumerate(zip(idx, results)):
+                if res.error is not None:
+                    out[i] = {"allowed": False, "error": str(res.error)}
+                else:
+                    out[i] = {"allowed": res.allowed}
+                    if obs is not None:
+                        # per-item workload accounting (the batch bypasses
+                        # the single-check serve gate); the whole batch
+                        # rode one launch, so no per-item tier stamp
+                        # exists here
+                        obs.record_check(nid, tuples[pos], res.allowed)
+            self._json(
+                200,
+                {"results": out, "snaptoken": encode_snaptoken(version, nid)},
+            )
 
     def _expand(self) -> None:
         """ref: expand/handler.go:43-107 (GET, subject-set params)."""

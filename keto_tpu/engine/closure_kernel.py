@@ -150,6 +150,7 @@ _CLOSURE_STATICS = ("cc_probes", "ch_probes", "has_dirty")
 
 
 @functools.partial(jax.jit, static_argnames=_CLOSURE_STATICS)
+@jax.named_scope("keto.closure")
 def closure_kernel_packed(
     tables: dict,
     qpack: jnp.ndarray,

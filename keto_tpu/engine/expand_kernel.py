@@ -154,6 +154,7 @@ class _ExpandState(NamedTuple):
     jax.jit,
     static_argnames=("fh_probes", "max_steps", "frontier_cap", "edge_cap"),
 )
+@jax.named_scope("keto.expand")
 def expand_kernel(
     tables: dict,
     q_obj: jnp.ndarray,  # [B]
@@ -351,6 +352,7 @@ def expand_kernel(
         "fh_probes", "max_steps", "frontier_cap", "edge_cap", "pool_cap"
     ),
 )
+@jax.named_scope("keto.expand")
 def expand_kernel_packed(
     tables: dict,
     qpack: jnp.ndarray,  # [4, B] int32: obj, rel, depth, valid

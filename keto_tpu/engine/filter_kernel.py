@@ -356,6 +356,7 @@ def _filter_impl(
 
 
 @functools.partial(jax.jit, static_argnames=_FILTER_STATICS)
+@jax.named_scope("keto.filter")
 def filter_kernel_packed(
     tables: dict,
     qcpack: jnp.ndarray,  # [5 + C] int32: sa, tag, rel, depth, n_cand, cand

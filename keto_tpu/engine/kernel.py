@@ -224,6 +224,7 @@ def _isolate(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+@jax.named_scope("keto.bucket_rows")
 def _bucket_rows(pack: jnp.ndarray, h1: jnp.ndarray, h2: jnp.ndarray,
                  probes: int, spb: int) -> jnp.ndarray:
     """Gather every table row a probe chain of `probes` slots can touch,
@@ -462,6 +463,7 @@ def program_lookup(tables, obj, rel, live, *, n_config_rels: int):
     return ns, has_prog, pid, flags
 
 
+@jax.named_scope("keto.flag")
 def flag_phase(
     tables, obj, rel, live, *, n_config_rels: int, island_is_host: bool = False,
     prog=None,
@@ -491,6 +493,7 @@ def flag_phase(
     return jnp.where(live, code, 0).astype(jnp.int32)
 
 
+@jax.named_scope("keto.probe")
 def probe_phase(
     tables, obj, rel, skind, sa, sb, depth, live, *,
     dh_probes: int, has_delta: bool = True,
@@ -517,6 +520,7 @@ def probe_phase(
     return main_hit & live & (depth >= 1)
 
 
+@jax.named_scope("keto.expand")
 def expand_phase(
     tables,
     q,
@@ -759,6 +763,7 @@ def expand_phase(
     )
 
 
+@jax.named_scope("keto.dedupe")
 def dedupe_phase(
     children: Expansion, F: int, n_queries: int
 ) -> tuple[
@@ -854,6 +859,7 @@ def dedupe_phase(
     return nt_q, nt_ctx, nt_obj, nt_rel, nt_depth, n_new, overflow_q
 
 
+@jax.named_scope("keto.seed")
 def seed_state(
     q_obj, q_rel, q_depth, q_valid, frontier_cap: int, n_island_cap: int = 0,
     K: int = 1,
@@ -952,6 +958,7 @@ def run_bfs_loop(step_fn, init, max_steps: int, n_queries: int):
     return bounded_loop(loop_cond(max_steps, n_queries), step_fn, init, max_steps)
 
 
+@jax.named_scope("keto.finalize")
 def finalize(
     final: _State, max_steps: int, n_queries: int
 ) -> tuple[
@@ -979,6 +986,7 @@ def finalize(
     )
 
 
+@jax.named_scope("keto.check")
 def _check_kernel_impl(
     tables: dict,
     q_obj: jnp.ndarray,  # [B] seed object slots

@@ -562,6 +562,7 @@ def _list_objects_impl(
 @functools.partial(
     jax.jit, static_argnames=_REVERSE_STATICS + ("pool_cap",)
 )
+@jax.named_scope("keto.reverse")
 def list_objects_kernel_packed(
     tables: dict,
     qpack: jnp.ndarray,  # [6, B] int32: sa, tag, ns, rel, depth, valid
@@ -869,6 +870,7 @@ def _list_subjects_impl(
 @functools.partial(
     jax.jit, static_argnames=_SUBJECTS_STATICS + ("pool_cap",)
 )
+@jax.named_scope("keto.reverse")
 def list_subjects_kernel_packed(
     tables: dict,
     qpack: jnp.ndarray,  # [4, B] int32: obj, rel, depth, valid

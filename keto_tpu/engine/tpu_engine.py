@@ -26,6 +26,7 @@ micro-batcher feeds.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -45,7 +46,8 @@ from .definitions import (
     Membership,
     paginate_names,
 )
-from ..observability import next_launch_id
+from ..observability import StageSpan, next_launch_id
+from .device_feed import DeviceFeed
 from .delta import SnapshotView, empty_delta_tables
 from .kernel import (
     CAUSE_NAME_UNINDEXED,
@@ -196,6 +198,9 @@ class TPUCheckEngine:
         # point; launch ids are allocated process-wide either way so logs
         # and typed errors stay correlatable when recording is off
         self.flightrec = flightrec
+        # what the host was doing whenever no check launch was queued
+        # (keto_tpu_device_feed_seconds_total, launch_device_seconds)
+        self.device_feed = DeviceFeed(metrics)
         # Leopard closure index (engine/closure.py): deep checks answered
         # in one probe step when the index covers them. `closure_enabled`
         # is an attribute (not re-read per batch) so the bench's A/B legs
@@ -2310,8 +2315,18 @@ class TPUCheckEngine:
     def check_batch(
         self, tuples: Sequence[RelationTuple], max_depth: int = 0
     ) -> list[CheckResult]:
-        """Batched membership checks (no proof trees)."""
-        return self.check_batch_resolve(self.check_batch_submit(tuples, max_depth))
+        """Batched membership checks (no proof trees). Evaluates ON the
+        calling thread, so a served BatchCheck's RequestTrace rides the
+        ambient contextvar into the launch as its one rider: the engine
+        stages land on the RPC's breakdown as they do for the batcher's
+        riders."""
+        from ..observability import current_request_trace
+
+        return self.check_batch_resolve(
+            self.check_batch_submit(
+                tuples, max_depth, batch_rt=current_request_trace()
+            )
+        )
 
     def check_batch_host(
         self, tuples: Sequence[RelationTuple], max_depth: int = 0
@@ -2426,6 +2441,7 @@ class TPUCheckEngine:
     def check_batch_submit(
         self, tuples: Sequence[RelationTuple], max_depth: int = 0,
         telemetry=None, allow_closure: bool = True, explain_sink=None,
+        batch_rt=None,
     ):
         """Launch the device kernel for one batch WITHOUT synchronizing.
 
@@ -2440,6 +2456,11 @@ class TPUCheckEngine:
         device_wait/host_fallback at resolve) is added to every rider —
         batch-shared stages, attributed identically to each request in
         the batch — and emitted as per-request engine spans when tracing.
+        `batch_rt` is the ONE RequestTrace a whole direct batch belongs
+        to (check_batch on a BatchCheck handler's thread): it receives
+        the same stages once per launch, every slice of a multi-split
+        included; per-tuple fields (tier, the degraded floor) stay the
+        riders' own.
 
         `explain_sink` is an optional per-tuple list the RESOLVE phase
         fills with each query's ANSWERING TIER ({"tier": closure |
@@ -2459,7 +2480,7 @@ class TPUCheckEngine:
         try:
             return self._check_batch_submit_inner(
                 tuples, max_depth, telemetry, launch_id, allow_closure,
-                explain_sink=explain_sink,
+                explain_sink=explain_sink, batch_rt=batch_rt,
             )
         except Exception as e:
             # don't clobber an id a recursive split-slice submit already
@@ -2471,7 +2492,7 @@ class TPUCheckEngine:
     def _check_batch_submit_inner(
         self, tuples: Sequence[RelationTuple], max_depth: int,
         telemetry, launch_id: int, allow_closure: bool = True,
-        explain_sink=None,
+        explain_sink=None, batch_rt=None,
     ):
         n = len(tuples)
         # fault-injection point (keto_tpu/faults.py): a stall here models
@@ -2479,23 +2500,6 @@ class TPUCheckEngine:
         # any state build, so the batcher's watchdog/breaker see exactly
         # what a real launch failure looks like. Disarmed: one dict miss.
         _faults.inject("device_launch")
-        t_submit = time.perf_counter()
-        # store outage: the breaker-open path serves this batch from the
-        # existing mirror + delta overlay at its covered version (the
-        # response snaptoken is the staleness bound); riders pinned to a
-        # newer version are routed to the host-replay path below, where
-        # the dead store answers them with the typed per-item 503
-        state, degraded = self._ensure_state_degraded_ok("check")
-        # marker fault (keto_tpu/faults.py mirror_corrupt): flip one bit
-        # in a device table before this launch — the silent-HBM-fault
-        # stand-in the anti-entropy scrubber (engine/scrub.py) must
-        # detect and auto-repair. Disarmed: one dict miss.
-        corrupt_spec = _faults.get("mirror_corrupt")
-        if corrupt_spec is not None and corrupt_spec.should_fire():
-            self.corrupt_mirror()
-        global_max = self.config.max_read_depth()
-        depth = max_depth if 0 < max_depth <= global_max else global_max
-
         B = next((b for b in self._allowed_buckets if b >= n), None)
         if B is None:
             # split oversized batches along the largest allowed bucket;
@@ -2509,13 +2513,189 @@ class TPUCheckEngine:
                         telemetry=(
                             telemetry[i : i + step] if telemetry else None
                         ),
-                        allow_closure=allow_closure,
+                        allow_closure=allow_closure, batch_rt=batch_rt,
                     )
                     for i in range(0, n, step)
                 ],
                 None,
             )
 
+        with StageSpan("assemble", launch_id) as asm:
+            # store outage: the breaker-open path serves this batch from
+            # the existing mirror + delta overlay at its covered version
+            # (the response snaptoken is the staleness bound); riders
+            # pinned to a newer version are routed to the host-replay
+            # path below, where the dead store answers them with the
+            # typed per-item 503
+            state, degraded = self._ensure_state_degraded_ok("check")
+            # marker fault (keto_tpu/faults.py mirror_corrupt): flip one
+            # bit in a device table before this launch — the
+            # silent-HBM-fault stand-in the anti-entropy scrubber
+            # (engine/scrub.py) must detect and auto-repair. Disarmed:
+            # one dict miss.
+            corrupt_spec = _faults.get("mirror_corrupt")
+            if corrupt_spec is not None and corrupt_spec.should_fire():
+                self.corrupt_mirror()
+            global_max = self.config.max_read_depth()
+            depth = max_depth if 0 < max_depth <= global_max else global_max
+            queries = self._encode_queries(
+                state, tuples, B, depth, degraded, telemetry
+            )
+
+            # Leopard closure fast path: when the index covers this
+            # engine state (same base snapshot, synced through
+            # covered_version), the WHOLE batch rides one single-step
+            # intersection launch first — chain depth stops mattering.
+            # Queries the index cannot answer (uncovered/dirty/invalid)
+            # are re-submitted through the BFS kernel at resolve time
+            # with cause-coded counters; host-side skip causes
+            # (unbuilt/stale/lag) count here, once per query.
+            # allow_closure=False is the resolve-time re-submission
+            # itself.
+            cl_view = None
+            if allow_closure and self.closure_enabled:
+                cl_view, cl_cause = self._closure_gate(state)
+                if cl_view is None and cl_cause is not None:
+                    self._count_closure_fallback(cl_cause, n)
+
+        meta = {
+            "state": state,
+            "tuples": tuples,
+            "n": n,
+            "B": B,
+            "max_depth": max_depth,
+            "q_valid": queries[-1],
+            "telemetry": telemetry,
+            "batch_rt": batch_rt,
+            "explain_sink": explain_sink,
+            # flight-recorder fields, read back at the resolve sync
+            # point together with the device stats vector
+            "launch_id": launch_id,
+            "t_submit": asm.start,
+        }
+        if cl_view is not None:
+            from .closure_kernel import (
+                closure_kernel_packed,
+                estimate_closure_gather_bytes,
+            )
+            from .kernel import pack_queries
+
+            with StageSpan("dispatch", launch_id) as dsp, self.tracer.span(
+                "engine.closure_launch", batch=B
+            ):
+                outputs = closure_kernel_packed(
+                    cl_view.tables,
+                    pack_queries(*queries),
+                    cc_probes=cl_view.cc_probes,
+                    ch_probes=cl_view.ch_probes,
+                    has_dirty=cl_view.has_dirty,
+                )
+            meta.update(
+                kind="closure",
+                step_cap=1,
+                gather_step_bytes=estimate_closure_gather_bytes(
+                    B, cl_view.cc_probes, cl_view.ch_probes,
+                    cl_view.has_dirty,
+                ),
+            )
+            return self._launched("closure", outputs, meta, asm, dsp)
+
+        # per-launch frontier sizing: every BFS step's cost scales with the
+        # frontier length, not the query count, so a small bucket must not
+        # pay the full-size frontier (a 16-query launch at F=16384 costs
+        # the same ~130 ms as a 4096-query one). Small buckets get a
+        # proportional frontier; queries whose exploration outgrows it are
+        # flagged needs_host and replayed exactly — a safe (slower) path.
+        if self.auto_frontier:
+            # 4x headroom over the seed tasks; measured on the serve path
+            # (1-core CPU host): B=16 at F=64 is 0.2 ms/launch vs 1.6 ms
+            # at the old 1024 floor — small-batch serve latency is the
+            # launch cost, so the floor must scale with the bucket
+            launch_cap = min(self.frontier_cap, max(4 * B, 64))
+        else:
+            launch_cap = self.frontier_cap
+
+        # islands: one ctx block of K leaves per instance; cap scales with
+        # the batch so island-heavy workloads don't immediately overflow
+        # to host replay (overflow is safe, just slow)
+        island_cap = 2 * B if state.snapshot.island_circuits else 0
+        n_shards = 1
+        with StageSpan("dispatch", launch_id) as dsp, self.tracer.span(
+            "engine.kernel_launch", batch=B, frontier=launch_cap
+        ):
+            if self.mesh is not None:
+                from ..parallel.kernel import (
+                    sharded_check_kernel,
+                    sharded_static_config,
+                )
+
+                statics = sharded_static_config(
+                    state.sharded, global_max, launch_cap,
+                    n_island_cap=island_cap, has_delta=state.has_delta,
+                )
+                # dict view of the statics tuple for the gather-bytes
+                # estimate (each shard runs the full per-step gather set
+                # over its own tables)
+                cfg = dict(zip(_KERNEL_STATICS, statics))
+                n_shards = int(self.mesh.devices.size)
+                sharded_tables, replicated_tables = state.tables
+                outputs = sharded_check_kernel(
+                    self.mesh, sharded_tables, replicated_tables, *queries,
+                    statics=statics, axis=self.mesh.axis_names[0],
+                )
+            else:
+                from .kernel import check_kernel_packed, pack_queries
+
+                cfg = kernel_static_config(
+                    state.snapshot, global_max, launch_cap,
+                    n_island_cap=island_cap, has_delta=state.has_delta,
+                )
+                # single-buffer I/O: ONE host->device upload (the packed
+                # query array) and ONE device->host readback at resolve,
+                # in place of seven uploads and five readbacks that each
+                # pay a transfer's fixed cost.
+                outputs = check_kernel_packed(
+                    state.tables, pack_queries(*queries), **cfg
+                )
+        # everything past the launch is deferred to resolve: touching the
+        # outputs here would block on the device round-trip
+        meta.update(
+            island_cap=island_cap if self.mesh is None else None,
+            launch_cap=launch_cap,
+            step_cap=int(cfg["max_steps"]),
+            gather_step_bytes=n_shards * estimate_step_gather_bytes(cfg),
+        )
+        return self._launched("batch", outputs, meta, asm, dsp)
+
+    def _launched(self, kind: str, outputs, meta: dict, asm, dsp):
+        """The in-flight handle of a launch just dispatched. The stage
+        seconds so far ride it (resolve adds device_wait / resolve /
+        host_fallback and finalizes attribution), and the device-feed
+        account learns that the launch entered the device queue."""
+        t_done = dsp.start + dsp.seconds
+        meta["stage_s"] = {
+            "assemble": dsp.start - asm.start,
+            "dispatch": dsp.seconds,
+        }
+        # the requests this launch answers: the batcher's riders, or the
+        # one request a direct batch belongs to
+        meta["riders"] = [
+            rt
+            for rt in (meta["telemetry"] or (meta["batch_rt"],))
+            if rt is not None
+        ]
+        meta["feed_token"], meta["starved_s"] = self.device_feed.dispatched(
+            asm.start, dsp.start, t_done, meta["riders"]
+        )
+        return (kind, outputs, meta)
+
+    def _encode_queries(
+        self, state, tuples: Sequence[RelationTuple], B: int, depth: int,
+        degraded: bool, telemetry,
+    ):
+        """One batch's query columns padded to bucket `B`, in
+        pack_queries' order: (q_obj, q_rel, q_depth, q_skind, q_sa, q_sb,
+        q_valid)."""
         q_depth = np.full(B, depth, dtype=np.int32)
         if isinstance(state.snapshot.obj_slots, ArrayMap) or B > 4096:
             # vectorized batch encoding for big (ArrayMap) vocabs at any
@@ -2568,162 +2748,7 @@ class TPUCheckEngine:
                 mv = getattr(rt, "min_version", None)
                 if mv is not None and mv > covered:
                     q_valid[i] = False
-
-        # Leopard closure fast path: when the index covers this engine
-        # state (same base snapshot, synced through covered_version), the
-        # WHOLE batch rides one single-step intersection launch first —
-        # chain depth stops mattering. Queries the index cannot answer
-        # (uncovered/dirty/invalid) are re-submitted through the BFS
-        # kernel at resolve time with cause-coded counters; host-side
-        # skip causes (unbuilt/stale/lag) count here, once per query.
-        # allow_closure=False is the resolve-time re-submission itself.
-        if allow_closure and self.closure_enabled:
-            cl_view, cl_cause = self._closure_gate(state)
-            if cl_view is not None:
-                from .closure_kernel import (
-                    closure_kernel_packed,
-                    estimate_closure_gather_bytes,
-                )
-                from .kernel import pack_queries
-
-                t_launch = time.perf_counter()
-                with self.tracer.span("engine.closure_launch", batch=B):
-                    outputs = closure_kernel_packed(
-                        cl_view.tables,
-                        pack_queries(
-                            q_obj, q_rel, q_depth, q_skind, q_sa, q_sb,
-                            q_valid,
-                        ),
-                        cc_probes=cl_view.cc_probes,
-                        ch_probes=cl_view.ch_probes,
-                        has_dirty=cl_view.has_dirty,
-                    )
-                t_done = time.perf_counter()
-                return (
-                    "closure",
-                    outputs,
-                    {
-                        "state": state,
-                        "tuples": tuples,
-                        "n": n,
-                        "B": B,
-                        "max_depth": max_depth,
-                        "q_valid": q_valid,
-                        "stage_s": {
-                            "assemble": t_launch - t_submit,
-                            "dispatch": t_done - t_launch,
-                        },
-                        "telemetry": telemetry,
-                        "explain_sink": explain_sink,
-                        "launch_id": launch_id,
-                        "t_submit": t_submit,
-                        "kind": "closure",
-                        "step_cap": 1,
-                        "gather_step_bytes": estimate_closure_gather_bytes(
-                            B, cl_view.cc_probes, cl_view.ch_probes,
-                            cl_view.has_dirty,
-                        ),
-                    },
-                )
-            if cl_cause is not None:
-                self._count_closure_fallback(cl_cause, n)
-
-        # per-launch frontier sizing: every BFS step's cost scales with the
-        # frontier length, not the query count, so a small bucket must not
-        # pay the full-size frontier (a 16-query launch at F=16384 costs
-        # the same ~130 ms as a 4096-query one). Small buckets get a
-        # proportional frontier; queries whose exploration outgrows it are
-        # flagged needs_host and replayed exactly — a safe (slower) path.
-        if self.auto_frontier:
-            # 4x headroom over the seed tasks; measured on the serve path
-            # (1-core CPU host): B=16 at F=64 is 0.2 ms/launch vs 1.6 ms
-            # at the old 1024 floor — small-batch serve latency is the
-            # launch cost, so the floor must scale with the bucket
-            launch_cap = min(self.frontier_cap, max(4 * B, 64))
-        else:
-            launch_cap = self.frontier_cap
-
-        # islands: one ctx block of K leaves per instance; cap scales with
-        # the batch so island-heavy workloads don't immediately overflow
-        # to host replay (overflow is safe, just slow)
-        island_cap = 2 * B if state.snapshot.island_circuits else 0
-        t_launch = time.perf_counter()
-        n_shards = 1
-        with self.tracer.span(
-            "engine.kernel_launch", batch=B, frontier=launch_cap
-        ):
-            if self.mesh is not None:
-                from ..parallel.kernel import (
-                    sharded_check_kernel,
-                    sharded_static_config,
-                )
-
-                statics = sharded_static_config(
-                    state.sharded, global_max, launch_cap,
-                    n_island_cap=island_cap, has_delta=state.has_delta,
-                )
-                # dict view of the statics tuple for the gather-bytes
-                # estimate (each shard runs the full per-step gather set
-                # over its own tables)
-                cfg = dict(zip(_KERNEL_STATICS, statics))
-                n_shards = int(self.mesh.devices.size)
-                sharded_tables, replicated_tables = state.tables
-                outputs = sharded_check_kernel(
-                    self.mesh, sharded_tables, replicated_tables,
-                    q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid,
-                    statics=statics, axis=self.mesh.axis_names[0],
-                )
-            else:
-                from .kernel import check_kernel_packed, pack_queries
-
-                cfg = kernel_static_config(
-                    state.snapshot, global_max, launch_cap,
-                    n_island_cap=island_cap, has_delta=state.has_delta,
-                )
-                # single-buffer I/O: ONE host->device upload (the packed
-                # query array) and ONE device->host readback at resolve,
-                # in place of seven uploads and five readbacks that each
-                # pay a transfer's fixed cost.
-                outputs = check_kernel_packed(
-                    state.tables,
-                    pack_queries(
-                        q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid
-                    ),
-                    **cfg,
-                )
-        # everything past the launch is deferred to resolve: touching the
-        # outputs here would block on the device round-trip
-        t_done = time.perf_counter()
-        return (
-            "batch",
-            outputs,
-            {
-                "state": state,
-                "tuples": tuples,
-                "n": n,
-                "B": B,
-                "max_depth": max_depth,
-                "q_valid": q_valid,
-                "island_cap": island_cap if self.mesh is None else None,
-                # per-stage seconds accumulated so far; resolve adds
-                # device_wait / host_fallback and finalizes attribution
-                "stage_s": {
-                    "assemble": t_launch - t_submit,
-                    "dispatch": t_done - t_launch,
-                },
-                "telemetry": telemetry,
-                "explain_sink": explain_sink,
-                # flight-recorder fields, read back at the resolve sync
-                # point together with the device stats vector
-                "launch_id": launch_id,
-                "t_submit": t_submit,
-                "launch_cap": launch_cap,
-                "step_cap": int(cfg["max_steps"]),
-                "gather_step_bytes": (
-                    n_shards * estimate_step_gather_bytes(cfg)
-                ),
-            },
-        )
+        return q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid
 
     def check_batch_resolve(self, handle) -> list[CheckResult]:
         """Synchronize one in-flight batch and produce its CheckResults
@@ -2780,12 +2805,11 @@ class TPUCheckEngine:
         tuples = meta["tuples"]
         n, B, max_depth = meta["n"], meta["B"], meta["max_depth"]
         telemetry = meta.get("telemetry")
-        t_resolve = time.perf_counter()
-        member, cause, stats = unpack_closure_results(
-            # ketolint: allow[host-sync] reason=this IS the closure batch's designated sync point: one packed readback carries verdicts, causes, and the launch stats vector — the same single-transfer resolve contract as every other kernel
-            np.asarray(outputs), B,
-        )
-        device_wait_s = time.perf_counter() - t_resolve
+        with self._device_wait(meta) as waited:
+            member, cause, stats = unpack_closure_results(
+                # ketolint: allow[host-sync] reason=this IS the closure batch's designated sync point: one packed readback carries verdicts, causes, and the launch stats vector — the same single-transfer resolve contract as every other kernel
+                np.asarray(outputs), B,
+            )
 
         sink = meta.get("explain_sink")
         results: list = [None] * n
@@ -2794,38 +2818,40 @@ class TPUCheckEngine:
         leftover: list[int] = []
         leftover_cause: dict[int, str] = {}
         causes: dict[str, int] = {}
-        for i in range(n):
-            c = int(cause[i])
-            if c == 0:
-                results[i] = (
-                    RESULT_IS_MEMBER if member[i] else RESULT_NOT_MEMBER
-                )
-                versions[i] = covered
-                if sink is not None:
-                    sink[i] = {"tier": "closure"}
-                if telemetry is not None and telemetry[i] is not None:
-                    telemetry[i].tier = "closure"
-            else:
-                leftover.append(i)
-                name = CL_CAUSE_NAMES.get(c, "uncovered")
-                leftover_cause[i] = name
-                causes[name] = causes.get(name, 0) + 1
-        n_hits = n - len(leftover)
-        self.stats["closure_hits"] = (
-            self.stats.get("closure_hits", 0) + n_hits
-        )
-        if self.metrics is not None:
-            if n_hits:
-                self.metrics.closure_hits_total.inc(n_hits)
-                self.metrics.checks_total.labels("device").inc(n_hits)
-            self.metrics.check_batch_size.observe(n)
-        self.stats["device_checks"] += n_hits
-        for name, cnt in causes.items():
-            self._count_closure_fallback(name, cnt)
+        with StageSpan("resolve", meta.get("launch_id")) as resolved:
+            for i in range(n):
+                c = int(cause[i])
+                if c == 0:
+                    results[i] = (
+                        RESULT_IS_MEMBER if member[i] else RESULT_NOT_MEMBER
+                    )
+                    versions[i] = covered
+                    if sink is not None:
+                        sink[i] = {"tier": "closure"}
+                    if telemetry is not None and telemetry[i] is not None:
+                        telemetry[i].tier = "closure"
+                else:
+                    leftover.append(i)
+                    name = CL_CAUSE_NAMES.get(c, "uncovered")
+                    leftover_cause[i] = name
+                    causes[name] = causes.get(name, 0) + 1
+            n_hits = n - len(leftover)
+            self.stats["closure_hits"] = (
+                self.stats.get("closure_hits", 0) + n_hits
+            )
+            if self.metrics is not None:
+                if n_hits:
+                    self.metrics.closure_hits_total.inc(n_hits)
+                    self.metrics.checks_total.labels("device").inc(n_hits)
+                self.metrics.check_batch_size.observe(n)
+            self.stats["device_checks"] += n_hits
+            for name, cnt in causes.items():
+                self._count_closure_fallback(name, cnt)
 
         meta["closure_resolved"] = n_hits
         self._finish_check_stages(
-            meta, device_wait_s, 0.0, n, B, stats=stats, host_causes=causes
+            meta, waited, resolved, 0.0, n, B, stats=stats,
+            host_causes=causes,
         )
         if leftover:
             sub_sink = [None] * len(leftover) if sink is not None else None
@@ -2837,6 +2863,7 @@ class TPUCheckEngine:
                 ),
                 allow_closure=False,
                 explain_sink=sub_sink,
+                batch_rt=meta["batch_rt"],
             )
             sub_res, sub_ver = self.check_batch_resolve_v(sub_handle)
             for j, i in enumerate(leftover):
@@ -2850,202 +2877,218 @@ class TPUCheckEngine:
                     sink[i] = info
         return results, versions
 
+    @contextlib.contextmanager
+    def _device_wait(self, meta: dict):
+        """A batch's designated sync point as the `device_wait` stage.
+        Whether the readback lands or raises, the device-feed account
+        learns that the launch left the device queue."""
+        with StageSpan("device_wait", meta.get("launch_id")) as waited:
+            try:
+                yield waited
+            finally:
+                meta["device_s"] = self.device_feed.ready(
+                    meta["feed_token"], time.perf_counter()
+                )
+
     def _check_batch_resolve_v_inner(self, outputs, meta):
         state = meta["state"]
-        tuples = meta["tuples"]
-        n, B, max_depth = meta["n"], meta["B"], meta["max_depth"]
+        n, B = meta["n"], meta["B"]
         q_valid = meta["q_valid"]
-        t_resolve = time.perf_counter()
-        if meta.get("island_cap") is not None:
-            # packed single-device result: ONE device->host readback —
-            # the launch stats vector rides the same transfer
-            from .kernel import unpack_results
+        with self._device_wait(meta) as waited:
+            if meta.get("island_cap") is not None:
+                # packed single-device result: ONE device->host readback —
+                # the launch stats vector rides the same transfer
+                from .kernel import unpack_results
 
-            ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = (
-                unpack_results(
-                    # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                    np.asarray(outputs), B, meta["island_cap"],
-                    state.snapshot.K,
+                ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = (
+                    unpack_results(
+                        # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                        np.asarray(outputs), B, meta["island_cap"],
+                        state.snapshot.K,
+                    )
                 )
-            )
-            ctx_hit = ctx_hit.copy()
-        else:
-            ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = outputs
-            # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-            ctx_hit = np.asarray(ctx_hit).copy()
-            # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-            needs_host = np.asarray(needs_host)
-            # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-            n_isl = int(n_isl)
-            # ketolint: allow[host-sync] reason=part of the same designated resolve sync point: the mesh path's replicated stats vector reads back with the batch results, not as an extra round-trip
-            stats = np.asarray(stats)
-        if _faults.get("batch_corrupt") is not None:
-            # fault-injection point: poison every slot's device verdict
-            # so each query takes the exact-host-replay escape hatch the
-            # capacity overflows use — answers must stay byte-correct
-            _faults.inject("batch_corrupt")
-            # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-            needs_host = np.maximum(np.asarray(needs_host), 1)
-        if n_isl:
-            from .islands import combine_islands
-
-            member = combine_islands(
+                ctx_hit = ctx_hit.copy()
+            else:
+                ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = outputs
                 # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                ctx_hit, np.asarray(isl_parent), np.asarray(isl_pid),
-                n_isl, state.snapshot.island_circuits, B, state.snapshot.K,
-            )
-        else:
-            member = ctx_hit[:B]
-        device_wait_s = time.perf_counter() - t_resolve
+                ctx_hit = np.asarray(ctx_hit).copy()
+                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                needs_host = np.asarray(needs_host)
+                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                n_isl = int(n_isl)
+                # ketolint: allow[host-sync] reason=part of the same designated resolve sync point: the mesh path's replicated stats vector reads back with the batch results, not as an extra round-trip
+                stats = np.asarray(stats)
+            if _faults.get("batch_corrupt") is not None:
+                # fault-injection point: poison every slot's device verdict
+                # so each query takes the exact-host-replay escape hatch the
+                # capacity overflows use — answers must stay byte-correct
+                _faults.inject("batch_corrupt")
+                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                needs_host = np.maximum(np.asarray(needs_host), 1)
+            if n_isl:
+                from .islands import combine_islands
 
-        # fast path: every query ran on device (the steady serving
-        # state) — one numpy reduction decides, then results come from a
-        # bare list comprehension over the verdict array instead of the
-        # per-item bookkeeping loop (~3x less host time per batch, and
-        # the host loop serializes against the next launch's encode)
+                member = combine_islands(
+                    # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                    ctx_hit, np.asarray(isl_parent), np.asarray(isl_pid),
+                    n_isl, state.snapshot.island_circuits, B, state.snapshot.K,
+                )
+            else:
+                member = ctx_hit[:B]
+
         sink = meta.get("explain_sink")
         telemetry = meta.get("telemetry")
-        if (
-            n <= B
-            and bool(q_valid[:n].all())
-            and not bool((needs_host[:n] > 0).any())
-        ):
-            with self.tracer.span("engine.resolve_batch", batch=n) as sp:
-                sp.set_attribute("host_replays", 0)
-                results = [
-                    RESULT_IS_MEMBER if m else RESULT_NOT_MEMBER
-                    for m in member[:n].tolist()
-                ]
-            if sink is not None:
-                for i in range(n):
-                    sink[i] = {"tier": "device"}
-            if telemetry is not None:
-                for rt in telemetry:
-                    if rt is not None:
-                        rt.tier = "device"
-            self.stats["device_checks"] += n
-            if self.metrics is not None:
-                self.metrics.check_batch_size.observe(n)
-                self.metrics.checks_total.labels("device").inc(n)
-            self._finish_check_stages(
-                meta, device_wait_s, 0.0, n, B, stats=stats
-            )
-            return results, [state.covered_version] * n
-
-        results = []
-        versions: list = []
         covered = state.covered_version
         n_host = 0
         host_s = 0.0
         host_causes: dict[str, int] = {}
-        # identical host-replayed queries within one batch evaluate once
-        # (an adversarial batch of 4096 same-tuple fallbacks would
-        # otherwise serialize 4096 recursive walks)
-        replay_memo: dict[tuple, CheckResult] = {}
-        with self.tracer.span("engine.resolve_batch", batch=n) as sp:
-            for i, t in enumerate(tuples):
-                if i < B and q_valid[i] and not needs_host[i]:
-                    # shared immutable singletons: 4096 CheckResult
-                    # constructions per batch are measurable on the
-                    # 1-core serve host
-                    results.append(
-                        RESULT_IS_MEMBER if member[i] else RESULT_NOT_MEMBER
-                    )
-                    versions.append(covered)
-                    if sink is not None:
+        with StageSpan("resolve", meta.get("launch_id")) as resolved, (
+            self.tracer.span("engine.resolve_batch", batch=n)
+        ) as sp:
+            if (
+                n <= B
+                and bool(q_valid[:n].all())
+                and not bool((needs_host[:n] > 0).any())
+            ):
+                # fast path: every query ran on device (the steady
+                # serving state) — one numpy reduction decides, then
+                # results come from a bare list comprehension over the
+                # verdict array instead of the per-item bookkeeping loop
+                # (~3x less host time per batch, and the host loop
+                # serializes against the next launch's encode)
+                results = [
+                    RESULT_IS_MEMBER if m else RESULT_NOT_MEMBER
+                    for m in member[:n].tolist()
+                ]
+                versions: list = [covered] * n
+                if sink is not None:
+                    for i in range(n):
                         sink[i] = {"tier": "device"}
-                    if telemetry is not None and telemetry[i] is not None:
-                        telemetry[i].tier = "device"
-                else:
-                    n_host += 1
-                    # cause bookkeeping: the kernel reports a CAUSE_* code
-                    # per query; queries that never reached the device
-                    # (unknown vocabulary) count as "unindexed"
-                    if i < B and q_valid[i]:
-                        cause = CAUSE_NAMES.get(
-                            int(needs_host[i]), CAUSE_NAME_UNINDEXED
-                        )
-                    else:
-                        cause = CAUSE_NAME_UNINDEXED
-                    host_causes[cause] = host_causes.get(cause, 0) + 1
-                    # field-structured key: the display string is NOT
-                    # injective (a subject_id spelled "(ns:obj#rel)"
-                    # renders like a real subject set)
-                    key = (
-                        t.namespace, t.object, t.relation, t.subject_id,
-                        t.subject_set, max_depth,
-                    )
-                    res = replay_memo.get(key)
-                    if res is None:
-                        t_host = time.perf_counter()
-                        res = self.reference.check_relation_tuple(
-                            t, max_depth, self.nid
-                        )
-                        host_s += time.perf_counter() - t_host
-                        replay_memo[key] = res
-                    results.append(res)
-                    versions.append(None)
-                    if sink is not None:
-                        sink[i] = {"tier": "host", "cause": cause}
-                    if telemetry is not None and telemetry[i] is not None:
-                        telemetry[i].tier = "host"
+                if telemetry is not None:
+                    for rt in telemetry:
+                        if rt is not None:
+                            rt.tier = "device"
+            else:
+                results, versions, n_host, host_s = self._resolve_items(
+                    meta, member, needs_host, host_causes
+                )
             sp.set_attribute("host_replays", n_host)
-        self.stats["device_checks"] += n - n_host
-        self.stats["host_checks"] += n_host
-        for cause, cnt in host_causes.items():
-            self.stats["host_cause"][cause] = (
-                self.stats["host_cause"].get(cause, 0) + cnt
-            )
-        if self.metrics is not None:
-            self.metrics.check_batch_size.observe(n)
-            self.metrics.checks_total.labels("device").inc(n - n_host)
-            if n_host:
-                self.metrics.checks_total.labels("host").inc(n_host)
+            self.stats["device_checks"] += n - n_host
+            self.stats["host_checks"] += n_host
             for cause, cnt in host_causes.items():
-                self.metrics.host_fallback_total.labels(cause).inc(cnt)
+                self.stats["host_cause"][cause] = (
+                    self.stats["host_cause"].get(cause, 0) + cnt
+                )
+            if self.metrics is not None:
+                self.metrics.check_batch_size.observe(n)
+                self.metrics.checks_total.labels("device").inc(n - n_host)
+                if n_host:
+                    self.metrics.checks_total.labels("host").inc(n_host)
+                for cause, cnt in host_causes.items():
+                    self.metrics.host_fallback_total.labels(cause).inc(cnt)
         self._finish_check_stages(
-            meta, device_wait_s, host_s, n, B,
+            meta, waited, resolved, host_s, n, B,
             stats=stats, host_causes=host_causes,
         )
         return results, versions
 
+    def _resolve_items(self, meta, member, needs_host, host_causes: dict):
+        """The per-item bookkeeping loop of a batch that did NOT wholly
+        run on device: device verdicts where they stand, exact host
+        replay (counted by cause into `host_causes`) for the rest.
+        Returns (results, versions, host replays, host replay seconds)."""
+        state = meta["state"]
+        B, max_depth, q_valid = meta["B"], meta["max_depth"], meta["q_valid"]
+        sink = meta.get("explain_sink")
+        telemetry = meta.get("telemetry")
+        covered = state.covered_version
+        results = []
+        versions: list = []
+        n_host = 0
+        host_s = 0.0
+        # identical host-replayed queries within one batch evaluate once
+        # (an adversarial batch of 4096 same-tuple fallbacks would
+        # otherwise serialize 4096 recursive walks)
+        replay_memo: dict[tuple, CheckResult] = {}
+        for i, t in enumerate(meta["tuples"]):
+            if i < B and q_valid[i] and not needs_host[i]:
+                # shared immutable singletons: 4096 CheckResult
+                # constructions per batch are measurable on the
+                # 1-core serve host
+                results.append(
+                    RESULT_IS_MEMBER if member[i] else RESULT_NOT_MEMBER
+                )
+                versions.append(covered)
+                if sink is not None:
+                    sink[i] = {"tier": "device"}
+                if telemetry is not None and telemetry[i] is not None:
+                    telemetry[i].tier = "device"
+            else:
+                n_host += 1
+                # cause bookkeeping: the kernel reports a CAUSE_* code
+                # per query; queries that never reached the device
+                # (unknown vocabulary) count as "unindexed"
+                if i < B and q_valid[i]:
+                    cause = CAUSE_NAMES.get(
+                        int(needs_host[i]), CAUSE_NAME_UNINDEXED
+                    )
+                else:
+                    cause = CAUSE_NAME_UNINDEXED
+                host_causes[cause] = host_causes.get(cause, 0) + 1
+                # field-structured key: the display string is NOT
+                # injective (a subject_id spelled "(ns:obj#rel)"
+                # renders like a real subject set)
+                key = (
+                    t.namespace, t.object, t.relation, t.subject_id,
+                    t.subject_set, max_depth,
+                )
+                res = replay_memo.get(key)
+                if res is None:
+                    t_host = time.perf_counter()
+                    res = self.reference.check_relation_tuple(
+                        t, max_depth, self.nid
+                    )
+                    host_s += time.perf_counter() - t_host
+                    replay_memo[key] = res
+                results.append(res)
+                versions.append(None)
+                if sink is not None:
+                    sink[i] = {"tier": "host", "cause": cause}
+                if telemetry is not None and telemetry[i] is not None:
+                    telemetry[i].tier = "host"
+        return results, versions, n_host, host_s
+
     def _finish_check_stages(
-        self, meta, device_wait_s: float, host_s: float, n: int, B: int,
+        self, meta, waited, resolved, host_s: float, n: int, B: int,
         stats=None, host_causes=None,
     ) -> None:
         """Finalize one batch's stage attribution: per-stage histogram
-        samples (once per batch), the occupancy gauge, each rider's
-        RequestTrace stages (+ launch id), the flight-recorder entry,
-        and per-request engine spans when tracing. Batch-shared stages
-        are attributed identically to every rider — the breakdown says
-        where the BATCH spent its time, which is what a tail-latency
-        investigation needs."""
+        samples (once per batch), each rider's RequestTrace stages
+        (+ launch id), the flight-recorder entry, and per-request engine
+        spans when tracing. `waited` and `resolved` are the batch's
+        device_wait and resolve StageSpans; `resolve` is what followed
+        the readback less the `host_s` seconds of host replay inside it.
+        Batch-shared stages are attributed identically to every rider —
+        the breakdown says where the BATCH spent its time, which is what
+        a tail-latency investigation needs."""
         stage_s = dict(meta.get("stage_s") or ())
-        stage_s["device_wait"] = device_wait_s
+        stage_s["device_wait"] = waited.seconds
+        stage_s["resolve"] = max(0.0, resolved.seconds - host_s)
         if host_s > 0.0:
             stage_s["host_fallback"] = host_s
-        telemetry = meta.get("telemetry")
+        riders = meta["riders"]
         if self.metrics is not None:
             # exemplar: the first rider's trace id rides the stage
             # histogram buckets (OpenMetrics exemplars — the metrics ->
             # trace join); batch-shared stages observe once, so one
             # representative trace id per batch is the honest grain
-            exemplar_tid = None
-            for rt in (telemetry or ()):
-                if rt is not None:
-                    exemplar_tid = rt.ctx.trace_id
-                    break
+            exemplar_tid = riders[0].ctx.trace_id if riders else None
             for name, dur in stage_s.items():
                 self.metrics.observe_stage(name, dur, trace_id=exemplar_tid)
-            self.metrics.batch_occupancy.set(n / B if B else 1.0)
-        self._record_launch(meta, stats, n, B, host_causes, stage_s)
-        if not telemetry:
-            return
+        self._record_launch(meta, stats, n, B, host_causes, stage_s, riders)
         spans = getattr(self.tracer, "active", False)
         launch_id = meta.get("launch_id")
-        for rt in telemetry:
-            if rt is None:
-                continue
+        for rt in riders:
             if launch_id is not None:
                 ids = getattr(rt, "launch_ids", None)
                 if ids is not None:
@@ -3061,8 +3104,9 @@ class TPUCheckEngine:
                         batch=B, launch_id=launch_id,
                     )
 
+
     def _record_launch(
-        self, meta, stats, n: int, B: int, host_causes, stage_s
+        self, meta, stats, n: int, B: int, host_causes, stage_s, riders
     ) -> None:
         """One flight-recorder entry + the keto_tpu_launch_* metric
         samples for a resolved device batch. Everything here is host
@@ -3094,14 +3138,15 @@ class TPUCheckEngine:
             "step_cap": step_cap,
             "gather_bytes_est": gather_bytes,
             "host_causes": dict(host_causes or {}),
-            "trace_ids": [
-                rt.ctx.trace_id
-                for rt in (meta.get("telemetry") or ())
-                if rt is not None
-            ],
+            "trace_ids": [rt.ctx.trace_id for rt in riders],
             "stage_ms": {
                 k: round(v * 1e3, 3) for k, v in stage_s.items()
             },
+            # the device-feed account's view of this launch: its
+            # estimated device service time, and how long the device
+            # queue had stood empty when it was dispatched
+            "device_ms": round(meta.get("device_s", 0.0) * 1e3, 3),
+            "starved_ms": round(meta.get("starved_s", 0.0) * 1e3, 3),
             **sd,
         }
         if "closure_resolved" in meta:

@@ -33,6 +33,7 @@ import contextlib
 import contextvars
 import logging
 import secrets
+import sys
 import threading
 import time
 from typing import Optional
@@ -45,16 +46,72 @@ logger = logging.getLogger("keto_tpu")
 # used with Metrics.observe_stage / RequestTrace.add_stage comes from
 # here so the docs table and the bench summary can enumerate them
 CHECK_STAGES = (
-    "transport",      # handler time outside the batcher/engine stages
+    "transport",      # handler time NO other stage covers (the residual:
+                      # what the spans below leave unexplained)
     "cache",          # check-cache fast-path lookup (hits only: a hit
                       # request records NO assemble/dispatch/device_wait
                       # because those stages never run)
+    "decode",         # BatchCheck handler: admission + wire tuples ->
+                      # RelationTuples + namespace validation
     "queue",          # batcher queue wait (enqueue -> group dispatch)
     "assemble",       # state refresh + batch encoding + bucket padding
     "dispatch",       # device launch (H2D upload + async kernel dispatch)
     "device_wait",    # block-until-ready + readback + unpack
+    "resolve",        # verdicts -> CheckResults + counters after the
+                      # readback, less any host_fallback seconds
     "host_fallback",  # exact host replay of cause-flagged queries
+    "respond",        # BatchCheck handler: results -> response message
+                      # + per-item workload accounting
 )
+
+# what an engine's device queue held, second by second
+# (engine/device_feed.py; keto_tpu_device_feed_seconds_total{state})
+DEVICE_FEED_STATES = (
+    "busy",                # at least one check launch not yet read back
+    "starved_no_request",  # empty, and no request had arrived
+    "starved_decode",      # empty while the handler decoded the request
+    "starved_queue",       # empty while the request sat in the batcher
+    "starved_assemble",    # empty while the engine encoded the batch
+    "starved_dispatch",    # empty while the launch was being dispatched
+)
+
+
+class StageSpan:
+    """One stage of the served check path, on two clocks at once: a
+    `jax.profiler.TraceAnnotation` named `keto.<stage>` (the profiler's
+    clock: while a trace is being taken the span lands in the host plane
+    of the same .xplane.pb as the device's `XLA Ops`, which is what lets
+    an idle gap of the device be put down to a host span; `launch_id`
+    rides it as a stat) and `seconds` of `time.perf_counter()` between
+    `start` and the exit (the clock the stage histograms use). Outside a
+    trace the annotation costs under a microsecond. Per RPC and per
+    launch only, never per item."""
+
+    __slots__ = ("start", "seconds", "_annotation")
+
+    def __init__(self, name: str, launch_id: Optional[int] = None):
+        self.start = 0.0
+        self.seconds = 0.0
+        # a process that never imported JAX cannot be taking a JAX
+        # trace: host-only processes (CLI, check.engine=host) pay no
+        # import for a span nobody could read
+        jax = sys.modules.get("jax")
+        if jax is None:
+            self._annotation = contextlib.nullcontext()
+        else:
+            stats = {} if launch_id is None else {"launch_id": launch_id}
+            self._annotation = jax.profiler.TraceAnnotation(
+                f"keto.{name}", **stats
+            )
+
+    def __enter__(self) -> "StageSpan":
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self.start
+        self._annotation.__exit__(*exc)
 
 
 # -- W3C trace context --------------------------------------------------------
@@ -137,10 +194,16 @@ class RequestTrace:
 
     __slots__ = (
         "ctx", "stages", "deadline", "launch_ids", "min_version", "tier",
+        "arrived", "enqueued",
     )
 
     def __init__(self, ctx: Optional[SpanContext] = None, deadline=None):
         self.ctx = ctx if ctx is not None else new_trace()
+        # perf_counter() stamps the device-feed account walks back along
+        # (engine/device_feed.py): when the transport took the request
+        # up, and when a batcher queued it (None on the direct path)
+        self.arrived = time.perf_counter()
+        self.enqueued: Optional[float] = None
         self.stages: dict[str, float] = {}
         self.deadline = deadline
         self.launch_ids: list[int] = []
@@ -408,10 +471,12 @@ class Metrics:
         self.check_stage_duration = prom.Histogram(
             "keto_tpu_check_stage_duration_seconds",
             "Check serving time per pipeline stage (transport | cache | "
-            "queue | assemble | dispatch | device_wait | host_fallback); "
-            "batch-level stages observe once per device batch; `cache` "
-            "observes per cache hit (hit requests record no "
-            "assemble/dispatch/device_wait time)",
+            "decode | queue | assemble | dispatch | device_wait | "
+            "resolve | host_fallback | respond); batch-level stages "
+            "observe once per device batch; `cache` observes per cache "
+            "hit (hit requests record no assemble/dispatch/device_wait "
+            "time); decode/respond observe once per BatchCheck; "
+            "`transport` is the residual no other stage covers",
             ["stage"],
             registry=self.registry,
             buckets=(
@@ -432,12 +497,6 @@ class Metrics:
             "keto_tpu_inflight_launches",
             "Launched-but-unresolved device batches (bounded by the "
             "batcher's in-flight semaphore)",
-            registry=self.registry,
-        )
-        self.batch_occupancy = prom.Gauge(
-            "keto_tpu_batch_occupancy",
-            "Real rows / padded bucket rows of the most recent device "
-            "batch (1.0 = no padding waste)",
             registry=self.registry,
         )
         self.delta_overlay_ops = prom.Gauge(
@@ -672,6 +731,43 @@ class Metrics:
             "rows",
             registry=self.registry,
             buckets=(0.0, 0.1, 0.25, 0.5, 0.75, 0.9, 0.99),
+        )
+        self.device_feed_seconds = prom.Counter(
+            "keto_tpu_device_feed_seconds_total",
+            "Wall seconds since an engine's first check launch, by what "
+            "its device queue held (engine/device_feed.py): `busy` = at "
+            "least one check launch dispatched and not read back; "
+            "`starved_*` = nothing queued, charged to what the NEXT "
+            "launch was doing meanwhile, walking back along its own "
+            "timeline (dispatch, assemble, batcher queue, handler "
+            "decode) and `starved_no_request` before its request "
+            "arrived. The six states sum to wall time. A resolver that "
+            "wakes late under the interpreter lock sees its readback "
+            "late, so starved time is a LOWER bound",
+            ["state"],
+            registry=self.registry,
+        )
+        # every state declared from the start: a scrape before the
+        # first launch (and a reader that names the series) sees zeros,
+        # not a missing family
+        self.device_feed_state = {
+            state: self.device_feed_seconds.labels(state)
+            for state in DEVICE_FEED_STATES
+        }
+        self.launch_device_seconds = prom.Histogram(
+            "keto_tpu_launch_device_seconds",
+            "Estimated device service time of one check launch: readback "
+            "done minus the later of its dispatch and the latest "
+            "readback seen before it (launches run in order on one "
+            "device queue, so this leaves out the wait behind earlier "
+            "launches that device_wait includes). Over EVERY launch, "
+            "not the few a profiler trace holds. A late-waking resolver "
+            "makes it an UPPER bound",
+            registry=self.registry,
+            buckets=(
+                0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                0.25, 0.5, 1.0, 2.5,
+            ),
         )
         self.flightrec_dumps_total = prom.Counter(
             "keto_tpu_flightrec_dumps_total",
@@ -1152,6 +1248,22 @@ class Metrics:
             child.observe(seconds, exemplar={"trace_id": trace_id})
         else:
             child.observe(seconds)
+
+    @contextlib.contextmanager
+    def stage(self, name: str, rt: Optional[RequestTrace] = None):
+        """Time one handler stage as a StageSpan and, when the body ends
+        without raising, feed the SAME elapsed seconds to the stage
+        histogram and to the request's breakdown. A body that raises (a
+        shed or malformed request) never rode the pipeline and records
+        nothing."""
+        with StageSpan(name) as span:
+            yield span
+        self.observe_stage(
+            name, span.seconds,
+            trace_id=rt.ctx.trace_id if rt is not None else None,
+        )
+        if rt is not None:
+            rt.add_stage(name, span.seconds)
 
     def observe_tier(
         self, tier: str, seconds: float, trace_id: Optional[str] = None

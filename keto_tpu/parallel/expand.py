@@ -53,6 +53,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
     F = frontier_cap
     E = edge_cap
 
+    @jax.named_scope("keto.expand")
     def run(shard_tabs, rep_tabs, q_obj, q_rel, q_depth, q_valid):
         tables = {k: v[0] for k, v in shard_tabs.items()}
         tables.update(rep_tabs)

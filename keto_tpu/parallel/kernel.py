@@ -64,6 +64,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
     ) = statics
     F = frontier_cap
 
+    @jax.named_scope("keto.check")
     def run(shard_tabs, rep_tabs, q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid):
         tables = {k: v[0] for k, v in shard_tabs.items()}
         tables.update(rep_tabs)

@@ -274,8 +274,7 @@ class TestStageMetrics:
         # the new pipeline gauges export too
         for gauge in (
             "keto_tpu_batcher_queue_depth", "keto_tpu_inflight_launches",
-            "keto_tpu_batch_occupancy", "keto_tpu_snapshot_hbm_bytes",
-            "keto_tpu_delta_overlay_ops",
+            "keto_tpu_snapshot_hbm_bytes", "keto_tpu_delta_overlay_ops",
             "keto_tpu_compaction_lag_versions",
         ):
             assert gauge in text, f"missing gauge: {gauge}"
