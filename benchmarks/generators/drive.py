@@ -81,43 +81,57 @@ def columns(truth: Truth):
     return concat_columns([own, par, truth.rbac, co])
 
 
+def _distinct(rng, rows: int, per: int, n: int):
+    """`per` distinct draws from range(n) in each of `rows` rows: a row that
+    drew one number twice draws again."""
+    out = rng.integers(0, n, (rows, per))
+    while True:
+        s = np.sort(out, axis=1)
+        again = (s[:, 1:] == s[:, :-1]).any(axis=1)
+        if not again.any():
+            return out
+        out[again] = rng.integers(0, n, (int(again.sum()), per))
+
+
 def _rbac_columns(n_roles: int, n_users: int, seed: int):
-    """Each role holds 12 user members and 2 nested roles of higher id (the
-    graph stays acyclic). A role may draw one member twice: only the tuples
-    the store will keep are returned."""
+    """Each role holds 12 distinct user members and nests 2 distinct roles
+    among the 97 of next higher id (the last role none, the one before it
+    one: the graph stays acyclic). So the overlay has 14 n_roles - 3 tuples
+    for every seed, and with it the folders, the files and the edges: the
+    seed draws who owns and who belongs, never how much there is. (Copied
+    from tools/scale_bench.synth_rbac_columns, which draws with replacement
+    and drops what it drew twice: a store of another size, and so another
+    program to compile, for every seed.)"""
     from keto_tpu.storage.columns import TupleColumns, concat_columns
 
     rng = np.random.default_rng(seed)
-    members_per, nested_per = 12, 2
+    members_per, nested_per, reach = 12, 2, 97
     role_of = np.repeat(np.arange(n_roles), members_per)
-    member = rng.integers(0, n_users, n_roles * members_per)
-    keep = np.sort(np.unique(role_of * n_users + member, return_index=True)[1])
-    role_of, member = role_of[keep], member[keep]
+    member = _distinct(rng, n_roles, members_per, n_users).reshape(-1)
     direct = TupleColumns(
-        ns=np.full(len(keep), "rbac", "U4"),
+        ns=np.full(len(role_of), "rbac", "U4"),
         obj=np.char.add("role", role_of.astype("U7")),
-        rel=np.full(len(keep), "member", "U6"),
-        skind=np.zeros(len(keep), np.int8),
-        sns=np.full(len(keep), "", "U1"),
+        rel=np.full(len(role_of), "member", "U6"),
+        skind=np.zeros(len(role_of), np.int8),
+        sns=np.full(len(role_of), "", "U1"),
         sobj=np.char.add("u", member.astype("U10")),
-        srel=np.full(len(keep), "", "U1"),
+        srel=np.full(len(role_of), "", "U1"),
     )
-    n_nest = n_roles * nested_per
-    parent_role = np.repeat(np.arange(n_roles), nested_per)
-    child_role = np.minimum(
-        parent_role + 1 + rng.integers(0, 97, n_nest), n_roles - 1
-    )
-    keep = np.sort(
-        np.unique(parent_role * n_roles + child_role, return_index=True)[1]
-    )
-    parent_role, child_role = parent_role[keep], child_role[keep]
+    room = np.minimum(reach, n_roles - 1 - np.arange(n_roles))  # roles above
+    first = rng.integers(0, np.maximum(room, 1))
+    second = rng.integers(0, np.maximum(room - 1, 1))
+    second += second >= first  # the other of two distinct offsets
+    offsets = np.stack([first, second], axis=1)
+    keep = np.arange(nested_per)[None, :] < room[:, None]
+    parent_role = np.repeat(np.arange(n_roles), nested_per)[keep.reshape(-1)]
+    child_role = parent_role + 1 + offsets.reshape(-1)[keep.reshape(-1)]
     nested = TupleColumns(
-        ns=np.full(len(keep), "rbac", "U4"),
+        ns=np.full(len(parent_role), "rbac", "U4"),
         obj=np.char.add("role", parent_role.astype("U7")),
-        rel=np.full(len(keep), "member", "U6"),
-        skind=np.ones(len(keep), np.int8),
-        sns=np.full(len(keep), "rbac", "U4"),
+        rel=np.full(len(parent_role), "member", "U6"),
+        skind=np.ones(len(parent_role), np.int8),
+        sns=np.full(len(parent_role), "rbac", "U4"),
         sobj=np.char.add("role", child_role.astype("U7")),
-        srel=np.full(len(keep), "member", "U6"),
+        srel=np.full(len(parent_role), "member", "U6"),
     )
     return concat_columns([direct, nested])

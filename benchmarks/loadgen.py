@@ -111,7 +111,7 @@ def main(argv=None) -> int:
     np.savez(
         args.answers,
         rpc=np.array([r[0] for r in window], np.int64),
-        answers=np.array([r[4] for r in window], bool).reshape(len(window), -1),
+        answers=np.array([r[4] for r in window], bool).reshape(len(window), workload.items),
     )
     print(json.dumps({
         "event": "result",

@@ -17,6 +17,12 @@ configs/<config>.json, traffic/<traffic>.json, metrics/<metric>.json,
 generators/<name>.py and readers/<name>.py hold them. This file and
 loadgen.py name none of them.
 
+The command starts that process as a child and starts it once more if it
+had to compile: a process that has compiled its check program serves
+2 to 3% slower than one that read it from the compile cache (PERF.md, PR 28),
+so every window is served by a process that read all its programs. Both
+set-ups count in `setup_s`.
+
 Without a TPU it refuses to run. The one exception is the rehearsal of the
 on-chip-measurement guide: JAX_PLATFORMS=cpu AND --tuples <a small size>.
 A rehearsal prints counts and host clocks of the CPU under the same names,
@@ -29,6 +35,7 @@ import argparse
 import json
 import os
 import shutil
+import signal
 import subprocess
 import sys
 import threading
@@ -42,7 +49,10 @@ sys.path[:0] = [ROOT, HERE]
 OUT = os.path.join(HERE, "out")
 TRACE_START_S = 3.0  # into the window
 TRACE_LENGTH_S = 3.0  # longer is too large to reduce inside a run
+TRACE_MARGIN_S = 0.05  # of trace before and after the marked window
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"  # compiled, and kept
+AGAIN = 75  # exit code of a serving process that compiled: start it again
 
 
 class BenchFailure(Exception):
@@ -116,15 +126,24 @@ def serve(config: dict, cols):
 
 
 class Tracer:
-    """A profiler trace of TRACE_LENGTH_S seconds, TRACE_START_S into the
-    window (both cut to fit a short window), taken by a timer thread."""
+    """A profiler trace that holds a window of TRACE_LENGTH_S seconds,
+    TRACE_START_S into the run's window (both cut to fit a short one), taken
+    by a timer thread. The window is marked inside the trace, by an
+    annotation held open while the thread sleeps: it lands in a host plane
+    on the clock of the device planes, and trace_reduce clips the device's
+    events to it. The session is TRACE_MARGIN_S longer at either end: on
+    the chip the device's events begin and end up to 5 ms inside the host's
+    session, and the profiler cuts the event of a launch in flight at the
+    session's edge, so without the margin the window's ends read as idle
+    and a cut launch as a whole, short one (PERF.md, PR 28). The host's own
+    clock around the same sleep is printed beside it and decides nothing."""
 
     def __init__(self, seconds: float):
         self.dir = os.path.join(OUT, "trace")
         shutil.rmtree(self.dir, ignore_errors=True)
         self.start_after = min(TRACE_START_S, seconds / 4)
         self.length = min(TRACE_LENGTH_S, seconds / 2)
-        self.window_s = None
+        self.host_window_s = None
         self._thread = threading.Thread(target=self._run, daemon=True)
 
     def begin(self) -> None:
@@ -132,26 +151,62 @@ class Tracer:
 
     def _run(self) -> None:
         import jax
-
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0  # the device planes are what is read
-        options.host_tracer_level = 1
-        time.sleep(self.start_after)
-        jax.profiler.start_trace(self.dir, profiler_options=options)
-        t0 = time.perf_counter()
-        time.sleep(self.length)
-        self.window_s = time.perf_counter() - t0
-        jax.profiler.stop_trace()
-
-    def reduce(self) -> dict | None:
         import trace_reduce
 
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # the device planes are what is read,
+        options.host_tracer_level = 1  # and the host plane's one annotation
+        time.sleep(self.start_after)
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        try:
+            time.sleep(TRACE_MARGIN_S)
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_EVENT):
+                time.sleep(self.length)
+            self.host_window_s = time.perf_counter() - t0
+            time.sleep(TRACE_MARGIN_S)
+        finally:
+            jax.profiler.stop_trace()
+
+    def reduce(self) -> dict:
         self._thread.join()
         try:
-            planes = trace_reduce.read_device_events(self.dir)
-            return trace_reduce.reduce(planes, self.window_s)
+            summary = window_summary(self.dir)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+        return {**summary, "host_window_s": self.host_window_s}
+
+
+def window_summary(trace_dir: str) -> dict:
+    """trace_reduce's summary of the one marked window of the trace under
+    `trace_dir`. A trace without the mark, or with no operation inside it,
+    gives no device time, and the host's clock is no stand-in for it."""
+    import trace_reduce
+
+    planes, windows = trace_reduce.read_trace(trace_dir)
+    if len(windows) != 1:
+        raise BenchFailure(
+            f"the trace holds {len(windows)} {trace_reduce.WINDOW_EVENT} "
+            "events, not the one that marks the traced window"
+        )
+    summary = trace_reduce.reduce(planes, windows[0])
+    if summary is None:
+        raise BenchFailure(
+            "no operation ran on the device inside the traced window"
+        )
+    return summary
+
+
+def device_times(trace: dict) -> dict:
+    """`busy_s` and `window_s` of the result line's `device`, refused where
+    the contract refuses them."""
+    busy_s, window_s = trace["busy_s"], trace["window_s"]
+    if not 0 < busy_s <= window_s:
+        raise BenchFailure(
+            f"device.busy_s {busy_s!r} is not above 0 and at most "
+            f"device.window_s {window_s!r}"
+        )
+    return {"busy_s": busy_s, "window_s": window_s}
 
 
 def run_child(args, config_path, traffic_path, port, answers, on_window):
@@ -204,15 +259,27 @@ def reference_differs(engine, workload, answers_path: str, seed: int,
     return differ
 
 
+def compared(child: dict, ref_differs: int, failed_batches: dict,
+             breaker: float) -> dict:
+    """Every number that decides `correct`, as [number, limit]; each may be
+    at most its limit, and every limit is 0: an answer is exact or wrong."""
+    return {
+        "wrong_checks": [child["wrong_checks"], 0],
+        "reference_differs": [ref_differs, 0],
+        "failed_device_batches": [sum(failed_batches.values()), 0],
+        "breaker_state": [breaker, 0],
+    }
+
+
 def verdict(child: dict, ref_differs: int, failed_batches: dict,
             breaker: float) -> tuple[bool, int]:
     """(`correct`, `failed`) of the result line. Wrong answers, failed device
     batches and an open breaker make a run incorrect; an RPC that erred is a
     failed RPC."""
     failed = int(child["errors"] + child["wrong_rpcs"] + child["callers_stuck"])
+    numbers = compared(child, ref_differs, failed_batches, breaker)
     correct = (
-        child["wrong_checks"] == 0 and ref_differs == 0
-        and not any(failed_batches.values()) and breaker == 0
+        all(number <= limit for number, limit in numbers.values())
         and child["attempted"] > 0
     )
     return bool(correct), failed
@@ -245,6 +312,8 @@ def read_metrics(metrics: list, cell: str, run) -> dict:
 
 
 def main(argv=None) -> int:
+    """The serving process. Returns AGAIN, before any traffic, where it had
+    to compile a program that the compile cache now holds."""
     t_start = time.perf_counter()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -253,7 +322,13 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--tuples", type=int, default=None,
                     help="rehearsal size; only with JAX_PLATFORMS=cpu")
+    ap.add_argument("--started", type=float, default=None,
+                    help="set by supervise(): its clock when the command began")
+    ap.add_argument("--again", action="store_true",
+                    help="set by supervise(): the process before this one compiled")
     args = ap.parse_args(argv)
+    if args.started is not None:
+        t_start = args.started  # perf_counter is one clock for every process
 
     from workload import WARM_STREAM, Workload, read_json
 
@@ -282,6 +357,10 @@ def main(argv=None) -> int:
     jax.monitoring.register_event_duration_secs_listener(
         lambda name, _secs, **_: name == COMPILE_EVENT and compiles.append(name)
     )
+    kept: list[str] = []  # one entry for every program compiled here and cached
+    jax.monitoring.register_event_listener(
+        lambda name, **_: name == CACHE_WRITE_EVENT and kept.append(name)
+    )
     cache_dir = ensure_compile_cache()
     os.makedirs(OUT, exist_ok=True)
     emit("device", rehearsal=rehearsal, compile_cache=cache_dir, **device)
@@ -298,7 +377,10 @@ def main(argv=None) -> int:
             engine.check_batch(workload.draw(WARM_STREAM + k, n)[0])
         warm_launch_s = time.perf_counter() - t0
         emit("serving", tuples=len(cols), synth_s=synth_s,
-             warm_launch_s=warm_launch_s, programs=len(compiles), **clocks)
+             warm_launch_s=warm_launch_s, programs=len(compiles),
+             programs_compiled=len(kept), again=args.again, **clocks)
+        if kept and not args.again:
+            return AGAIN  # the finally below stops the daemon
 
         from scrape import Scrape
 
@@ -318,8 +400,6 @@ def main(argv=None) -> int:
         after = Scrape.of(daemon.metrics_port)
         compiles_in_window = len(compiles) - marks["programs"]
         trace = tracer.reduce() if tracer else None
-        if tracer and trace is None:
-            raise BenchFailure("no operation ran on the device while it was traced")
 
         t0 = time.perf_counter()
         samples = int(config["reference_samples"])
@@ -362,21 +442,54 @@ def main(argv=None) -> int:
         ),
     }
     if trace is not None:
-        device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
+        emit("trace", **{k: v for k, v in trace.items()
+                         if k not in ("device_ops", "idle_gaps")})
+        device.update(device_times(trace))
         line["breakdown"] = {
             "device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"],
         }
-        emit("trace", **{k: v for k, v in trace.items()
-                         if k not in ("device_ops", "idle_gaps")})
     line["device"] = device
+    line["compared"] = compared(child, ref_differs, failed_batches, breaker)
+    for name, (number, limit) in line["compared"].items():
+        print(f"compared {name}: {number} (limit {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 
 
-if __name__ == "__main__":
+def supervise(argv: list, program=None) -> int:
+    """Run the serving process (`program`: this file) as a child of this
+    one, which stays off JAX and so off the chip, and once more if it ends
+    with AGAIN. The children write to this process's output; the last line
+    is the second's."""
+    started = time.perf_counter()
+    program = program or [sys.executable, os.path.abspath(__file__)]
+    code = AGAIN
+    for more in ([], ["--again"]):
+        if code != AGAIN:
+            break
+        child = subprocess.Popen(
+            [*program, *argv, "--started", repr(started), *more]
+        )
+        try:
+            code = child.wait()
+        finally:
+            if child.poll() is None:
+                child.kill()
+                child.wait()
+    return 1 if code == AGAIN else code
+
+
+def serve_once(argv=None) -> int:
     try:
-        code = main()
+        return main(argv)
     except BenchFailure as e:
         print(f"benchmarks/run.py: FAILED: {e}", file=sys.stderr, flush=True)
-        code = 1
-    sys.exit(code)
+        return 1
+
+
+if __name__ == "__main__":
+    if "--started" in sys.argv:
+        sys.exit(serve_once())
+    # ended from outside, this process takes its child along (the finally)
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    sys.exit(supervise(sys.argv[1:]))

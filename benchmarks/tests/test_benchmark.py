@@ -23,19 +23,22 @@ import run  # noqa: E402
 import trace_reduce  # noqa: E402
 from scrape import Scrape  # noqa: E402
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
-def rehearse(root: str, cell: str, trace: int) -> dict:
+def rehearse(root: str, cell: str, trace: int, program=None) -> tuple[dict, str]:
+    """(the result line, the standard error) of a rehearsal of run.py, or
+    of `program`, a script that ends in run.main()."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    command = ["-c", program] if program else [os.path.join(root, "benchmarks", "run.py")]
     done = subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks", "run.py"),
+        [sys.executable, *command,
          "--workload", cell, "--seed", "3000000001", "--seconds", "2",
          "--trace", str(trace), "--tuples", "20000"],
         capture_output=True, text=True, env=env, timeout=300,
     )
     assert done.returncode == 0, done.stderr[-2000:]
-    return json.loads(done.stdout.strip().splitlines()[-1])
+    return json.loads(done.stdout.strip().splitlines()[-1]), done.stderr
 
 
 @pytest.fixture(scope="module")
@@ -47,7 +50,7 @@ def bench():
 @pytest.fixture(scope="module", params=[0, 1], ids=["end_to_end", "per_layer"])
 def rehearsal(request, bench):
     cell = bench["workloads"][0]["name"]
-    return request.param, rehearse(ROOT, cell, request.param)
+    return request.param, rehearse(ROOT, cell, request.param)[0]
 
 
 def test_rehearsal_prints_the_contracts_line(rehearsal, bench):
@@ -56,8 +59,9 @@ def test_rehearsal_prints_the_contracts_line(rehearsal, bench):
     reads a Prometheus name the daemon does not expose, so this passing run
     has found every one."""
     trace, line = rehearsal
-    assert set(line) == RESULT_KEYS
+    assert list(line) == RESULT_KEYS  # what was compared comes last
     assert line["correct"] is True and line["failed"] == 0 < line["attempted"]
+    assert all(number <= limit for number, limit in line["compared"].values())
     assert line["device"]["platform"] == "cpu"
     assert {"busy_s", "window_s"}.isdisjoint(line["device"])
     if trace:
@@ -93,7 +97,7 @@ def test_a_new_cell_is_files_and_an_entry(tmp_path, bench):
     bench["per_layer"].append({"name": "queue_b_ms", "unit": "ms",
                                "workloads": ["other.few"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    line = rehearse(str(tmp_path), "other.few", 1)
+    line, _ = rehearse(str(tmp_path), "other.few", 1)
     assert line["correct"] is True
     assert line["metrics"]["queue_b_ms"]["value"] > 0
 
@@ -112,6 +116,12 @@ def test_trace_reduction_on_hand_made_intervals():
     assert trace_reduce.self_times(nested) == [
         (0, 4, "while"), (1, 2, "a"), (3, 3, "cond"), (3.5, 1, "b"),
     ]
+    assert trace_reduce.clip(nested, 2.0, 4.0) == [
+        (2.0, 2.0, "while"), (2.0, 1.0, "a"), (3.0, 1.0, "cond"), (3.5, 0.5, "b"),
+    ]
+    launches = [(0, 2.0, "p"), (2, 4.0, "p"), (6, 9.0, "p")]
+    assert trace_reduce.whole_launches(launches, 1.0, 7.0) == ([(2, 4.0, "p")], 2)
+    assert trace_reduce.whole_launches(launches, 3.0, 5.0) == ([], 1)
     launched = trace_reduce.programs(
         [(0, 2.0, "jit_check(1)"), (2, 4.0, "jit_check(1)"), (6, 9.0, "jit_expand(2)")]
     )
@@ -124,11 +134,43 @@ def test_reduce_reports_busy_window_and_breakdown():
         trace_reduce.OPS_LINE: OPS,
         trace_reduce.MODULES_LINE: [(0.0, 1.5, "jit_check(7)"), (3.0, 1.0, "jit_check(7)")],
     }}
-    summary = trace_reduce.reduce(planes, window_s=5.0)
+    summary = trace_reduce.reduce(planes, window=(0.0, 5.0))
+    assert summary["window_s"] == 5.0 and summary["event_span_s"] == 4.0
     assert summary["busy_s"] == 2.5 and summary["idle_share"] == 0.5
     assert summary["programs"] == {"jit_check(7)": [2, 2.5]}
+    assert summary["launches_cut"] == 0
     assert summary["idle_gaps"][0][1] == 1.5
+    cut = trace_reduce.reduce(planes, window=(1.0, 3.5))
+    assert cut["busy_s"] == 1.0 and cut["window_s"] == 2.5
+    assert cut["programs"] == {} and cut["launches_cut"] == 2
+    # without a window: from the first to the last event, no launch cut
+    whole = trace_reduce.reduce(planes)
+    assert whole["window_s"] == 4.0 and whole["busy_s"] == 2.5
+    assert whole["programs"] == {"jit_check(7)": [2, 2.5]}
     assert trace_reduce.reduce({"/device:TPU:0": {trace_reduce.OPS_LINE: []}}) is None
+
+
+def test_busy_time_beyond_the_window_is_refused():
+    """The contract refuses a traced line unless 0 < busy_s <= window_s; so
+    does run.py, naming both numbers (PR 27's first traced deep run)."""
+    assert run.device_times({"busy_s": 2.5, "window_s": 3.0}) == {
+        "busy_s": 2.5, "window_s": 3.0,
+    }
+    for busy_s, window_s in ((3.04432, 3.00087), (0.0, 3.0)):
+        with pytest.raises(run.BenchFailure) as failure:
+            run.device_times({"busy_s": busy_s, "window_s": window_s})
+        assert repr(busy_s) in str(failure.value)
+        assert repr(window_s) in str(failure.value)
+
+
+def test_a_failure_ends_the_command_with_code_1():
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", "no.such",
+         "--seed", "1", "--seconds", "1"],
+        capture_output=True, text=True, timeout=120,
+    )
+    assert done.returncode == 1 and done.stdout == ""
+    assert "FAILED: BENCHMARK.json has no workload named 'no.such'" in done.stderr
 
 
 def test_an_unknown_device_kind_is_an_error():
@@ -165,6 +207,92 @@ def test_a_wrong_answer_makes_the_run_incorrect(doctored, correct, failed):
     assert run.verdict(child, 1, {}, 0.0)[0] is False
     assert run.verdict(child, 0, {"device": 1.0}, 0.0)[0] is False
     assert run.verdict(child, 0, {}, 1.0)[0] is False
+
+
+ONE_ANSWER_ALTERED = """
+import sys
+sys.path[:0] = [%r]
+import run
+from keto_tpu.engine import definitions, tpu_engine
+
+whole = tpu_engine.TPUCheckEngine.check_batch
+
+
+def altered(self, *args, **kwargs):
+    results = list(whole(self, *args, **kwargs))
+    results[-1] = (definitions.RESULT_NOT_MEMBER if results[-1].allowed
+                   else definitions.RESULT_IS_MEMBER)
+    return results
+
+
+tpu_engine.TPUCheckEngine.check_batch = altered
+sys.exit(run.main(sys.argv[1:]))
+""" % BENCH
+
+
+def test_an_answer_altered_where_it_is_produced_makes_the_run_incorrect(bench):
+    """(f) through a whole rehearsal: the engine under the daemon flips the
+    last of every batch's 2,048 answers, and the run says so, in `correct`,
+    in `failed`, in what it compared and on its last lines of stderr."""
+    cell = bench["workloads"][0]["name"]
+    line, stderr = rehearse(ROOT, cell, 0, program=ONE_ANSWER_ALTERED)
+    assert line["correct"] is False
+    assert line["failed"] == line["attempted"] > 0
+    wrong, limit = line["compared"]["wrong_checks"]
+    assert wrong == line["attempted"] > limit == 0
+    assert stderr.strip().splitlines()[-4].startswith(f"compared wrong_checks: {wrong} ")
+
+
+COMPILES_ONCE = """
+import sys
+sys.path[:0] = [%r]
+import jax
+import run
+
+whole = run.serve
+
+
+def serve(config, cols):
+    if "--again" not in sys.argv:  # as if the check program had been compiled
+        jax.monitoring.record_event(run.CACHE_WRITE_EVENT)
+    return whole(config, cols)
+
+
+run.serve = serve
+sys.exit(run.serve_once())
+""" % BENCH
+
+
+def test_a_process_that_compiled_is_started_again(bench, capfd):
+    """The window is served by a process that read every program from the
+    compile cache: one that compiled and cached a program ends before any
+    traffic and is started once more, and `setup_s` covers both set-ups."""
+    cell = bench["workloads"][0]["name"]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    code = ("import sys; sys.path.insert(0, %r); import run; "
+            "sys.exit(run.supervise(sys.argv[1:], [sys.executable, '-c', %r]))"
+            % (BENCH, COMPILES_ONCE))
+    done = subprocess.run(
+        [sys.executable, "-c", code, "--workload", cell, "--seed", "3000000002",
+         "--seconds", "2", "--trace", "0", "--tuples", "20000"],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(l) for l in done.stdout.splitlines() if l.startswith("{")]
+    serving = [l for l in lines if l.get("phase") == "serving"]
+    assert [(l["programs_compiled"], l["again"]) for l in serving] == [
+        (1, False), (0, True),
+    ]
+    assert sum("correct" in l for l in lines) == 1 and lines[-1]["correct"] is True
+    one_setup = serving[1]["bulk_load_s"] + serving[1]["snapshot_build_s"]
+    assert lines[-1]["metrics"]["setup_s"]["value"] > 2 * one_setup
+
+
+def test_a_process_that_compiles_every_time_is_not_started_a_third_time(capfd):
+    always = [sys.executable, "-c", "import sys; print('started'); sys.exit(%d)" % run.AGAIN]
+    assert run.supervise([], always) == 1
+    assert capfd.readouterr().out.count("started") == 2
+    assert run.supervise([], [sys.executable, "-c", "import sys; sys.exit(3)"]) == 3
 
 
 def test_the_load_generator_stays_off_jax():

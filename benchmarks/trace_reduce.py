@@ -3,21 +3,31 @@ time of the check program per launch, the operations that took most time and
 the longest idle gaps.
 
 The arithmetic is pure functions over lists of (start, duration, name) in
-seconds; `read_device_events` in front of them is the only code that knows
-the trace's format. On a TPU each chip is a plane `/device:TPU:<n>`, whose
-line `XLA Ops` holds every operation the chip ran and whose line
-`XLA Modules` holds one event per launched program.
+seconds; `read_trace` in front of them is the only code that knows the
+trace's format. On a TPU each chip is a plane `/device:TPU:<n>`, whose line
+`XLA Ops` holds every operation the chip ran and whose line `XLA Modules`
+holds one event per launched program.
+
+The traced window is marked inside the trace: run.py holds one
+`TraceAnnotation` named `bench.window` open while it sleeps, and the event
+lands in a `/host:*` plane of the same file, on the clock the device planes
+use. `reduce` works on that window alone: operations are clipped to its
+edges, so busy time cannot pass it, and a launch that an edge cuts is
+counted apart and left out of the programs' times.
 """
 
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+HOST_PLANE_PREFIX = "/host:"
+WINDOW_EVENT = "bench.window"
 
 
 def merge(intervals):
@@ -30,6 +40,25 @@ def merge(intervals):
         else:
             out.append([start, end])
     return [(s, e) for s, e in out]
+
+
+def clip(events, w0: float, w1: float):
+    """The part of each (start, duration, name) that lies inside [w0, w1];
+    an event with nothing inside goes."""
+    out = []
+    for start, duration, name in events:
+        s, e = max(start, w0), min(start + duration, w1)
+        if e > s:
+            out.append((s, e - s, name))
+    return out
+
+
+def whole_launches(modules, w0: float, w1: float):
+    """(the events of a modules line that lie wholly inside [w0, w1], how
+    many more an edge of it cuts). A cut launch would read as a short one."""
+    touching = [e for e in modules if e[0] < w1 and e[0] + e[1] > w0]
+    whole = [e for e in touching if w0 <= e[0] and e[0] + e[1] <= w1]
+    return whole, len(touching) - len(whole)
 
 
 def busy_seconds(intervals) -> float:
@@ -97,8 +126,9 @@ def seconds_per_launch(programs: dict, match: str) -> float | None:
     return sum(v[1] for v in hits) / launches if launches else None
 
 
-def read_device_events(trace_dir: str) -> dict:
-    """{plane: {line: [(start_s, duration_s, name)]}} of the device planes of
+def read_trace(trace_dir: str) -> tuple[dict, list]:
+    """({plane: {line: [(start_s, duration_s, name)]}} of the device planes,
+    [(start_s, end_s)] of every `bench.window` event of the host planes) of
     the newest trace under `trace_dir`."""
     from jax.profiler import ProfileData
 
@@ -107,9 +137,16 @@ def read_device_events(trace_dir: str) -> dict:
         key=os.path.getmtime,
     )
     if not paths:
-        return {}
-    planes = {}
+        return {}, []
+    planes, windows = {}, []
     for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name.startswith(HOST_PLANE_PREFIX):
+            windows += [
+                (ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9)
+                for line in plane.lines
+                for ev in line.events
+                if ev.name == WINDOW_EVENT
+            ]
         if not DEVICE_PLANE.match(plane.name):
             continue
         planes[plane.name] = {
@@ -120,39 +157,53 @@ def read_device_events(trace_dir: str) -> dict:
             for line in plane.lines
             if line.name in (OPS_LINE, MODULES_LINE)
         }
-    return planes
+    return planes, windows
 
 
-def reduce(planes: dict, window_s: float | None = None) -> dict | None:
+def reduce(planes: dict, window: tuple[float, float] | None = None) -> dict | None:
     """The summary the readers and the result line use, averaged over the
-    chips that ran anything. `window_s` is the traced window by the host's
-    clock; without it the window is from the first to the last device
-    event, which leaves out idle time at either end. None if no operation
-    ran on any device."""
+    chips that ran anything in the window. `window` is (start, end) on the
+    events' clock: operations are clipped to it, idle time at either end of
+    it is a gap like any other, and a launch that an edge cuts counts in
+    `launches_cut` and not in `programs`. `event_lead_s` and `event_tail_s`
+    say how far the device's events reach beyond it: on a device that is
+    never idle both are above 0, or the session did not cover the window.
+    Without a window it is from the first to the last device event, which
+    leaves out idle time at either end. None if no operation ran on any
+    device inside the window."""
     chips = [p for p in planes.values() if p.get(OPS_LINE)]
     if not chips:
         return None
-    starts = [e[0] for p in chips for e in p[OPS_LINE]]
-    ends = [e[0] + e[1] for p in chips for e in p[OPS_LINE]]
-    t0, t1 = min(starts), max(ends)
-    event_span_s = t1 - t0
-    window_s = window_s or event_span_s
-    busy = [busy_seconds(p[OPS_LINE]) for p in chips]
+    first = min(e[0] for p in chips for e in p[OPS_LINE])
+    last = max(e[0] + e[1] for p in chips for e in p[OPS_LINE])
+    w0, w1 = window or (first, last)
+    l0, l1 = window or (-math.inf, math.inf)  # no window cuts no launch
+    chips = [
+        (clip(p[OPS_LINE], w0, w1), *whole_launches(p.get(MODULES_LINE, []), l0, l1))
+        for p in chips
+    ]
+    chips = [chip for chip in chips if chip[0]]
+    if not chips:
+        return None
+    # min: the clipped intervals lie inside the window, and their summed
+    # lengths can pass its length by a rounding of the last digit
+    busy = [min(busy_seconds(ops), w1 - w0) for ops, _, _ in chips]
     busy_s = sum(busy) / len(chips)
-    fullest = max(chips, key=lambda p: busy_seconds(p[OPS_LINE]))
+    fullest = chips[busy.index(max(busy))][0]
     return {
-        "window_s": window_s,
-        "event_span_s": event_span_s,
+        "window_s": w1 - w0,
+        "event_span_s": last - first,
+        "event_lead_s": w0 - first,
+        "event_tail_s": last - w1,
         "busy_s": busy_s,
-        "idle_share": 1.0 - busy_s / window_s,
-        "programs": programs(
-            [e for p in chips for e in p.get(MODULES_LINE, [])]
-        ),
+        "idle_share": 1.0 - busy_s / (w1 - w0),
+        "programs": programs([e for _, whole, _ in chips for e in whole]),
+        "launches_cut": sum(cut for _, _, cut in chips),
         "device_ops": top_ops(
-            [e for p in chips for e in self_times(p[OPS_LINE])], 10
+            [e for ops, _, _ in chips for e in self_times(ops)], 10
         ),
         "idle_gaps": [
-            [f"unattributed@{s - t0:.3f}s", d]
-            for s, d in idle_gaps(fullest[OPS_LINE], t0, t1, 5)
+            [f"unattributed@{s - w0:.3f}s", d]
+            for s, d in idle_gaps(fullest, w0, w1, 5)
         ],
     }
