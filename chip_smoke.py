@@ -48,10 +48,11 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # tools/ holds the drive topology (scale_bench) and the differential tier
 sys.path[:0] = [ROOT, os.path.join(ROOT, "tools")]
 
-# The largest round size today's table layout serves from a 16 GB chip:
-# the probe tables hold 8n slots rounded up to a power of two, and every
-# launch copies them whole into a padded layout (ROADMAP S3). One tuple
-# past 2^20 doubles both and no longer fits.
+# The round size the smoke, the compile tests and the benchmark's two
+# configurations share: the probe tables hold 8n slots rounded up to a
+# power of two, 0.80 GB on the chip as 64-lane bucket rows. No launch
+# copies them any more (ROADMAP S3, done), so a larger store is a matter
+# of memory and of a new configuration (R1), not of this constant.
 DEFAULT_TUPLES = 1_000_000
 DEFAULT_SEED = 7
 RPC_TIMEOUT_S = 600.0

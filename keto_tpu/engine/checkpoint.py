@@ -43,7 +43,9 @@ FORMAT_VERSION = 4  # v4: backend-keyed table layout — the meta vector
 # because the two layouts place keys in DIFFERENT slots: a checkpoint
 # written under one layout loaded under the other would mis-probe every
 # table, so a layout mismatch degrades to a rebuild exactly like a
-# version mismatch.
+# version mismatch. (The code names where keys are PLACED; the shape a
+# pack is stored in, kernel.as_bucket_rows, is made from these columns
+# at upload and is no part of the file.)
 # v3: bucketized probe sequence (snapshot.probe_slot) — v2 files hold
 # tables built with the old (h1 + j*h2) slot layout and would mis-probe;
 # a version mismatch just triggers a rebuild.

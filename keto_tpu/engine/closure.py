@@ -1207,10 +1207,10 @@ class ClosureIndex:
             )
             self._view = None
             if build is not None and tables is not None:
-                import jax.numpy as jnp
+                from .kernel import device_table, device_tables
 
-                dev = {k: jnp.asarray(v) for k, v in tables.items()}
-                dev["cd_pack"] = jnp.asarray(empty_dirty_table())
+                dev = device_tables(tables)
+                dev["cd_pack"] = device_table(empty_dirty_table())
                 self._view = ClosureView(
                     dev, cc_probes, ch_probes, False,
                     build.snapshot_version, self._synced_version, graph.R,
@@ -1300,13 +1300,13 @@ class ClosureIndex:
                 self._stale = True
                 self.stats["rebuild_pending"] += 1
                 return False
-            import jax.numpy as jnp
+            from .kernel import device_table
 
             old = self._view
             tables = dict(old.tables) if old is not None else None
             if tables is None:
                 return False
-            tables["cd_pack"] = jnp.asarray(cd)
+            tables["cd_pack"] = device_table(cd)
             self._synced_version = max(self._synced_version, through_version)
             self._view = ClosureView(
                 tables, old.cc_probes, old.ch_probes, bool(self._dirty),
@@ -1419,9 +1419,9 @@ class ClosureIndex:
         )
         merged = self._merge_refresh(build, graph, keys, fresh)
         tables, cc_probes, ch_probes = pack_closure_tables(merged, graph.R)
-        import jax.numpy as jnp
+        from .kernel import device_table, device_tables
 
-        dev = {k: jnp.asarray(v) for k, v in tables.items()}
+        dev = device_tables(tables)
         with self._mu:
             if self._build is not build or self._stale:
                 return False
@@ -1455,7 +1455,7 @@ class ClosureIndex:
             if cd is None:
                 self._stale = True
                 return False
-            dev["cd_pack"] = jnp.asarray(cd)
+            dev["cd_pack"] = device_table(cd)
             self._view = ClosureView(
                 dev, cc_probes, ch_probes, bool(self._dirty),
                 merged.snapshot_version, self._synced_version, graph.R,

@@ -45,7 +45,8 @@ garbage passes GARBAGE_FRACTION.
 Future work: after a merge the engine re-uploads the
 full table set; the deltas are actually tiny (op slots in the hash
 tables + the CSR tail), so a jitted device-side scatter
-(`dh_pack.at[slots].set(rows)`) could cut the post-merge upload from
+(`dh_pack.at[slots // 8, ...].set(rows)`: the pack is stored as bucket
+rows, kernel.as_bucket_rows) could cut the post-merge upload from
 O(tables) to O(ops) — it needs headroom-padded edge arrays so the CSR
 tail append keeps shapes static, and slot tracking through
 _hash_insert. Worth it once the post-merge upload shows up in a profile

@@ -23,9 +23,11 @@ in-flight checks advance together as one frontier of tasks
 TPU-specific gather discipline (measured, tools/microbench2.py): a
 row-gather from a 2-D table moves its whole row for roughly the cost of
 one element (~15ns/row on v5e), while N per-column gathers pay N times.
-So every hash table lives on device as PACKED interleaved rows —
-[cap, 8] for the 5-key edge tables, [cap, 4] for (obj, rel)->value —
-and each logical lookup is ONE [F, P, row]-shaped row-gather, fenced
+So every hash table lives on device as PACKED interleaved slots —
+8 lanes for the 5-key edge tables, 4 for (obj, rel)->value — stored and
+placed as the 64-lane bucket rows a probe fetches ([cap/spb, spb*w]:
+as_bucket_rows, device_table), so no program relays a table out before
+it gathers; each logical lookup is ONE [F, PB, row]-shaped row-gather, fenced
 with optimization_barrier so XLA emits its fast standalone gather
 kernel instead of scalarizing it inside a fusion. All probe rounds/
 slots batch into one wide trailing index dim per lookup.
@@ -54,6 +56,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from .delta import DELTA_PROBES, DIRTY_FOR_CHECK, empty_delta_tables
 from .snapshot import (
@@ -229,11 +233,13 @@ def _bucket_rows(pack: jnp.ndarray, h1: jnp.ndarray, h2: jnp.ndarray,
                  probes: int, spb: int) -> jnp.ndarray:
     """Gather every table row a probe chain of `probes` slots can touch,
     as BUCKET rows: the device twin of snapshot.probe_slot's bucketized
-    sequence. `pack` is [cap, w]; slots j = 0..probes-1 live in buckets
-    (h1 + (j//spb)*h2) mod (cap/spb), spb consecutive slots each, so
-    PB = ceil(probes/spb) bucket-row gathers of 64 ints (256 B) cover
-    the chain. Returns [..., PB*spb, w] slot rows (leading dims = h1's
-    shape).
+    sequence. `pack` is stored as those bucket rows, [cap/spb, spb*w]
+    (as_bucket_rows), so the gather indexes its operand as it lies in
+    memory and no launch relays a table out; slots j = 0..probes-1 live
+    in buckets (h1 + (j//spb)*h2) mod (cap/spb), spb consecutive slots
+    each, so PB = ceil(probes/spb) bucket-row gathers of 64 ints (256 B)
+    cover the chain. Returns [..., PB*spb, w] slot rows (leading dims =
+    h1's shape).
 
     `spb` MUST be snapshot.slots_per_bucket(n_key_cols) for the probed
     table — each probe helper passes it from the same single source the
@@ -246,23 +252,24 @@ def _bucket_rows(pack: jnp.ndarray, h1: jnp.ndarray, h2: jnp.ndarray,
     rows do NOT coalesce): one spb-slot bucket row per spb probe slots
     instead of one slot row per probe — the dominant per-step cost
     divides by ~min(probes, spb)."""
-    cap, w = pack.shape
-    nb = cap // spb
+    nb, row = pack.shape
+    w = row // spb
     PB = (probes + spb - 1) // spb
     jb = jnp.arange(PB, dtype=jnp.uint32)
     bidx = ((h1[..., None] + jb * h2[..., None]) & jnp.uint32(nb - 1)).astype(
         jnp.int32
     )  # [..., PB]
-    rows = _isolate(pack.reshape(nb, spb * w)[bidx])  # [..., PB, spb*w]
+    rows = _isolate(pack[bidx])  # [..., PB, spb*w]
     return rows.reshape(*h1.shape, PB * spb, w)
 
 
 def _edge_key_probe(tables, prefix, obj, rel, skind, sa, sb, probes: int,
                     key=None):
-    """Probe a 5-key edge hash table stored as PACKED rows
-    `{prefix}_pack[cap, 8]` = (obj, rel, skind, sa, sb, val, pad, pad),
-    fetched as [F, PB, 64] bucket rows (_bucket_rows) — ONE gathered row
-    per 8 slots of probe depth, the measured round-5 cost lever.
+    """Probe a 5-key edge hash table stored as PACKED slots
+    (obj, rel, skind, sa, sb, val, pad, pad), 8 to a bucket row of
+    `{prefix}_pack[cap/8, 64]`, fetched as [F, PB, 64] bucket rows
+    (_bucket_rows) — ONE gathered row per 8 slots of probe depth, the
+    measured round-5 cost lever.
 
     Matching compares WHOLE rows against a [F, 8] key matrix (lanes >= 5
     auto-pass; the value rides lane 5 of the same masked reduce), which
@@ -298,8 +305,8 @@ def edge_probe_key(obj, rel, skind, sa, sb) -> jnp.ndarray:
 
 def _multi_pair_key_probe(tables, prefix, obj, rels, probes: int,
                           n_vals: int = 1):
-    """Probe a (obj, rel)-keyed packed table `{prefix}_pack[cap, 4]` =
-    (obj, rel, val, val2/pad) for MANY relations per task at once.
+    """Probe a (obj, rel)-keyed packed table `{prefix}_pack[cap/16, 64]`
+    of (obj, rel, val, val2/pad) slots for MANY relations per task at once.
     `rels` is a [F, S] relation matrix; returns the [F, S] value matrix
     (EMPTY = miss), or with `n_vals=2` a [F, S, 2] matrix carrying BOTH
     value lanes (the rh span table stores (row_start, row_end) so the
@@ -342,49 +349,91 @@ def dirty_lookup(tables, obj, rel):
     return jnp.maximum(val, 0)
 
 
-def pack_edge_table(obj, rel, skind, sa, sb, val) -> np.ndarray:
-    """Interleave six edge-table columns into [cap, 8] rows (pad lanes
-    zeroed) — the device layout every 5-key probe gathers."""
-    import numpy as _np
+BUCKET_ROW_BYTES = 256  # one gathered bucket row (snapshot.slots_per_bucket)
 
-    cap = obj.shape[0]
-    out = _np.zeros((cap, 8), dtype=_np.int32)
-    for i, col in enumerate((obj, rel, skind, sa, sb, val)):
+
+def as_bucket_rows(slots: np.ndarray, n_key_cols: int) -> np.ndarray:
+    """THE stored shape of a hash-probed table: [cap, w] slot rows seen
+    as the [cap/spb, spb*w] bucket rows _bucket_rows gathers (64 lanes,
+    256 B under the bucketized layout; [cap, w] itself where spb is 1).
+    Row-major, so this is a view and the slot order is probe_slot's.
+    Every probed `*_pack` goes through here on its way to the device —
+    a table stored one way and probed another cannot be built."""
+    cap, w = slots.shape
+    spb = slots_per_bucket(n_key_cols)
+    return slots.reshape(cap // spb, spb * w)
+
+
+def bucket_row_layout(shape, dtype):
+    """How a table of this shape has to lie on the device: row-major
+    (a jax Layout) where its rows are whole 256 B bucket rows, which the
+    kernels gather one row at a time; None for narrower tables, whose
+    few columns are read as columns and keep the client's choice."""
+    if len(shape) < 2 or shape[-1] * np.dtype(dtype).itemsize < BUCKET_ROW_BYTES:
+        return None
+    return Layout(major_to_minor=tuple(range(len(shape))))
+
+
+def device_table(host, sharding=None) -> jax.Array:
+    """One host table onto the device (the default one, or as `sharding`
+    says), lying the way the kernels read it (bucket_row_layout). Left to
+    itself the TPU client stores a [n, 64] int32 array column-major (no
+    lane padding that way), and every program that gathers bucket rows
+    from it first copies the whole table back to row-major, once a
+    launch. A committed array carries its layout into every jit that
+    takes it, so nothing is said at the kernels."""
+    a = jnp.asarray(host) if sharding is None else jax.device_put(host, sharding)
+    layout = bucket_row_layout(a.shape, a.dtype)
+    if layout is not None:
+        a = jax.device_put(a, Format(layout, a.sharding))
+    return a
+
+
+def device_tables(host: dict, sharding=None) -> dict:
+    return {k: device_table(v, sharding) for k, v in host.items()}
+
+
+def _lane_rows(width: int, cols) -> np.ndarray:
+    """Interleave columns into [n, width] int32 rows (pad lanes zeroed)."""
+    out = np.zeros((cols[0].shape[0], width), dtype=np.int32)
+    for i, col in enumerate(cols):
         out[:, i] = col
     return out
+
+
+def pack_edge_table(obj, rel, skind, sa, sb, val) -> np.ndarray:
+    """Interleave six edge-table columns into 8-lane slot rows (pad
+    lanes zeroed), stored as bucket rows (as_bucket_rows) — the device
+    layout every 5-key probe gathers."""
+    return as_bucket_rows(_lane_rows(8, (obj, rel, skind, sa, sb, val)), 5)
 
 
 def pack_pair_table(obj, rel, val) -> np.ndarray:
-    """Interleave three (obj, rel)->val columns into [cap, 4] rows."""
-    import numpy as _np
+    """Interleave three (obj, rel)->val columns into 4-lane slot rows,
+    stored as bucket rows (as_bucket_rows)."""
+    return as_bucket_rows(_lane_rows(4, (obj, rel, val)), 2)
 
-    cap = obj.shape[0]
-    out = _np.zeros((cap, 4), dtype=_np.int32)
-    for i, col in enumerate((obj, rel, val)):
-        out[:, i] = col
-    return out
+
+def pack_row_table(a, b, c) -> np.ndarray:
+    """Three columns as [n, 4] rows for a table INDEXED by row (the CSR
+    edge rows rv_pack / fe_pack), never hash-probed: no bucket shape."""
+    return _lane_rows(4, (a, b, c))
 
 
 def pack_rh_span_table(rh_obj, rh_rel, rh_row, row_ptr) -> np.ndarray:
-    """(obj, rel) -> CSR span packed as [cap, 4] rows
-    (obj, rel, row_start, row_end): resolving row_ptr at PACK time means
-    the kernel's row lookup needs zero extra gathers — the span rides
-    the probe's own bucket-row fetch (EMPTY rows pack (-1, -1))."""
-    import numpy as _np
-
-    cap = rh_obj.shape[0]
-    out = _np.zeros((cap, 4), dtype=_np.int32)
-    out[:, 0] = rh_obj
-    out[:, 1] = rh_rel
+    """(obj, rel) -> CSR span packed as 4-lane slot rows
+    (obj, rel, row_start, row_end), stored as bucket rows: resolving
+    row_ptr at PACK time means the kernel's row lookup needs zero extra
+    gathers — the span rides the probe's own bucket-row fetch (EMPTY
+    rows pack (-1, -1))."""
     valid = rh_row != EMPTY
     if row_ptr.shape[0] >= 2:
-        rc = _np.clip(rh_row, 0, row_ptr.shape[0] - 2)
-        out[:, 2] = _np.where(valid, row_ptr[rc], EMPTY)
-        out[:, 3] = _np.where(valid, row_ptr[rc + 1], EMPTY)
+        rc = np.clip(rh_row, 0, row_ptr.shape[0] - 2)
+        start = np.where(valid, row_ptr[rc], EMPTY)
+        end = np.where(valid, row_ptr[rc + 1], EMPTY)
     else:
-        out[:, 2] = EMPTY
-        out[:, 3] = EMPTY
-    return out
+        start = end = np.full(rh_obj.shape[0], EMPTY, dtype=np.int32)
+    return as_bucket_rows(_lane_rows(4, (rh_obj, rh_rel, start, end)), 2)
 
 
 def pack_instr_table(instr_kind, instr_rel, instr_rel2) -> np.ndarray:
@@ -1212,18 +1261,17 @@ def snapshot_tables(snapshot: GraphSnapshot, delta: dict | None = None) -> dict:
     delta-overlay tables default to empty (fixed shapes either way)."""
     raw = dict(snapshot.device_arrays())
     raw.update(delta or empty_delta_tables())
-    return {k: jnp.asarray(v) for k, v in pack_raw_tables(raw).items()}
+    return device_tables(pack_raw_tables(raw))
 
 
 def refresh_delta_tables(tables: dict, delta: dict, vocab_arrays: dict) -> dict:
     """New table dict with only the overlay (and the vocab-dependent
     objslot_ns / ns_has_config arrays, which grow with delta vocab) re-
     uploaded; the big compacted tables are reused as-is."""
-    out = dict(tables)
-    for k, v in vocab_arrays.items():
-        out[k] = jnp.asarray(v)
-    out.update({k: jnp.asarray(v) for k, v in pack_delta_tables(delta).items()})
-    return out
+    return {
+        **tables,
+        **device_tables({**vocab_arrays, **pack_delta_tables(delta)}),
+    }
 
 
 def kernel_static_config(

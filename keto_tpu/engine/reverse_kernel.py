@@ -79,7 +79,7 @@ from .kernel import (
     empty_launch_stats,
     flag_phase,
     pack_instr_table,
-    pack_pair_table,
+    pack_row_table,
     pack_rh_span_table,
     program_lookup,
     scan_seg_map_backend,
@@ -182,7 +182,7 @@ def pack_reverse_tables(rnp: dict, snapshot: GraphSnapshot) -> dict:
         "rvh_pack": pack_rh_span_table(
             rnp["rvh_obj"], rnp["rvh_rel"], rnp["rvh_row"], rnp["rv_row_ptr"]
         ),
-        "rv_pack": pack_pair_table(rnp["rv_pobj"], rnp["rv_prel"], rnp["rv_sb"]),
+        "rv_pack": pack_row_table(rnp["rv_pobj"], rnp["rv_prel"], rnp["rv_sb"]),
         "rsh_pack": pack_rh_span_table(
             rnp["rsh_obj"], rnp["rsh_tag"], rnp["rsh_row"], rnp["rs_row_ptr"]
         ),
@@ -207,7 +207,7 @@ def pack_subjects_tables(csr: dict, snapshot: GraphSnapshot) -> dict:
         "fsh_pack": pack_rh_span_table(
             csr["fh_obj"], csr["fh_rel"], csr["fh_row"], csr["f_row_ptr"]
         ),
-        "fe_pack": pack_pair_table(csr["f_skind"], csr["f_sa"], csr["f_sb"]),
+        "fe_pack": pack_row_table(csr["f_skind"], csr["f_sa"], csr["f_sb"]),
         "instr_pack": pack_instr_table(
             snapshot.instr_kind, snapshot.instr_rel, snapshot.instr_rel2
         ),

@@ -487,17 +487,17 @@ class TPUCheckEngine:
             "ns_has_config": overlay.ns_has_config,
         }
         if self.mesh is not None:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from .kernel import pack_delta_tables
+            from .kernel import device_tables, pack_delta_tables
 
             sharded_tables, replicated = state.tables
-            replicated = dict(replicated)
             packed = dict(vocab_arrays)
             packed.update(pack_delta_tables(delta))
-            for k, v in packed.items():
-                replicated[k] = jax.device_put(v, NamedSharding(self.mesh, P()))
+            replicated = {
+                **replicated,
+                **device_tables(packed, NamedSharding(self.mesh, P())),
+            }
             tables = (sharded_tables, replicated)
         else:
             tables = refresh_delta_tables(state.tables, delta, vocab_arrays)
@@ -517,14 +517,13 @@ class TPUCheckEngine:
         # and overlay extension re-derive from the fresh delta (O(delta))
         if state.expand_tables is not None:
             if self.mesh is not None:
-                import jax
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
-                from .kernel import pack_delta_tables
+                from .kernel import device_table, pack_delta_tables
 
                 sharded_csr, _ = state.expand_tables
                 fresh_dirty = {
-                    "dirty_pack": jax.device_put(
+                    "dirty_pack": device_table(
                         pack_delta_tables(delta)["dirty_pack"],
                         NamedSharding(self.mesh, P()),
                     )
@@ -654,19 +653,14 @@ class TPUCheckEngine:
     @staticmethod
     def _pack_expand_csr(csr: dict) -> dict:
         """Host full-CSR arrays -> the expand kernel's device table dict."""
-        import jax.numpy as jnp
+        from .kernel import device_tables, pack_pair_table
 
-        from .kernel import pack_pair_table
-
-        return {
-            "fh_pack": jnp.asarray(pack_pair_table(
+        return device_tables({
+            "fh_pack": pack_pair_table(
                 csr["fh_obj"], csr["fh_rel"], csr["fh_row"]
-            )),
-            "f_row_ptr": jnp.asarray(csr["f_row_ptr"]),
-            "f_skind": jnp.asarray(csr["f_skind"]),
-            "f_sa": jnp.asarray(csr["f_sa"]),
-            "f_sb": jnp.asarray(csr["f_sb"]),
-        }
+            ),
+            **{k: csr[k] for k in ("f_row_ptr", "f_skind", "f_sa", "f_sb")},
+        })
 
     def _patched_expand_state(self, state: _EngineState, enc_u, ins_u):
         """Patch the retained host full-CSR mirror with the merged ops
@@ -786,22 +780,18 @@ class TPUCheckEngine:
             "rs_obj": rs_obj, "rs_rel": rs_rel,
             "garbage": total_garbage,
         }
-        import jax.numpy as jnp
+        from .kernel import device_tables
 
-        tables = {
-            k: jnp.asarray(v)
-            for k, v in pack_reverse_tables(reverse_np, merged).items()
-        }
-        return reverse_np, tables
+        return reverse_np, device_tables(
+            pack_reverse_tables(reverse_np, merged)
+        )
 
     @staticmethod
     def _merge_expand_dirty(base_csr: dict, delta_np: dict) -> dict:
-        import jax.numpy as jnp
-
-        from .kernel import pack_delta_tables
+        from .kernel import device_table, pack_delta_tables
 
         merged = dict(base_csr)
-        merged["dirty_pack"] = jnp.asarray(
+        merged["dirty_pack"] = device_table(
             pack_delta_tables(delta_np)["dirty_pack"]
         )
         return merged
@@ -810,12 +800,10 @@ class TPUCheckEngine:
     def _merge_reverse_dirty(base_tables: dict, delta_np: dict) -> dict:
         """Reverse-kernel tables + the delta's reverse-dirty (rd) overlay
         — only the small rd pack re-uploads on a delta refresh."""
-        import jax.numpy as jnp
-
-        from .kernel import pack_pair_table
+        from .kernel import device_table, pack_pair_table
 
         merged = {k: v for k, v in base_tables.items() if k != "rd_pack"}
-        merged["rd_pack"] = jnp.asarray(
+        merged["rd_pack"] = device_table(
             pack_pair_table(
                 delta_np["rd_obj"], delta_np["rd_tag"], delta_np["rd_val"]
             )
@@ -824,12 +812,10 @@ class TPUCheckEngine:
 
     @staticmethod
     def _merge_subjects_dirty(base_tables: dict, delta_np: dict) -> dict:
-        import jax.numpy as jnp
-
-        from .kernel import pack_pair_table
+        from .kernel import device_table, pack_pair_table
 
         merged = {k: v for k, v in base_tables.items() if k != "dirty_pack"}
-        merged["dirty_pack"] = jnp.asarray(
+        merged["dirty_pack"] = device_table(
             pack_pair_table(
                 delta_np["dirty_obj"], delta_np["dirty_rel"],
                 delta_np["dirty_val"],
@@ -1016,7 +1002,7 @@ class TPUCheckEngine:
             tables,
             key=lambda k: int(getattr(tables[k], "nbytes", 0) or 0),
         )
-        import jax.numpy as jnp
+        from .kernel import device_table
 
         host = np.asarray(tables[key]).copy()
         flat = host.reshape(-1).view(np.uint8)
@@ -1025,7 +1011,7 @@ class TPUCheckEngine:
         flat[bit // 8 % flat.size] ^= np.uint8(1 << (bit % 8))
         with self._lock:
             if self._state is state:  # don't poison a successor state
-                tables[key] = jnp.asarray(host)
+                tables[key] = device_table(host)
         self.stats["mirror_corruptions"] = (
             self.stats.get("mirror_corruptions", 0) + 1
         )
@@ -1320,9 +1306,8 @@ class TPUCheckEngine:
         state = self._ensure_state_degraded_ok("list")[0]
         if state.reverse_tables is not None:
             return state
-        import jax.numpy as jnp
-
         from .expand_kernel import ExpandDecoder
+        from .kernel import device_tables
         from .reverse_kernel import (
             build_reverse_state,
             build_reverse_state_columnar,
@@ -1348,10 +1333,7 @@ class TPUCheckEngine:
             if state.base_decoder is None:
                 state.base_decoder = ExpandDecoder(state.snapshot)
                 state.decoder = state.base_decoder.extended(state.view.overlay)
-            tables = {
-                k: jnp.asarray(v)
-                for k, v in pack_reverse_tables(rnp, state.snapshot).items()
-            }
+            tables = device_tables(pack_reverse_tables(rnp, state.snapshot))
             # reverse_tables is the readiness signal: set it last
             state.reverse_tables = self._merge_reverse_dirty(
                 tables, state.delta_np
@@ -1369,13 +1351,12 @@ class TPUCheckEngine:
             return state
         if self.mesh is None:
             state = self._ensure_expand_state()
-        import jax.numpy as jnp
-
         from .expand_kernel import (
             ExpandDecoder,
             build_full_csr,
             build_full_csr_columnar,
         )
+        from .kernel import device_tables
         from .reverse_kernel import pack_subjects_tables
 
         with self._lock:
@@ -1398,10 +1379,7 @@ class TPUCheckEngine:
             if state.base_decoder is None:
                 state.base_decoder = ExpandDecoder(state.snapshot)
                 state.decoder = state.base_decoder.extended(state.view.overlay)
-            tables = {
-                k: jnp.asarray(v)
-                for k, v in pack_subjects_tables(csr, state.snapshot).items()
-            }
+            tables = device_tables(pack_subjects_tables(csr, state.snapshot))
             state.subjects_tables = self._merge_subjects_dirty(
                 tables, state.delta_np
             )

@@ -267,17 +267,20 @@ def get_sharded_expand_kernel(mesh: Mesh, statics: tuple, axis: str = "x"):
 def place_sharded_expand_tables(
     stacked: dict, delta_np: dict, mesh: Mesh, axis: str = "x"
 ) -> tuple[dict, dict]:
-    import numpy as np
-
-    from ..engine.kernel import pack_pair_table
+    from ..engine.kernel import (
+        device_table,
+        pack_delta_tables,
+        pack_pair_table,
+    )
+    from .kernel import stack_shard_packs
 
     assert set(stacked) == set(_EXPAND_SHARDED_KEYS)
-    n = stacked["fh_obj"].shape[0]
-    fh_pack = np.zeros((n, stacked["fh_obj"].shape[1], 4), dtype=np.int32)
-    for i in range(n):
-        fh_pack[i] = pack_pair_table(
+    fh_pack = stack_shard_packs(
+        stacked["fh_obj"].shape[0],
+        lambda i: pack_pair_table(
             stacked["fh_obj"][i], stacked["fh_rel"][i], stacked["fh_row"][i]
-        )
+        ),
+    )
     raw = {
         "fh_pack": fh_pack,
         "f_row_ptr": stacked["f_row_ptr"],
@@ -286,15 +289,13 @@ def place_sharded_expand_tables(
         "f_sb": stacked["f_sb"],
     }
     sharded = {
-        k: jax.device_put(
+        k: device_table(
             v, NamedSharding(mesh, P(axis, *([None] * (v.ndim - 1))))
         )
         for k, v in raw.items()
     }
-    from ..engine.kernel import pack_delta_tables
-
     replicated = {
-        "dirty_pack": jax.device_put(
+        "dirty_pack": device_table(
             pack_delta_tables(delta_np)["dirty_pack"],
             NamedSharding(mesh, P()),
         )

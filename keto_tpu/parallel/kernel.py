@@ -202,6 +202,22 @@ def sharded_static_config(
     )
 
 
+def stack_shard_packs(n: int, pack_shard):
+    """[n, *pack] stack of `pack_shard(i)` for i < n, in whatever shape
+    the builder stores a pack (kernel.as_bucket_rows). Preallocates and
+    packs in place: a list-of-arrays + np.stack would hold a second full
+    copy of the dominant tables at peak (GBs at 1e8 edges)."""
+    import numpy as np
+
+    first = pack_shard(0)
+    out = np.zeros((n, *first.shape), dtype=first.dtype)
+    out[0] = first
+    del first
+    for i in range(1, n):
+        out[i] = pack_shard(i)
+    return out
+
+
 def place_sharded_tables(
     snap: ShardedSnapshot, mesh: Mesh, axis: str = "x",
     release_columns: bool = False,
@@ -219,6 +235,8 @@ def place_sharded_tables(
     import numpy as np
 
     from ..engine.kernel import (
+        device_table,
+        device_tables,
         pack_edge_table,
         pack_rh_span_table,
     )
@@ -227,31 +245,25 @@ def place_sharded_tables(
     n = s["dh_obj"].shape[0]
 
     def put_sharded(v):
-        return jax.device_put(
+        return device_table(
             v, NamedSharding(mesh, P(axis, *([None] * (v.ndim - 1))))
         )
 
     sharded = {}
-    # preallocate + pack in place: a list-of-arrays + np.stack would hold
-    # a second full copy of the dominant tables at peak (GBs at 1e8 edges)
-    dh_pack = np.zeros((n, s["dh_obj"].shape[1], 8), dtype=np.int32)
-    for i in range(n):
-        dh_pack[i] = pack_edge_table(
-            s["dh_obj"][i], s["dh_rel"][i], s["dh_skind"][i],
-            s["dh_sa"][i], s["dh_sb"][i], s["dh_val"][i],
-        )
+    dh_pack = stack_shard_packs(n, lambda i: pack_edge_table(
+        s["dh_obj"][i], s["dh_rel"][i], s["dh_skind"][i],
+        s["dh_sa"][i], s["dh_sb"][i], s["dh_val"][i],
+    ))
     if release_columns:
         for k in ("dh_obj", "dh_rel", "dh_skind", "dh_sa", "dh_sb", "dh_val"):
             s[k] = None
     sharded["dh_pack"] = put_sharded(dh_pack)
     del dh_pack
 
-    rh_pack = np.zeros((n, s["rh_obj"].shape[1], 4), dtype=np.int32)
-    for i in range(n):
-        # per-shard row_ptr resolves into the span lanes at pack time
-        rh_pack[i] = pack_rh_span_table(
-            s["rh_obj"][i], s["rh_rel"][i], s["rh_row"][i], s["row_ptr"][i]
-        )
+    # per-shard row_ptr resolves into the span lanes at pack time
+    rh_pack = stack_shard_packs(n, lambda i: pack_rh_span_table(
+        s["rh_obj"][i], s["rh_rel"][i], s["rh_row"][i], s["row_ptr"][i]
+    ))
     if release_columns:
         for k in ("rh_obj", "rh_rel", "rh_row", "row_ptr"):
             s[k] = None
@@ -267,10 +279,7 @@ def place_sharded_tables(
     sharded["e_pack"] = put_sharded(e_pack)
     del e_pack
 
-    replicated = {
-        k: jax.device_put(v, NamedSharding(mesh, P()))
-        for k, v in snap.replicated.items()
-    }
+    replicated = device_tables(snap.replicated, NamedSharding(mesh, P()))
     return sharded, replicated
 
 

@@ -1,0 +1,177 @@
+"""The stored shape of a hash-probed table (kernel.as_bucket_rows).
+
+Every `*_pack` a kernel probes is built, uploaded and kept as the bucket
+rows `_bucket_rows` gathers: `[cap / spb, spb * w]`, with
+`spb = snapshot.slots_per_bucket(n_key_cols)`. These cases hold the three
+builders every such table goes through to that shape under both layouts,
+and hold the device's probe to the host's: the buckets `_bucket_rows`
+fetches are the slots `snapshot.probe_slot` walks, so the kernel's probes
+find exactly what `compact.py`'s host probe finds.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from keto_tpu.engine import compact, kernel, snapshot
+from keto_tpu.engine.snapshot import EMPTY
+
+N_KEYS = 300  # present keys; as many absent ones are probed beside them
+
+
+class Table:
+    """One built table: its columns, and the pack its builder made."""
+
+    def __init__(self, builder: str, rng: np.random.Generator):
+        self.builder = builder
+        self.n_key_cols = 5 if builder == "edge" else 2
+        self.width = 8 if builder == "edge" else 4
+        # distinct keys: the first half goes into the table, the rest stays out
+        drawn = np.unique(
+            rng.integers(0, 1 << 20, size=(4 * N_KEYS, self.n_key_cols)), axis=0
+        )
+        drawn = drawn[rng.permutation(len(drawn))][: 2 * N_KEYS].astype(np.int32)
+        self.present, self.absent = drawn[:N_KEYS], drawn[N_KEYS:]
+        self.values = np.arange(N_KEYS, dtype=np.int32)
+        *self.cols, self.probes = snapshot._build_hash_table(
+            tuple(self.present[:, i] for i in range(self.n_key_cols)), self.values
+        )
+        self.cap = len(self.cols[0])
+        self.spb = snapshot.slots_per_bucket(self.n_key_cols)
+        if builder == "edge":
+            self.pack = kernel.pack_edge_table(*self.cols)
+            self.slots = self.cols
+        elif builder == "pair":
+            self.pack = kernel.pack_pair_table(*self.cols)
+            self.slots = self.cols
+        else:  # the value is a CSR row; its span rides the two value lanes
+            self.row_ptr = np.concatenate(
+                [[0], np.cumsum(rng.integers(1, 9, size=N_KEYS))]
+            ).astype(np.int32)
+            self.pack = kernel.pack_rh_span_table(*self.cols, self.row_ptr)
+            row = self.cols[2]
+            held = row != EMPTY
+            self.slots = [
+                self.cols[0], self.cols[1],
+                np.where(held, self.row_ptr[np.clip(row, 0, None)], EMPTY),
+                np.where(held, self.row_ptr[np.clip(row, 0, None) + 1], EMPTY),
+            ]
+
+    def queries(self) -> np.ndarray:
+        return np.concatenate([self.present, self.absent])
+
+    def hashes(self, keys: np.ndarray):
+        h1 = snapshot.hash_combine(*(keys[:, i] for i in range(self.n_key_cols)))
+        return h1, snapshot.mix32(h1 ^ snapshot._GOLDEN) | np.uint32(1)
+
+    def host_probe(self, key: np.ndarray) -> int:
+        """The slot holding `key` on snapshot.probe_slot's walk, or -1: the
+        walk of compact._host_row_lookup, for any number of key columns."""
+        h1, h2 = self.hashes(key[None, :])
+        for j in range(self.probes):
+            slot = int(snapshot.probe_slot(h1, h2, np.uint32(j), self.cap, self.spb)[0])
+            if all(self.cols[i][slot] == key[i] for i in range(self.n_key_cols)):
+                return slot
+            if self.cols[0][slot] == EMPTY:
+                return -1
+        return -1
+
+
+@pytest.fixture(params=["compact", "bucketized"])
+def layout(request, monkeypatch):
+    monkeypatch.setattr(snapshot, "_TABLE_LAYOUT", request.param)
+    return request.param
+
+
+@pytest.fixture(params=["edge", "pair", "rh_span"])
+def table(request, layout):
+    return Table(request.param, np.random.default_rng(29))
+
+
+def test_pack_is_stored_as_bucket_rows(table, layout):
+    want_spb = 1 if layout == "compact" else 64 // table.width
+    assert table.spb == want_spb
+    assert table.pack.shape == (table.cap // table.spb, table.spb * table.width)
+    assert table.pack.dtype == np.int32
+
+
+def test_slot_rows_hold_what_the_columns_gave(table):
+    slots = table.pack.reshape(table.cap, table.width)
+    for lane, col in enumerate(table.slots):
+        np.testing.assert_array_equal(slots[:, lane], col)
+    assert not slots[:, len(table.slots):].any()  # pad lanes
+    again = kernel.as_bucket_rows(slots, table.n_key_cols)
+    np.testing.assert_array_equal(again, table.pack)
+
+
+def test_bucket_rows_are_the_slots_probe_slot_walks(table):
+    keys = table.queries()
+    h1, h2 = table.hashes(keys)
+    rows = np.asarray(kernel._bucket_rows(
+        kernel.device_table(table.pack), jnp.asarray(h1), jnp.asarray(h2),
+        table.probes, table.spb,
+    ))
+    walked = -(-table.probes // table.spb) * table.spb
+    assert rows.shape == (len(keys), walked, table.width)
+    slots = table.pack.reshape(table.cap, table.width)
+    for j in range(walked):
+        at = snapshot.probe_slot(h1, h2, np.uint32(j), table.cap, table.spb)
+        np.testing.assert_array_equal(rows[:, j], slots[at])
+
+
+def test_device_probe_finds_what_the_host_probe_finds(table):
+    keys = table.queries()
+    at = np.array([table.host_probe(k) for k in keys])
+    assert (at[:N_KEYS] >= 0).all() and (at[N_KEYS:] < 0).all()
+    tables = {"t_pack": kernel.device_table(table.pack)}
+    cols = [jnp.asarray(keys[:, i]) for i in range(table.n_key_cols)]
+    if table.builder == "edge":
+        found, val = kernel._edge_key_probe(tables, "t", *cols, table.probes)
+        np.testing.assert_array_equal(np.asarray(found), at >= 0)
+        want = np.where(at >= 0, table.cols[5][np.clip(at, 0, None)], EMPTY)
+        np.testing.assert_array_equal(np.asarray(val), want)
+    elif table.builder == "pair":
+        val = kernel._multi_pair_key_probe(
+            tables, "t", cols[0], cols[1][:, None], table.probes
+        )[:, 0]
+        want = np.where(at >= 0, table.cols[2][np.clip(at, 0, None)], EMPTY)
+        np.testing.assert_array_equal(np.asarray(val), want)
+        single = kernel._pair_key_probe(tables, "t", cols[0], cols[1], table.probes)
+        np.testing.assert_array_equal(np.asarray(single), want)
+    else:
+        spans = np.asarray(kernel._multi_pair_key_probe(
+            tables, "t", cols[0], cols[1][:, None], table.probes, n_vals=2
+        ))[:, 0, :]
+        rows = np.array([
+            compact._host_row_lookup(*table.cols, table.probes, int(k[0]), int(k[1]))
+            for k in keys
+        ])
+        np.testing.assert_array_equal(rows >= 0, at >= 0)
+        held = rows >= 0
+        np.testing.assert_array_equal(spans[held, 0], table.row_ptr[rows[held]])
+        np.testing.assert_array_equal(spans[held, 1], table.row_ptr[rows[held] + 1])
+        assert (spans[~held] == EMPTY).all()
+
+
+@pytest.mark.parametrize(
+    "shape, row_major",
+    [
+        ((1024, 64), True),  # bucket rows
+        ((4, 512, 64), True),  # a stack of shards' bucket rows
+        ((8192, 8), False),  # slot rows of the compact layout
+        ((4099, 2), False),  # e_pack: two columns, read as columns
+        ((4096,), False),
+    ],
+)
+def test_only_bucket_rows_are_placed_row_major(shape, row_major):
+    """The TPU client stores [n, 64] int32 column-major by itself, and a
+    program that gathers rows from that first copies the whole table."""
+    layout = kernel.bucket_row_layout(shape, np.int32)
+    assert (layout is not None) == row_major
+    if row_major:
+        assert layout.major_to_minor == tuple(range(len(shape)))
+    placed = kernel.device_table(np.ones(shape, np.int32))
+    assert placed.shape == shape and bool(placed.committed) == row_major
+    np.testing.assert_array_equal(np.asarray(placed), 1)

@@ -22,6 +22,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 import pytest
+from jax.experimental.layout import Format
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import chip_smoke  # also puts tools/ on sys.path
@@ -135,11 +136,18 @@ def capture_launch(module, name: str, call) -> tuple[tuple, dict]:
 
 
 def described(tree, sharding_of):
-    """Shapes in place of arrays: a described device holds no array."""
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding_of(a)),
-        tree,
-    )
+    """Shapes in place of arrays: a described device holds no array. What
+    the engine placed itself (a committed array: `kernel.device_table`)
+    is described lying as that placed it, bucket rows row-major."""
+
+    def shape_of(a):
+        sharding = sharding_of(a)
+        layout = kernel.bucket_row_layout(np.shape(a), a.dtype)
+        if layout is not None and getattr(a, "committed", False):
+            sharding = Format(layout, sharding)
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding)
+
+    return jax.tree.map(shape_of, tree)
 
 
 def compile_for_chip(name, jitted, args, statics, sharding_of):
@@ -155,6 +163,14 @@ def compile_for_chip(name, jitted, args, statics, sharding_of):
     return compiled, memory
 
 
+def assert_no_table_relayout(memory):
+    """A copy of a probe table inside the program shows as `temp` the size
+    of the tables (9.14 GB a check launch, 4.57 GB an expand or a
+    list-subjects, before the tables were stored as bucket rows)."""
+    assert memory.temp_size_in_bytes < 1024**3
+    assert memory.temp_size_in_bytes < memory.argument_size_in_bytes
+
+
 def smoke_batch(drive):
     queries, _ = chip_smoke.view_queries(drive, np.random.default_rng(1), 2048)
     return queries
@@ -163,13 +179,15 @@ def smoke_batch(drive):
 def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     """The smoke's largest check launch, its 2,048-item BatchCheck (bucket
     4,096, frontier 16,384), with tables and working set inside one v5e's
-    16 GiB. Today most of `temp` is the per-launch relayout of dh_pack and
-    rh_pack (ROADMAP S3); the layout fix has this number to beat."""
+    16 GiB. dh_pack and rh_pack are stored as the 64-lane bucket rows the
+    kernel gathers, so no launch relays them out: `temp` holds the frontier's
+    working set and does not follow the tables' rows (ROADMAP S3)."""
     tables_and_queries, statics = capture_launch(
         kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
     )
     tables, qpack = tables_and_queries
-    assert tables["dh_pack"].shape == (1 << 23, 8)
+    assert tables["dh_pack"].shape == (1 << 20, 64)
+    assert tables["rh_pack"].shape == (1 << 19, 64)
     assert qpack.shape == (7, 4096) and statics["frontier_cap"] == 16384
     _, memory = compile_for_chip(
         "check_kernel_packed", kernel.check_kernel_packed, tables_and_queries,
@@ -178,6 +196,7 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print(f"argument + temp = {total} of {V5E_HBM_BYTES}")
     assert total < V5E_HBM_BYTES
+    assert_no_table_relayout(memory)
 
 
 @pytest.fixture(scope="module")
@@ -244,6 +263,8 @@ def test_kernel_compiles_for_the_chip(
         name, getattr(module, name), args, statics, lambda a: one_chip
     )
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_HBM_BYTES
+    if name in ("expand_kernel_packed", "list_subjects_kernel_packed"):
+        assert_no_table_relayout(memory)
 
 
 @pytest.fixture(scope="module")

@@ -109,7 +109,8 @@ def main() -> int:
 
     variants["empty"] = loopify(lambda o, sink: sink + (o[0] & 1))
 
-    # calibration: k standalone row-gathers from the big packed table
+    # calibration: k standalone bucket-row gathers from the big packed table
+    # (dh_pack is stored as its [cap/8, 64] bucket rows)
     def gather_k(k):
         def body(o, sink):
             acc = sink

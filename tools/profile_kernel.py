@@ -95,8 +95,8 @@ def main() -> int:
                 "rh_probes": statics["rh_probes"],
                 "K": statics["K"],
                 "max_steps": statics["max_steps"],
-                "dh_cap": int(tables["dh_pack"].shape[0]),
-                "rh_cap": int(tables["rh_pack"].shape[0]),
+                "dh_cap": int(snap.dh_obj.shape[0]),
+                "rh_cap": int(snap.rh_obj.shape[0]),
                 "n_edges": int(tables["e_pack"].shape[0]),
                 "device": str(jax.devices()[0]),
             }

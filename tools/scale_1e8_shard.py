@@ -230,7 +230,11 @@ def tpu_phase(args) -> int:
     import jax
 
     from keto_tpu.engine.delta import empty_delta_tables
-    from keto_tpu.engine.kernel import check_kernel_packed, pack_delta_tables
+    from keto_tpu.engine.kernel import (
+        check_kernel_packed,
+        device_tables,
+        pack_delta_tables,
+    )
 
     record: dict = {"phase": "tpu"}
     with open(os.path.join(args.out, "statics.json")) as f:
@@ -249,9 +253,8 @@ def tpu_phase(args) -> int:
     tables_np = {**shard, **repl, **pack_delta_tables(empty_delta_tables())}
     host_bytes = int(sum(v.nbytes for v in tables_np.values()))
     t0 = time.perf_counter()
-    tables = {}
-    for k, v in tables_np.items():
-        tables[k] = jax.device_put(v, dev)
+    # the probe tables are stored as bucket rows and placed row-major
+    tables = device_tables(tables_np)
     jax.block_until_ready(list(tables.values()))
     record["device_put_s"] = round(time.perf_counter() - t0, 1)
     record["device_table_bytes"] = host_bytes
