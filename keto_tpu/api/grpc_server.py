@@ -361,16 +361,21 @@ class _Services:
         # this RPC's trace itself (check_batch reads the contextvar)
         results = engine.check_batch(tuples, int(req.max_depth))
         with self.metrics.stage("respond", rt):
-            obs = self.registry.workload_observatory()
-            for pos, (i, r) in enumerate(zip(idx, results)):
+            answered: list[RelationTuple] = []
+            verdicts: list[bool] = []
+            for i, t, r in zip(idx, tuples, results):
                 if r.error is not None:
                     out[i] = pb.BatchCheckResult(allowed=False, error=str(r.error))
                 else:
-                    out[i] = pb.BatchCheckResult(allowed=r.allowed)
-                    if obs is not None:
-                        # per-item workload accounting (the batch bypasses
-                        # the single-check serve gate; no per-item tier)
-                        obs.record_check(nid, tuples[pos], r.allowed)
+                    allowed = r.allowed
+                    out[i] = pb.BatchCheckResult(allowed=allowed)
+                    answered.append(t)
+                    verdicts.append(allowed)
+            obs = self.registry.workload_observatory()
+            if obs is not None:
+                # workload accounting, once a batch (the batch bypasses
+                # the single-check serve gate; no per-item tier)
+                obs.record_check_batch(nid, answered, verdicts)
             resp = pb.BatchCheckResponse(snaptoken=encode_snaptoken(version, nid))
             resp.results.extend(out)
         return resp

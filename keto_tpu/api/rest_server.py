@@ -660,18 +660,22 @@ class _Handler(BaseHTTPRequestHandler):
         # this request's trace itself (check_batch reads the contextvar)
         results = engine.check_batch(tuples, max_depth)
         with metrics.stage("respond", rt):
-            obs = self.registry.workload_observatory()
-            for pos, (i, res) in enumerate(zip(idx, results)):
+            answered: list[RelationTuple] = []
+            verdicts: list[bool] = []
+            for i, t, res in zip(idx, tuples, results):
                 if res.error is not None:
                     out[i] = {"allowed": False, "error": str(res.error)}
                 else:
-                    out[i] = {"allowed": res.allowed}
-                    if obs is not None:
-                        # per-item workload accounting (the batch bypasses
-                        # the single-check serve gate); the whole batch
-                        # rode one launch, so no per-item tier stamp
-                        # exists here
-                        obs.record_check(nid, tuples[pos], res.allowed)
+                    allowed = res.allowed
+                    out[i] = {"allowed": allowed}
+                    answered.append(t)
+                    verdicts.append(allowed)
+            obs = self.registry.workload_observatory()
+            if obs is not None:
+                # workload accounting, once a batch (the batch bypasses
+                # the single-check serve gate); the whole batch rode one
+                # launch, so no per-item tier stamp exists here
+                obs.record_check_batch(nid, answered, verdicts)
             self._json(
                 200,
                 {"results": out, "snaptoken": encode_snaptoken(version, nid)},

@@ -1117,6 +1117,30 @@ class Metrics:
             ["namespace", "relation", "tier", "verdict"],
             registry=self.registry,
         )
+        self.workload_fold_seconds_total = prom.Counter(
+            "keto_tpu_workload_fold_seconds_total",
+            "CPU seconds (the folding thread's own, without its waits "
+            "for the interpreter) the workload observatory spent folding "
+            "pending events into its accounting, sketches and SLO "
+            "buckets, by whose thread paid: folder (the daemon's keto-workload-fold "
+            "thread) or inline (a handler past the pending-checks "
+            "valve, an admin read surface, or an embedder with no "
+            "folder). One interpreter runs both, so the sum is what "
+            "the plane costs the serve path; inline seconds are also "
+            "inside some request's latency",
+            ["where"],
+            registry=self.registry,
+        )
+        self.workload_folded_checks_total = prom.Counter(
+            "keto_tpu_workload_folded_checks_total",
+            "Answered checks the workload observatory folded (a "
+            "BatchCheck counts its answered items), by the same "
+            "where as keto_tpu_workload_fold_seconds_total; summed "
+            "over both it equals keto_tpu_workload_requests_total "
+            "once the pending buffer is drained",
+            ["where"],
+            registry=self.registry,
+        )
         self.workload_tier_duration = prom.Histogram(
             "keto_tpu_workload_tier_duration_seconds",
             "Served request duration by ANSWERING tier (cache | "

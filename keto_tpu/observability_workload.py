@@ -28,17 +28,32 @@ ratio) — `keto-tpu admin capture` writes it and `tools/load_gen.py
 measured traffic instead of uniform synthetic queries.
 
 Everything here is monotonic-clock only (wall clocks are banned
-repo-wide) and stays off the serve path's critical microseconds: the
-feed points append one small event tuple to a buffer under one short
-lock, and the actual folding (sketch offers, per-pair stats, prom
-children, SLO buckets) runs in amortized batches — pre-aggregated per
-key, so a hot key's sixteen events cost one sketch offer — on every
-`_FOLD_BATCH`th request or at most ~1 s behind. Read surfaces drain
-first, so nothing an admin endpoint or a test reads is ever stale by
-more than the pending buffer. When `workload.enabled` is false every
-record call returns after one attribute test — the on/off A/B bar
-(WORKLOAD_AB_r18.json) holds the observatory to within 2% on the
-served check leg.
+repo-wide) and stays off the serve path's critical microseconds: a
+single check appends one small event tuple to a buffer under one short
+lock, a BatchCheck appends ONE event for all of its answered items
+(`record_check_batch`: columns of references, not 2,048 events), and
+the actual folding (per-pair stats, sketch merges, prom children, SLO
+buckets) runs on the daemon's folder thread four times a second, or,
+with no folder (library use, tests), every `_FOLD_BATCH` checks or at
+most ~1 s behind. A fold counts by columns (`collections.Counter` over
+comprehensions) and hands each sketch its pre-aggregated counts as one
+merge (`SpaceSaving.offer_many`), so a hot key's sixteen events cost
+one sketch entry and a uniform fold one sort, not an eviction a key.
+Read surfaces drain first, so nothing an admin endpoint or a test reads
+is ever stale by more than the pending buffer. When `workload.enabled`
+is false every record call returns after one attribute test.
+
+What it costs, as measured. WORKLOAD_AB_r18.json, the on/off A/B the
+plane was admitted on, is a CPU run of SINGLE checks at a few hundred a
+second (0.985 on the served check leg) and says nothing of a batch.
+Under 2,048-item BatchChecks at 38,000 checks a second the plane as it
+was (an event, a lock and a Python fold an item, every second RPC
+folding 4,096 events on its handler's thread) was half of the host's
+time an RPC: 45 to 55 ms with it, 21 to 24 without (CPU stage sums,
+ISSUE 32). The fold by columns costs about 1 us a check where the fold
+by events took 4 to 5 (CPU timings; PERF.md section 6, PR 32, has the
+chip's numbers), and `keto_tpu_workload_fold_seconds_total{where}`
+keeps the account from here on.
 """
 
 from __future__ import annotations
@@ -47,6 +62,8 @@ import heapq
 import logging
 import threading
 import time
+from collections import Counter
+from operator import itemgetter
 from typing import Callable, Optional
 
 logger = logging.getLogger("keto_tpu")
@@ -57,6 +74,12 @@ logger = logging.getLogger("keto_tpu")
 TIERS = ("cache", "closure", "device", "host", "vocab", "other")
 
 PROFILE_SCHEMA = "keto-tpu-workload-profile/1"
+
+# whose thread paid for a fold (the `where` label of
+# keto_tpu_workload_fold_seconds_total / _folded_checks_total): the
+# daemon's folder thread, or whoever else drained — a handler past the
+# valve, a read surface, an embedder with no folder
+FOLD_WHERE = ("folder", "inline")
 
 # method substrings that classify a request as a WRITE for the
 # read/write-ratio accounting (REST write plane verbs + the write-plane
@@ -108,9 +131,11 @@ class SpaceSaving:
     Min tracking rides a lazy-deletion heap: updates leave stale heap
     entries behind (a stale count is always a LOWER bound, so the heap
     top remains a valid minimum candidate); eviction pops until the top
-    is fresh. Offers are O(log capacity) amortized. Not thread-safe —
-    callers hold their own lock (one sketch update is a few dict ops;
-    the lock is cheaper than sharding the sketch)."""
+    is fresh. Offers are O(log capacity) amortized; a fold's worth of
+    pre-aggregated keys goes through `offer_many`, one merge in place
+    of one eviction a key. Not thread-safe — callers hold their own
+    lock (one sketch update is a few dict ops; the lock is cheaper than
+    sharding the sketch)."""
 
     __slots__ = ("capacity", "total", "_counts", "_heap")
 
@@ -143,6 +168,55 @@ class SpaceSaving:
         del self._counts[victim]
         heapq.heapreplace(self._heap, (cnt + n, key))
         self._counts[key] = [cnt + n, cnt]
+
+    # a merge walks every tracked entry and rebuilds the heap whatever
+    # the fold's size, an eviction touches one: on this many new keys a
+    # tracked slot the two cost the same (CPU timing, capacity 256: a
+    # merge 45 us and 0.2 us a new key, an eviction 0.6 us), and under
+    # it `offer_many` is so many `offer`s
+    _MERGE_FROM = 1 / 2
+
+    def offer_many(self, counts) -> None:
+        """Every key of `counts` ({key: n}, a fold's pre-aggregate)
+        offered n times, as one merge of mergeable summaries (Agarwal
+        et al., PODS 2012): tracked keys add their n; new keys enter as
+        (m + n, m), m the minimum tracked count before the merge (0
+        while there is free room: nothing was evicted yet, so an
+        untracked key was never seen); the `capacity` largest of
+        tracked and new stay. Every guarantee of `offer` holds: each
+        dropped entry counted at least m, so the tracked counts still
+        sum to at most `total`; a new key's true count was at most m
+        before this fold. What differs is which of several equal
+        counts stays, which a fold's dict order never defined either."""
+        tracked = self._counts
+        capacity = self.capacity
+        hits = tracked.keys() & counts.keys()
+        if len(counts) - len(hits) < capacity * self._MERGE_FROM:
+            for key, n in counts.items():
+                self.offer(key, n)
+            return
+        self.total += sum(counts.values())
+        m = (
+            0 if len(tracked) < capacity
+            else min(map(itemgetter(0), tracked.values()))
+        )
+        for key in hits:
+            tracked[key][0] += counts[key]
+        # list.sort and sorted, not heapq.nlargest: they are C, it is a
+        # Python loop an entry; equal counts (a uniform fold's ones) are
+        # one run to them. At most `capacity` new keys can stay
+        ranked = sorted(counts, key=counts.__getitem__, reverse=True)
+        new = [key for key in ranked[:capacity + len(hits)] if key not in hits]
+        entries = [(e[0], key, e) for key, e in tracked.items()]
+        entries += [(m + counts[key], key, None) for key in new[:capacity]]
+        if len(entries) > capacity:
+            entries.sort(key=itemgetter(0), reverse=True)
+            del entries[capacity:]
+        self._counts = {
+            key: e if e is not None else [cnt, m] for cnt, key, e in entries
+        }
+        self._heap = [(cnt, key) for cnt, key, _ in entries]
+        heapq.heapify(self._heap)
 
     def top(self, k: int) -> list[tuple[str, int, int]]:
         """[(key, count, err)] for the k largest tracked counts."""
@@ -183,6 +257,10 @@ class WindowedSketch:
     def offer(self, key: str, n: int = 1, now: Optional[float] = None) -> None:
         self._maybe_rotate(time.monotonic() if now is None else now)
         self._cur.offer(key, n)
+
+    def offer_many(self, counts, now: Optional[float] = None) -> None:
+        self._maybe_rotate(time.monotonic() if now is None else now)
+        self._cur.offer_many(counts)
 
     def total(self) -> int:
         return self._cur.total + (self._prev.total if self._prev else 0)
@@ -482,6 +560,37 @@ class SLOEngine:
         return out
 
 
+# -- a fold's checks as columns -------------------------------------------------
+
+
+def _field_columns(tuples) -> tuple[list, list, list, list]:
+    """(namespaces, objects, relations, subjects) of a run of tuples:
+    references to the tuples' own strings (a subject set rides as the
+    object it is), so whoever holds the columns holds no tuple."""
+    return (
+        [t.namespace for t in tuples],
+        [t.object for t in tuples],
+        [t.relation for t in tuples],
+        [s if (s := t.subject_id) is not None else t.subject_set for t in tuples],
+    )
+
+
+def _count_keys(keys: dict, columns) -> None:
+    """Add one run of checks to a fold's sketch-key counters. The keys
+    are built here, on the folding thread: `namespace:object`, the
+    subject as `subject_key` renders it, and the tuple's canonical
+    string (`str(RelationTuple)`), which is the one joined to the
+    other."""
+    ns, objs, rels, subs = columns
+    okeys = [f"{n}:{o}" for n, o in zip(ns, objs)]
+    skeys = [s if type(s) is str else f"({s})" for s in subs]
+    keys["object"].update(okeys)
+    keys["subject"].update(skeys)
+    keys["check"].update(
+        [f"{o}#{r}@{s}" for o, r, s in zip(okeys, rels, skeys)]
+    )
+
+
 # -- the observatory -----------------------------------------------------------
 
 
@@ -523,13 +632,19 @@ class WorkloadObservatory:
         # verdicts; .labels() walks locked dicts, see Metrics.observe_*)
         self._pair_cache: dict[tuple, object] = {}
         self._hotkey_gauge_sec = -1
-        # the feed buffer: record_check/observe_request append one event
-        # tuple here and return; _drain() folds pending events in
-        # pre-aggregated batches every _FOLD_BATCH events or ~1 s,
-        # whichever first — the serve path pays one append, not the
-        # sketch/stats/prom walk
+        if metrics is not None:
+            for where in FOLD_WHERE:  # both series from the first scrape
+                metrics.workload_fold_seconds_total.labels(where)
+                metrics.workload_folded_checks_total.labels(where)
+        # the feed buffers: record_check/observe_request append one event
+        # tuple and return, record_check_batch one event of columns for
+        # a whole BatchCheck; _drain() folds what is pending every
+        # _FOLD_BATCH checks or ~1 s, whichever first — the serve path
+        # pays one append, not the sketch/stats/prom walk
         self._buf_lock = threading.Lock()
         self._check_buf: list[tuple] = []
+        self._batch_buf: list[tuple] = []
+        self._batch_checks = 0  # items held by _batch_buf's events
         self._req_buf: list[tuple] = []
         self._last_fold = time.monotonic()
         # method -> is-write classification cache (the method vocabulary
@@ -537,21 +652,30 @@ class WorkloadObservatory:
         self._rw_class: dict[str, bool] = {}
         # the optional folder thread (daemon-owned: start_folder in
         # Daemon.start, stop_folder in Daemon.stop); while it runs, the
-        # serve path NEVER folds inline — a fold is hundreds of
-        # microseconds, and carrying it on every _FOLD_BATCHth request
-        # is exactly the median-vs-tail distortion the A/B bar catches
+        # serve path folds inline only past _FOLD_CAP — a fold costs
+        # about a microsecond a check on the one interpreter, and
+        # carrying it on a handler's thread is the median-vs-tail
+        # distortion the A/B bar catches
         self._folder: Optional[threading.Thread] = None
         self._folder_stop = threading.Event()
 
     # -- feed points -----------------------------------------------------------
 
     # inline-fold cadence WITHOUT a folder thread (library use, unit
-    # tests): fold once this many events queue or ~1 s passes. With the
-    # folder thread running (daemon mode) the inline trigger backs off
-    # to _FOLD_CAP — a pure memory safety valve the folder's 4/s
-    # cadence should never let fill
+    # tests): fold once this many checks and requests are pending or
+    # ~1 s passes. With the folder thread running (daemon mode) the
+    # inline trigger backs off to _FOLD_CAP, which bounds memory and
+    # nothing else: it counts CHECKS pending (a batch event counts its
+    # items), each of which holds five list slots and keeps its object
+    # and subject strings alive, some 200 B, so a full valve is about
+    # 13 MB and one inline fold of about 0.1 s.
+    # The folder wakes 4 times a second; at the 60,000 checks a second
+    # of a host-bound BatchCheck cell (PERF.md, PR 32) that is 15,000
+    # pending at a wake-up, so the valve opens only when the folder
+    # has not run for a second. At 4,096, as it was until PR 32, two
+    # 2,048-item RPCs filled it and every second RPC folded inline.
     _FOLD_BATCH = 16
-    _FOLD_CAP = 4096
+    _FOLD_CAP = 65536
 
     def start_folder(self, interval_s: float = 0.25) -> None:
         """Start the background folder (idempotent): pending events fold
@@ -563,7 +687,7 @@ class WorkloadObservatory:
 
         def run() -> None:
             while not self._folder_stop.wait(interval_s):
-                self._drain()
+                self._drain(where="folder")
 
         self._folder = threading.Thread(
             target=run, name="keto-workload-fold", daemon=True
@@ -582,15 +706,36 @@ class WorkloadObservatory:
         self._drain()
 
     def record_check(self, nid: str, t, allowed: bool, tier=None) -> None:
-        """One answered check (single or batch item), from the serve
-        fast path: enqueue one event — the tuple object rides the
-        buffer as-is (it is never mutated after parse) and the fold
-        builds the sketch keys."""
+        """One answered check, from the serve fast path: enqueue one
+        event — the tuple object rides the buffer as-is (it is never
+        mutated after parse) and the fold builds the sketch keys."""
         if not self.enabled:
             return
         with self._buf_lock:
             self._check_buf.append((nid, t, allowed, tier))
-            pending = len(self._check_buf) + len(self._req_buf)
+            pending = self._pending_locked()
+        self._fold_if_full(pending)
+
+    def record_check_batch(self, nid: str, tuples, allowed, tier=None) -> None:
+        """The answered items of one BatchCheck (`allowed[i]` is the
+        verdict on `tuples[i]`; errored items are the caller's to leave
+        out): enqueue ONE event for all of them. It holds the items'
+        fields as columns of string references, not the tuples: 2,048
+        tuples pinned until the fold are 2,048 more objects for the
+        collector to promote and walk, five lists are five."""
+        if not self.enabled or not tuples:
+            return
+        event = (nid, tier, list(allowed), _field_columns(tuples))
+        with self._buf_lock:
+            self._batch_buf.append(event)
+            self._batch_checks += len(tuples)
+            pending = self._pending_locked()
+        self._fold_if_full(pending)
+
+    def _pending_locked(self) -> int:
+        return len(self._check_buf) + self._batch_checks + len(self._req_buf)
+
+    def _fold_if_full(self, pending: int) -> None:
         limit = self._FOLD_BATCH if self._folder is None else self._FOLD_CAP
         if pending >= limit:
             self._drain()
@@ -620,59 +765,76 @@ class WorkloadObservatory:
                 method, code, duration_s, tier, trace_id, ok,
                 latency_eligible, now, acct,
             ))
-            pending = len(self._check_buf) + len(self._req_buf)
+            pending = self._pending_locked()
             stale = now - self._last_fold >= 1.0
-        if self._folder is None:
-            if pending >= self._FOLD_BATCH or stale:
-                self._drain()
-        elif pending >= self._FOLD_CAP:
+        if stale and self._folder is None:
             self._drain()
+        else:
+            self._fold_if_full(pending)
 
     # -- the fold --------------------------------------------------------------
 
-    def _drain(self) -> None:
+    def _drain(self, where: str = "inline") -> None:
         """Fold every pending event into the real sinks. Swaps the
         buffers under the buffer lock, folds OUTSIDE it (the fold takes
         the shard/sketch/slo/prom locks; never nested under the buffer
-        lock). Concurrent drains each fold their own swapped batch."""
+        lock). Concurrent drains each fold their own swapped batch.
+        `where` says whose thread pays: the folder's, or ("inline") a
+        handler's, a reader's or an embedder's."""
         with self._buf_lock:
             checks, self._check_buf = self._check_buf, []
+            batches, self._batch_buf = self._batch_buf, []
+            n_checks = len(checks) + self._batch_checks
+            self._batch_checks = 0
             reqs, self._req_buf = self._req_buf, []
             self._last_fold = time.monotonic()
-        if checks:
-            self._fold_checks(checks)
+        if not (n_checks or reqs):
+            return
+        # this thread's CPU time, not the wall's: what the fold takes of
+        # the one interpreter, without its waits for the lock on it
+        t0 = time.thread_time()
+        if n_checks:
+            self._fold_checks(checks, batches)
         if reqs:
             self._fold_requests(reqs)
+        if self.metrics is not None:
+            self.metrics.workload_fold_seconds_total.labels(where).inc(
+                time.thread_time() - t0
+            )
+            self.metrics.workload_folded_checks_total.labels(where).inc(n_checks)
 
-    def _fold_checks(self, events: list[tuple]) -> None:
-        """Pre-aggregate a batch per pair / sketch key / prom child,
-        then apply each aggregate under its lock once — a hot key's
-        sixteen events cost one sketch offer with n=16."""
+    def _fold_checks(self, checks: list[tuple], batches: list[tuple]) -> None:
+        """Count a fold's checks by columns — per (nid, pair, tier,
+        verdict) and per sketch key, `Counter` over comprehensions, no
+        Python statement an item — then apply each aggregate under its
+        lock once: a hot key's sixteen events cost one sketch entry
+        with n=16, and each sketch takes the fold as one merge."""
+        # (nid, namespace, relation, tier, verdict) -> checks
+        acct: Counter = Counter()
+        keys = {"object": Counter(), "subject": Counter(), "check": Counter()}
+        if checks:
+            # the single checks, seen as one more run of columns
+            nids, tuples, allowed, tiers = zip(*checks)
+            columns = _field_columns(tuples)
+            acct.update(zip(nids, columns[0], columns[2], tiers, allowed))
+            _count_keys(keys, columns)
+        for nid, tier, allowed, columns in batches:
+            # one nid and one tier a batch: count the short tuples
+            run = Counter(zip(columns[0], columns[2], allowed))
+            for (ns, rel, verdict), n in run.items():
+                acct[(nid, ns, rel, tier, verdict)] += n
+            _count_keys(keys, columns)
         by_pair: dict[tuple, list] = {}
-        by_child: dict[tuple, int] = {}
-        keys: dict[str, dict[str, int]] = {
-            "object": {}, "subject": {}, "check": {},
-        }
-        for nid, t, allowed, tier in events:
+        by_child: Counter = Counter()
+        for (nid, ns, rel, tier, allowed), n in acct.items():
             tier = tier if tier in TIERS else "other"
-            pair = (nid, t.namespace, t.relation)
-            agg = by_pair.get(pair)
+            agg = by_pair.get((nid, ns, rel))
             if agg is None:
-                agg = by_pair[pair] = [0, 0, 0, {}]
-            agg[0] += 1
-            if allowed:
-                agg[1] += 1
-            else:
-                agg[2] += 1
-            agg[3][tier] = agg[3].get(tier, 0) + 1
-            okey = f"{t.namespace}:{t.object}"
-            keys["object"][okey] = keys["object"].get(okey, 0) + 1
-            skey = subject_key(t)
-            keys["subject"][skey] = keys["subject"].get(skey, 0) + 1
-            ckey = str(t)
-            keys["check"][ckey] = keys["check"].get(ckey, 0) + 1
-            child_key = (t.namespace, t.relation, tier, allowed)
-            by_child[child_key] = by_child.get(child_key, 0) + 1
+                agg = by_pair[(nid, ns, rel)] = [0, 0, 0, Counter()]
+            agg[0] += n
+            agg[1 if allowed else 2] += n
+            agg[3][tier] += n
+            by_child[(ns, rel, tier, bool(allowed))] += n
         for pair, agg in by_pair.items():
             shard = self._shards[hash(pair) % self._nshards]
             with shard.lock:
@@ -687,14 +849,12 @@ class WorkloadObservatory:
         now = time.monotonic()
         with self._sketch_lock:
             for kind, counts in keys.items():
-                sk = self.sketches[kind]
-                for key, n in counts.items():
-                    sk.offer(key, n, now=now)
+                self.sketches[kind].offer_many(counts, now=now)
         if self.metrics is not None:
-            for (ns, rel, tier, allowed), n in by_child.items():
-                ckey = (ns, rel, tier, allowed)
+            for ckey, n in by_child.items():
                 child = self._pair_cache.get(ckey)
                 if child is None:
+                    ns, rel, tier, allowed = ckey
                     child = self._pair_cache[ckey] = (
                         self.metrics.workload_requests_total.labels(
                             ns, rel, tier,

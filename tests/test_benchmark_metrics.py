@@ -15,6 +15,7 @@ import sys
 import pytest
 
 from keto_tpu.observability import CHECK_STAGES, DEVICE_FEED_STATES, Metrics
+from keto_tpu.observability_workload import FOLD_WHERE
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -24,6 +25,7 @@ BENCH = os.path.join(ROOT, "benchmarks")
 LABEL_VALUES = {
     ("keto_tpu_check_stage_duration_seconds", "stage"): set(CHECK_STAGES),
     ("keto_tpu_device_feed_seconds", "state"): set(DEVICE_FEED_STATES),
+    ("keto_tpu_workload_fold_seconds", "where"): set(FOLD_WHERE),
 }
 SAMPLE_SUFFIXES = ("_total", "_sum", "_count", "_bucket", "")
 

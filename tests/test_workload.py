@@ -6,10 +6,12 @@ live admin endpoints (/admin/hotkeys, /admin/slo, /admin/workload)
 plus the request log's `tier=` attribute and the per-tier histogram's
 OpenMetrics exemplars on a real daemon."""
 
+import bisect
 import json
 import logging
 import random
 import time
+from collections import Counter
 import urllib.error
 import urllib.request
 
@@ -20,6 +22,7 @@ from keto_tpu.api import ReadClient, open_channel
 from keto_tpu.api.daemon import Daemon
 from keto_tpu.ketoapi import RelationTuple
 from keto_tpu.namespace import Namespace
+from keto_tpu.observability import Metrics
 from keto_tpu.registry import Registry
 from keto_tpu.observability_workload import (
     PROFILE_SCHEMA,
@@ -103,6 +106,111 @@ class TestSpaceSaving:
         sk.offer("b", n=2)
         assert sk.top(1) == [("a", 16, 0)]
         assert sk.total == 18
+
+
+def _stream(kind: str, seed: int, n: int, n_keys: int = 2000) -> list[str]:
+    """A seeded key stream: Zipfian (s=1.1) or uniform over n_keys."""
+    rng = random.Random(seed)
+    if kind == "uniform":
+        return [f"k{rng.randrange(n_keys)}" for _ in range(n)]
+    cum, acc = [], 0.0
+    for i in range(n_keys):
+        acc += 1.0 / (i + 1) ** 1.1
+        cum.append(acc)
+    return [
+        f"k{bisect.bisect_left(cum, rng.random() * acc)}" for _ in range(n)
+    ]
+
+
+def _assert_space_saving_guarantees(sk: SpaceSaving, truth: Counter):
+    assert sk.total == sum(truth.values())
+    assert len(sk) <= sk.capacity
+    reported = {key: (cnt, err) for key, cnt, err in sk.top(sk.capacity)}
+    for key, (cnt, err) in reported.items():
+        assert cnt >= truth[key], "a tracked count under the true count"
+        assert cnt - err <= truth[key], "count - err over the true count"
+    hot = {k for k, n in truth.items() if n > sk.total / sk.capacity}
+    assert hot <= set(reported), "a key over total/capacity is not tracked"
+    # what the first guarantee rests on: the tracked counts never sum
+    # to more than everything offered
+    assert sum(cnt for cnt, _ in reported.values()) <= sk.total
+
+
+class TestOfferMany:
+    CAPACITY = 64
+
+    @pytest.mark.parametrize("fold", [16, 256, 4096])
+    @pytest.mark.parametrize("kind", ["zipf", "uniform"])
+    def test_guarantees_hold_fold_after_fold(self, kind, fold):
+        # folds of 16 stay under the merge line of a 64-entry sketch
+        # (so many single offers), folds of 256 and 4,096 cross it (one
+        # merge): the same guarantees on both sides, after every fold
+        sk = SpaceSaving(self.CAPACITY)
+        truth: Counter = Counter()
+        stream = _stream(kind, seed=fold, n=max(8 * fold, 4096))
+        for at in range(0, len(stream), fold):
+            counts = Counter(stream[at:at + fold])
+            truth.update(counts)
+            sk.offer_many(counts)
+            _assert_space_saving_guarantees(sk, truth)
+        if kind == "zipf":
+            top10 = {k for k, _ in truth.most_common(10)}
+            assert top10 <= {k for k, _, _ in sk.top(self.CAPACITY)}
+
+    def test_merges_and_single_offers_interleave(self):
+        # the heap a merge rebuilds must serve the evictions after it,
+        # and a merge must read counts that offers moved past the heap
+        sk = SpaceSaving(self.CAPACITY)
+        truth: Counter = Counter()
+        stream = _stream("zipf", seed=11, n=6000)
+        for at in range(0, len(stream), 300):
+            counts = Counter(stream[at:at + 200])
+            truth.update(counts)
+            sk.offer_many(counts)
+            for key in stream[at + 200:at + 300]:
+                truth[key] += 1
+                sk.offer(key)
+            _assert_space_saving_guarantees(sk, truth)
+
+    def test_under_the_merge_line_it_is_so_many_offers(self):
+        a, b = SpaceSaving(self.CAPACITY), SpaceSaving(self.CAPACITY)
+        for sk in (a, b):
+            for i in range(200):
+                sk.offer(f"w{i}", n=1 + i % 5)
+        counts = {f"n{i}": 2 for i in range(8)} | {"w199": 3}
+        assert len(counts) < self.CAPACITY * SpaceSaving._MERGE_FROM
+        a.offer_many(counts)
+        for key, n in counts.items():
+            b.offer(key, n)
+        assert a.top(self.CAPACITY) == b.top(self.CAPACITY)
+        assert a.total == b.total
+
+    def test_free_room_fills_exactly_then_new_keys_inherit_the_minimum(self):
+        sk = SpaceSaving(4)
+        sk.offer_many({"a": 5, "b": 3, "c": 2})  # free room: exact
+        assert sk.top(4) == [("a", 5, 0), ("b", 3, 0), ("c", 2, 0)]
+        # not full before the merge: nothing was ever evicted, so the
+        # new keys are exact too, and the smallest candidate drops
+        sk.offer_many({"d": 4, "e": 1, "a": 1})
+        assert sk.top(4) == [("a", 6, 0), ("d", 4, 0), ("b", 3, 0), ("c", 2, 0)]
+        assert sk.total == 16
+        # full: new keys enter as (m + n, m), m = 2 the minimum before
+        # the merge; the tracked key adds its n; the four largest stay
+        sk.offer_many({"f": 3, "g": 1, "b": 2})
+        assert sk.top(4) == [("a", 6, 0), ("b", 5, 0), ("f", 5, 2), ("d", 4, 0)]
+        assert sk.total == 22
+
+    def test_windowed_offer_many_rotates_like_offer(self):
+        sk = WindowedSketch(capacity=8, window_s=10.0)
+        t0 = sk._rotated_at
+        sk.offer_many({"old": 5, "older": 1}, now=t0 + 1.0)
+        sk.offer_many({"new": 3}, now=t0 + 10.5)
+        assert dict((k, c) for k, c, _ in sk.top(8)) == {
+            "old": 5, "new": 3, "older": 1,
+        }
+        assert sk.total() == 9
+        sk.offer_many({"newer": 1}, now=t0 + 21.0)
+        assert "old" not in {k for k, _, _ in sk.top(8)}
 
 
 class TestWindowedSketch:
@@ -468,6 +576,155 @@ class TestObservatoryFold:
         assert obj["bad_short"] == 1
 
 
+def _folded(metrics: Metrics, where: str) -> float:
+    return metrics.registry.get_sample_value(
+        "keto_tpu_workload_folded_checks_total", {"where": where}
+    ) or 0.0
+
+
+def _request_children(metrics: Metrics) -> dict:
+    (family,) = [
+        f for f in metrics.registry.collect()
+        if f.name == "keto_tpu_workload_requests"
+    ]
+    return {
+        tuple(sorted(s.labels.items())): s.value
+        for s in family.samples if s.name.endswith("_total")
+    }
+
+
+class TestBatchEvent:
+    TUPLES = [
+        RelationTuple.from_string(s) for s in (
+            "files:doc#owner@alice",
+            "files:doc#owner@bob",
+            "files:doc2#viewer@alice",
+            "files:doc3#viewer@(files:dir#view)",
+            "dirs:d#parent@(files:dir#view)",
+            "files:doc#owner@alice",
+        )
+    ]
+    ALLOWED = [True, False, True, True, False, True]
+
+    def test_a_batch_is_one_event_and_equals_n_single_checks(self):
+        one, many = _obs(metrics=Metrics()), _obs(metrics=Metrics())
+        one.start_folder(interval_s=3600.0)  # hold the fold off
+        try:
+            one.record_check_batch("net0", self.TUPLES, self.ALLOWED)
+            assert len(one._batch_buf) == 1 and not one._check_buf
+            assert one._batch_checks == len(self.TUPLES)
+        finally:
+            one.stop_folder()
+        for t, allowed in zip(self.TUPLES, self.ALLOWED):
+            many.record_check("net0", t, allowed)
+        assert one.accounting() == many.accounting()
+        assert one.accounting()["net0/files#owner"] == {
+            "requests": 3, "allowed": 2, "denied": 1, "tiers": {"other": 3},
+        }
+        assert _request_children(one.metrics) == _request_children(many.metrics)
+        assert sum(_request_children(one.metrics).values()) == len(self.TUPLES)
+        for kind in ("object", "subject", "check"):
+            a, b = one.sketches[kind], many.sketches[kind]
+            assert a.total() == b.total() == len(self.TUPLES)
+            # under capacity both are exact, so the keys agree too
+            assert sorted(a.top(16)) == sorted(b.top(16))
+        assert {k for k, _, _ in one.sketches["check"].top(16)} == {
+            str(t) for t in self.TUPLES
+        }
+        assert {k for k, _, _ in one.sketches["subject"].top(16)} == {
+            subject_key(t) for t in self.TUPLES
+        }
+
+    def test_the_event_holds_no_tuple(self):
+        obs = _obs()
+        obs.start_folder(interval_s=3600.0)
+        try:
+            obs.record_check_batch("net0", self.TUPLES, self.ALLOWED, tier="device")
+            (nid, tier, allowed, columns), = obs._batch_buf
+            assert (nid, tier, allowed) == ("net0", "device", self.ALLOWED)
+            flat = [x for column in columns for x in column]
+            assert not any(isinstance(x, RelationTuple) for x in flat)
+        finally:
+            obs.stop_folder()
+        st = obs.accounting()["net0/files#viewer"]
+        assert st["tiers"] == {"device": 2}
+
+    def test_empty_and_disabled_batches_enqueue_nothing(self):
+        obs = _obs()
+        obs.record_check_batch("net0", [], [])
+        assert not obs._batch_buf
+        off = _obs(enabled=False)
+        off.record_check_batch("net0", self.TUPLES, self.ALLOWED)
+        assert not off._batch_buf and off.accounting() == {}
+
+    def test_without_a_folder_a_batch_folds_inline(self):
+        obs = _obs(metrics=Metrics())
+        obs.record_check_batch("net0", self.TUPLES * 3, self.ALLOWED * 3)
+        assert not obs._batch_buf and obs._batch_checks == 0
+        assert _folded(obs.metrics, "inline") == 18
+        assert _folded(obs.metrics, "folder") == 0
+
+    def test_with_the_folder_running_eight_batches_fold_nothing_inline(self):
+        # 8 x 2,048 answered items back to back, the drive cell's quarter
+        # of a second: the valve (65,536 pending checks) stays shut, and
+        # the folder's thread folds every one of them
+        metrics = Metrics()
+        obs = _obs(metrics=metrics, hotkey_capacity=256)
+        rng = random.Random(3)
+        batches = [
+            [
+                RelationTuple.make(
+                    "files", f"/d{rng.randrange(12000)}/f{rng.randrange(80)}",
+                    "view", f"u{rng.randrange(10000)}",
+                )
+                for _ in range(2048)
+            ]
+            for _ in range(8)
+        ]
+        assert 8 * 2048 < obs._FOLD_CAP
+        obs.start_folder(interval_s=0.05)
+        try:
+            for i, tuples in enumerate(batches):
+                obs.record_check_batch("net0", tuples, [bool(i & 1)] * 2048)
+            deadline = time.monotonic() + 10.0
+            while (
+                _folded(metrics, "folder") < 8 * 2048
+                and time.monotonic() < deadline
+            ):
+                time.sleep(0.01)
+            assert _folded(metrics, "folder") == 8 * 2048
+            assert _folded(metrics, "inline") == 0
+        finally:
+            obs.stop_folder()
+        assert _folded(metrics, "inline") == 0  # nothing was left to stop
+        seconds = metrics.registry.get_sample_value(
+            "keto_tpu_workload_fold_seconds_total", {"where": "folder"}
+        )
+        assert seconds > 0.0
+        assert obs.accounting()["net0/files#view"] == {
+            "requests": 8 * 2048, "allowed": 4 * 2048, "denied": 4 * 2048,
+            "tiers": {"other": 8 * 2048},
+        }
+        for kind in ("object", "subject", "check"):
+            assert obs.sketches[kind].total() == 8 * 2048
+
+    def test_past_the_valve_a_handler_folds_inline(self, monkeypatch):
+        # the valve counts pending CHECKS, a batch by its items
+        metrics = Metrics()
+        obs = _obs(metrics=metrics)
+        monkeypatch.setattr(obs, "_FOLD_CAP", 32)
+        obs.start_folder(interval_s=3600.0)
+        try:
+            obs.record_check_batch("net0", self.TUPLES * 5, self.ALLOWED * 5)
+            assert _folded(metrics, "inline") == 0  # 30 pending: shut
+            obs.record_check("net0", self.TUPLES[0], True)
+            obs.record_check("net0", self.TUPLES[1], True)
+            assert _folded(metrics, "inline") == 32
+            assert not obs._batch_buf and not obs._check_buf
+        finally:
+            obs.stop_folder()
+
+
 # -- config schema + registry wiring -------------------------------------------
 
 
@@ -599,6 +856,98 @@ class TestDaemonWorkloadPlane:
         assert TUPLE in checks
         # the cache-attribution join rides the same response
         assert "check_cache" in out
+
+    def test_a_batch_check_of_2048_is_one_event_and_2048_hotkeys(
+        self, daemon, monkeypatch
+    ):
+        obs = daemon.registry.workload_observatory()
+        events, singles = [], []
+        record_batch = obs.record_check_batch
+
+        def spy(nid, tuples, allowed, tier=None):
+            events.append(len(tuples))
+            record_batch(nid, tuples, allowed, tier)
+
+        monkeypatch.setattr(obs, "record_check_batch", spy)
+        monkeypatch.setattr(
+            obs, "record_check", lambda *a, **kw: singles.append(a)
+        )
+        before = _admin(daemon, "/admin/hotkeys?top=1")["kinds"]
+        items = [
+            RelationTuple.from_string(f"files:doc{i}#owner@user{i % 50}")
+            for i in range(2047)
+        ] + [RelationTuple.from_string(TUPLE)]
+        client = ReadClient(open_channel(f"127.0.0.1:{daemon.read_port}"))
+        try:
+            out = client.check_batch(items)
+        finally:
+            client.close()
+        assert [allowed for allowed, _ in out] == [False] * 2047 + [True]
+        assert events == [2048] and not singles
+        after = _admin(daemon, "/admin/hotkeys?top=300")["kinds"]
+        for kind in ("object", "subject", "check"):
+            assert after[kind]["total"] - before[kind]["total"] == 2048
+        top = {e["key"]: e["count"] for e in after["subject"]["top"]}
+        assert all(top[f"user{i}"] >= 40 for i in range(50))
+        acct = obs.accounting()
+        key = next(k for k in acct if k.endswith("/files#owner"))
+        assert acct[key]["denied"] >= 2047
+
+    @pytest.mark.parametrize("transport", ["grpc", "rest"])
+    def test_errored_batch_items_are_not_accounted(
+        self, daemon, monkeypatch, transport
+    ):
+        from keto_tpu.engine.definitions import CheckResult, Membership
+
+        obs = daemon.registry.workload_observatory()
+        engine = daemon.registry.check_engine(daemon.registry.nid)
+        check_batch = engine.check_batch
+
+        def second_item_errs(tuples, *args, **kw):
+            results = list(check_batch(tuples, *args, **kw))
+            results[1] = CheckResult(
+                Membership.NOT_MEMBER, error=RuntimeError("boom")
+            )
+            return results
+
+        monkeypatch.setattr(engine, "check_batch", second_item_errs)
+        items = [
+            RelationTuple.from_string(TUPLE),
+            RelationTuple.from_string("files:doc#owner@errs"),
+            # an unknown namespace never reaches the engine
+            RelationTuple.from_string("nope:doc#owner@alice"),
+            RelationTuple.from_string("files:doc#owner@mallory"),
+        ]
+        obs._drain()
+        before = obs.sketches["check"].total()
+        if transport == "grpc":
+            client = ReadClient(open_channel(f"127.0.0.1:{daemon.read_port}"))
+            try:
+                out = client.check_batch(items)
+            finally:
+                client.close()
+        else:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{daemon.read_port}"
+                "/relation-tuples/check/batch",
+                data=json.dumps(
+                    {"tuples": [t.to_dict() for t in items]}
+                ).encode(),
+                method="POST", headers={"Content-Type": "application/json"},
+            )
+            with urllib.request.urlopen(req) as r:
+                out = [
+                    (res["allowed"], res.get("error", ""))
+                    for res in json.loads(r.read())["results"]
+                ]
+        assert [allowed for allowed, _ in out] == [True, False, False, False]
+        assert [bool(err) for _, err in out] == [False, True, True, False]
+        hot = obs.hotkeys(top=300)["kinds"]["check"]
+        assert hot["total"] - before == 2  # the two that carry a verdict
+        keys = {e["key"] for e in hot["top"]}
+        assert "files:doc#owner@mallory" in keys
+        assert "files:doc#owner@errs" not in keys
+        assert "nope:doc#owner@alice" not in keys
 
     def test_admin_hotkeys_top_validates(self, daemon):
         with pytest.raises(urllib.error.HTTPError) as e:
