@@ -9,7 +9,7 @@ Instead of a goroutine-per-branch graph walk issuing one SQL query per
 edge page (reference internal/check/engine.go), the relation graph is
 mirrored in device memory as dictionary-encoded hash tables + CSR
 adjacency, and permission checks run as batched BFS frontier expansion
-under `jax.lax.while_loop`, sharded over a `jax.sharding.Mesh`.
+inside one bounded device loop, sharded over a `jax.sharding.Mesh`.
 
 Layout (mirrors the layer map in SURVEY.md §1):
   ketoapi     — public string-based API types + encodings   (ref: ketoapi/)

@@ -1,6 +1,6 @@
 """ketolint — repo-invariant checker (`python -m keto_tpu.analysis.lint`).
 
-Five AST passes encode the invariants the codebase lives by; each was
+Six AST passes encode the invariants the codebase lives by; each was
 prose in CHANGES.md / code comments until this tier existed. Pure
 stdlib: runs before deps are installed, in CI's analysis job and beside
 the metrics-golden check in the test job.
@@ -37,6 +37,13 @@ host-sync            Inside the engine batch hot path (check/list/
                      `.block_until_ready()`, `jax.device_get`, scalar
                      `int()`/`float()` coercion of a device value, or a
                      fresh `jax.jit` — must be an annotated sync point.
+one-program          Under engine/ and parallel/ nothing asks which
+                     backend it runs on: no `jax.default_backend()`, no
+                     comparison of a device's `.platform`. The CPU
+                     traces the program the chip runs, so tier-1 tests
+                     what the benchmark's cells measure. (The registry's
+                     `check.platform` pin is a deployment setting and
+                     lies outside.)
 
 Suppressions: `# ketolint: allow[<rule>] reason=...` on the offending
 line or the line directly above. A reasonless allow and an allow that
@@ -70,6 +77,7 @@ RULES = {
     "config-key": "config keys must exist in the schema and be read",
     "clock-monotonic": "deadline/backoff math must use a monotonic clock",
     "host-sync": "device sync in the batch hot path must be annotated",
+    "one-program": "engine and parallel code must not branch on the backend",
     "suppression": "ketolint allow[] annotations must carry a reason and match a finding",
 }
 
@@ -91,6 +99,9 @@ _HOT_FUNCS = re.compile(
     r"|list_objects_batch|list_subjects_batch|expand_batch"
     r"|filter_batch|filter_chunk)(_inner)?$"
 )
+
+# packages that build the device programs, for the one-program pass
+_PROGRAM_DIRS = {"engine", "parallel"}
 
 # a with-context (or receiver) names a lock when its final segment does
 _LOCK_NAME = re.compile(r"(^|_)(lock|mu|mutex|cond)\d*$")
@@ -665,6 +676,37 @@ def pass_host_sync(ctx: FileCtx) -> list[Finding]:
     return findings
 
 
+# -- pass 6: one program on every backend --------------------------------------
+
+
+def pass_one_program(ctx: FileCtx) -> list[Finding]:
+    if ctx.path.parent.name not in _PROGRAM_DIRS:
+        return []
+    findings = []
+    for node in ast.walk(ctx.tree):
+        if isinstance(node, ast.Call):
+            asks = _attr_chain(node.func)[-1:] == ["default_backend"]
+            what = "default_backend()"
+        elif isinstance(node, ast.Compare):
+            asks = any(
+                isinstance(side, ast.Attribute) and side.attr == "platform"
+                for side in (node.left, *node.comparators)
+            )
+            what = "comparison of a device's .platform"
+        else:
+            continue
+        if asks:
+            findings.append(
+                Finding(
+                    ctx.path, node.lineno, "one-program",
+                    f"{what} — a program chosen by backend is one the "
+                    "tests here never run; keep the form the chip runs, "
+                    "on every backend",
+                )
+            )
+    return findings
+
+
 # -- driver --------------------------------------------------------------------
 
 
@@ -720,6 +762,7 @@ def lint_paths(
         findings.extend(pass_typed_error(ctx, keto_errors))
         findings.extend(pass_clock(ctx))
         findings.extend(pass_host_sync(ctx))
+        findings.extend(pass_one_program(ctx))
     if schema is not None:
         findings.extend(
             pass_config_keys(

@@ -38,22 +38,25 @@ import numpy as np
 
 from .snapshot import GraphSnapshot
 
-FORMAT_VERSION = 4  # v4: backend-keyed table layout — the meta vector
-# grew a layout code (bucketized vs compact r04, snapshot.table_layout)
-# because the two layouts place keys in DIFFERENT slots: a checkpoint
-# written under one layout loaded under the other would mis-probe every
-# table, so a layout mismatch degrades to a rebuild exactly like a
-# version mismatch. (The code names where keys are PLACED; the shape a
-# pack is stored in, kernel.as_bucket_rows, is made from these columns
-# at upload and is no part of the file.)
+FORMAT_VERSION = 4  # v4: the meta vector ends in a layout code that
+# names where the builder PLACED keys. There is one placement today
+# (snapshot.probe_slot's bucket sequence, code 0); v4 files written by
+# CPU processes before the layouts were merged carry code 1 (one slot a
+# bucket, classic double hashing), and probing those tables with today's
+# sequence would mis-answer every lookup, so any other code is refused
+# like a version mismatch. (The shape a pack is stored in,
+# kernel.as_bucket_rows, is made from these columns at upload and is no
+# part of the file.)
 # v3: bucketized probe sequence (snapshot.probe_slot) — v2 files hold
 # tables built with the old (h1 + j*h2) slot layout and would mis-probe;
 # a version mismatch just triggers a rebuild.
 # v2: island circuits (AND/NOT device programs)
 
-# layout code riding last in the meta vector (v4+)
-_LAYOUT_CODES = {"bucketized": 0, "compact": 1}
-_LAYOUT_NAMES = {v: k for k, v in _LAYOUT_CODES.items()}
+# layout code riding last in the meta vector (v4+): the one this process
+# writes and reads, and the retired one, kept so a refusal can name it
+_LAYOUT_CODE = 0
+_LAYOUT_NAMES = {_LAYOUT_CODE: "bucketized", 1: "compact"}
+_LAYOUT = _LAYOUT_NAMES[_LAYOUT_CODE]
 
 # vocabularies larger than this reload as ArrayMaps, not Python dicts
 _ARRAY_VOCAB_THRESHOLD = 200_000
@@ -115,15 +118,13 @@ def save_snapshot(snapshot: GraphSnapshot, path: str) -> None:
         for (ns, obj), slot in snapshot.obj_slots.items():
             obj_ns[slot] = ns
             obj_names[slot] = obj
-    from .snapshot import table_layout
-
     payload = {k: getattr(snapshot, k) for k in _ARRAY_FIELDS}
     payload.update(
         {
             "meta": np.array(
                 [FORMAT_VERSION]
                 + [int(getattr(snapshot, k)) for k in _INT_FIELDS]
-                + [_LAYOUT_CODES[table_layout()]],
+                + [_LAYOUT_CODE],
                 dtype=np.int64,
             ),
             "ns_names": _names_by_id(snapshot.ns_ids, len(snapshot.ns_ids)),
@@ -282,8 +283,6 @@ def checkpoint_info(path: str) -> Optional[dict]:
     rebuild on)."""
     if not os.path.exists(path):
         return None
-    from .snapshot import table_layout
-
     try:
         with np.load(path, allow_pickle=False) as z:
             meta = z["meta"]
@@ -297,9 +296,9 @@ def checkpoint_info(path: str) -> Optional[dict]:
                 )
                 layout = _LAYOUT_NAMES.get(int(meta[-1]))
                 info["table_layout"] = layout
-                # a cross-layout checkpoint exists but cannot be probed
-                # by THIS process — its tables' keys live in other slots
-                if layout != table_layout():
+                # a checkpoint of another layout exists but cannot be
+                # probed — its tables' keys live in other slots
+                if layout != _LAYOUT:
                     info["loadable"] = False
             else:
                 info["loadable"] = False
@@ -315,11 +314,11 @@ def restore_snapshot(path: str) -> Optional[GraphSnapshot]:
 
       - missing or torn/corrupt file -> None (recover by rebuilding —
         a crash mid-publish must never wedge a restart);
-      - intact but incompatible (format version or cross-layout) ->
+      - intact but incompatible (format version or table layout) ->
         typed CheckpointIncompatibleError, because the file the caller
         explicitly wants CANNOT be honored by this process and silently
-        rebuilding would hide an operational mistake (e.g. pointing a
-        compact-layout follower at a bucketized leader's cache dir).
+        rebuilding would hide an operational mistake (e.g. a cache dir
+        left behind by a build that placed keys differently).
 
     load_snapshot keeps the old degrade-to-None contract for the
     engine's opportunistic warm-start probe."""
@@ -338,14 +337,12 @@ def restore_snapshot(path: str) -> Optional[GraphSnapshot]:
                 )
             )
         layout = info.get("table_layout")
-        from .snapshot import table_layout
-
-        if layout is not None and layout != table_layout():
+        if layout is not None and layout != _LAYOUT:
             raise CheckpointIncompatibleError(
                 debug=(
                     f"checkpoint {path} was built under the {layout!r} "
                     f"table layout; this process probes "
-                    f"{table_layout()!r} — its tables would mis-answer"
+                    f"{_LAYOUT!r} — its tables would mis-answer"
                 )
             )
         return None  # torn/corrupt: recover cleanly via rebuild
@@ -358,17 +355,15 @@ def load_snapshot(path: str) -> Optional[GraphSnapshot]:
     fsync ordering save_snapshot now enforces, or a stray partial copy)
     degrades to the same rebuild path as a missing one, never an error
     through Daemon.start."""
-    from .snapshot import table_layout
-
     try:
         with np.load(path, allow_pickle=False) as z:
             meta = z["meta"]
             if int(meta[0]) != FORMAT_VERSION:
                 return None
             if len(meta) != len(_INT_FIELDS) + 2 or (
-                _LAYOUT_NAMES.get(int(meta[-1])) != table_layout()
+                int(meta[-1]) != _LAYOUT_CODE
             ):
-                # layout mismatch: the tables were built for the OTHER
+                # layout mismatch: the tables were built for ANOTHER
                 # probe sequence — loading them would mis-probe every
                 # key, so degrade to a rebuild like any incompatibility
                 return None

@@ -22,8 +22,7 @@ boolean matmul:
     unpacked bit planes — OR of bits IS max), AND-NOT against the seen
     matrix `R` so only first discoveries survive. Steps run under the
     shared `bounded_loop` with `max_steps = max_depth` — the same loop
-    construct selection (while_loop on CPU, counted fori on TPU) as
-    every other kernel.
+    as every other kernel.
   * First-discovery depth bookkeeping: a per-(direct-node, source)
     level plane records the step at which each source first reached
     each direct-incidence node; `req = level + 1` reproduces the host

@@ -335,8 +335,6 @@ def expand_kernel(
     def cond_fn(st: _ExpandState):
         return (st.step < max_steps) & (st.n_tasks > 0)
 
-    # loop construct per backend: engine/kernel.bounded_loop (fori+cond
-    # on TPU-class backends, early-exiting while_loop on CPU)
     from .kernel import bounded_loop
 
     final = bounded_loop(cond_fn, step_fn, init, max_steps)

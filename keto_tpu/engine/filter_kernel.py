@@ -64,13 +64,14 @@ from .kernel import (
     N_LAUNCH_STATS,
     _isolate,
     bounded_loop,
+    covering_segments,
     dedupe_phase,
     empty_launch_stats,
     flag_phase,
     program_lookup,
     update_launch_stats,
 )
-from .reverse_kernel import _rd_lookup, _seg_map, _span_probe
+from .reverse_kernel import _rd_lookup, _span_probe
 from .snapshot import RINSTR_COMPUTED, RINSTR_POISON, RINSTR_TTU
 
 # sorted-candidate padding sentinel: real object slots are int32 node
@@ -248,7 +249,7 @@ def _filter_impl(
             ),
         )
 
-        seg, j2 = _seg_map(offsets, flat_counts, F)
+        seg, j2 = covering_segments(offsets, flat_counts, F)
         in_range = j2 < jnp.minimum(total, F)
 
         # ONE [F, 16] row-gather of the stacked per-(task, slot) source

@@ -3,8 +3,8 @@
 The TPU replacement for the reference's goroutine-per-branch recursive
 walk (internal/check/engine.go:183-207 + checkgroup): all branches of all
 in-flight checks advance together as one frontier of tasks
-(query, object-slot, relation, remaining-depth), inside one
-`jax.lax.while_loop` with static shapes:
+(query, object-slot, relation, remaining-depth), inside one bounded loop
+(bounded_loop) with static shapes:
 
   per step:
     1. flag tasks whose (ns, rel) program needs host evaluation (AND/NOT
@@ -355,7 +355,7 @@ BUCKET_ROW_BYTES = 256  # one gathered bucket row (snapshot.slots_per_bucket)
 def as_bucket_rows(slots: np.ndarray, n_key_cols: int) -> np.ndarray:
     """THE stored shape of a hash-probed table: [cap, w] slot rows seen
     as the [cap/spb, spb*w] bucket rows _bucket_rows gathers (64 lanes,
-    256 B under the bucketized layout; [cap, w] itself where spb is 1).
+    256 B).
     Row-major, so this is a view and the slot order is probe_slot's.
     Every probed `*_pack` goes through here on its way to the device —
     a table stored one way and probed another cannot be built."""
@@ -739,25 +739,8 @@ def expand_phase(
     )
 
     # build candidate children by segmented gather; all per-(task, slot)
-    # source columns flatten to [F*S] 1-D arrays (no small-lane layouts).
-    # The covering-segment map is backend-picked: on TPU-class backends
-    # ONE scatter of segment-start markers + a running max (a
-    # searchsorted over [F*S] offsets is ~17 sequential gather rounds of
-    # F random rows each, and the step cost there is gather-volume
-    # bound); on CPU the scan is the expensive op (lax.cummax measured
-    # 0.8 ms per call vs cheap binary-search gathers), so searchsorted
-    # stays. Nonempty segments have strictly increasing starts, so both
-    # reconstruct the identical mapping.
-    j = jnp.arange(F, dtype=jnp.int32)
-    if scan_seg_map_backend():
-        startpos = jnp.where(flat_counts > 0, offsets, F)  # empty segs drop
-        marks = jnp.zeros(F, jnp.int32).at[startpos].max(
-            jnp.arange(1, F * S + 1, dtype=jnp.int32), mode="drop"
-        )
-        seg = jax.lax.cummax(marks) - 1  # -1 before the first segment
-    else:
-        seg = jnp.searchsorted(offsets, j, side="right").astype(jnp.int32) - 1
-    seg = jnp.clip(seg, 0, F * S - 1)
+    # source columns flatten to [F*S] 1-D arrays (no small-lane layouts)
+    seg, j = covering_segments(offsets, flat_counts, F)
     # within rides srcmat lane 7 (offsets[seg]) — no standalone gather
     in_range = j < jnp.minimum(total, F)
 
@@ -953,40 +936,18 @@ def loop_cond(max_steps: int, n_queries: int):
     return cond_fn
 
 
-def tpu_class_backend() -> bool:
-    """Is the default backend TPU-class (anything but the CPU)? Two
-    backend-dependent choices split off this: the loop construct
-    (counted_loop_backend) and expand_phase's covering-segment algorithm
-    (scan_seg_map_backend). Each has its own predicate so one can be
-    varied (debugging, a future GPU case) without silently flipping the
-    other."""
-    return jax.default_backend() not in ("cpu",)
-
-
-def counted_loop_backend() -> bool:
-    """Should BFS loops run as counted fori+cond instead of while_loop?
-
-    - TPU: the counted form was adopted in round 5 against a fixed cost
-      per while_loop iteration seen on the device arrangement of that
-      time. It runs on the attached chip (chip_smoke.py); what it gains
-      or costs there is not measured (ROADMAP S2).
-    - CPU (measured round 5): while_loop iterations are cheap and the
-      loop EXITS EARLY (the bench workload resolves in ~4 of 13
-      budgeted steps); a counted loop runs all max_steps
-      bodies-or-conds and measured 2.2x SLOWER end to end (134.7k ->
-      62.4k checks/s).
-
-    So the choice keys off the backend at trace time. Semantics are
-    identical either way (loop_cond gates both)."""
-    return tpu_class_backend()
-
-
 def bounded_loop(cond_fn, step_fn, init, max_steps: int):
-    """Drive step_fn while cond_fn holds, never past max_steps; ONE
-    construct-selection site for every BFS loop (check, sharded check,
-    both expand kernels) per counted_loop_backend."""
-    if not counted_loop_backend():
-        return jax.lax.while_loop(cond_fn, step_fn, init)
+    """Drive step_fn while cond_fn holds, never past max_steps: THE loop
+    of every BFS kernel (check, sharded check, both expand kernels, the
+    reverse and filter walks, closure powering), on every backend.
+
+    A counted fori_loop whose body is a cond: once cond_fn fails the
+    remaining trips skip step_fn, so the result is that of a while_loop
+    over the same pair. The counted form was adopted in round 5 against
+    a fixed cost per while_loop iteration seen on the device arrangement
+    of that time; what it gains or costs on the attached chip is not
+    measured (ROADMAP S2). If while_loop wins there it replaces this
+    body, here and nowhere else, for every backend."""
 
     def body(i, st):
         return jax.lax.cond(cond_fn(st), step_fn, lambda s: s, st)
@@ -994,12 +955,24 @@ def bounded_loop(cond_fn, step_fn, init, max_steps: int):
     return jax.lax.fori_loop(0, max_steps, body, init)
 
 
-def scan_seg_map_backend() -> bool:
-    """Should expand_phase build its covering-segment map with
-    scatter+cummax (TPU-class: binary search = 17 rounds of F random
-    gathers) instead of searchsorted (CPU: the scan is the expensive
-    op)? See tpu_class_backend."""
-    return tpu_class_backend()
+def covering_segments(offsets: jnp.ndarray, flat_counts: jnp.ndarray, F: int):
+    """Covering-segment map over a [F] work list: (seg[F], j[F]) where
+    slot j lies in the span [offsets[seg], offsets[seg] + flat_counts[seg])
+    of segment seg (clipped into range past the last span).
+
+    ONE scatter of segment-start markers + a running max: a searchsorted
+    over the offsets is ~17 sequential gather rounds of F random rows
+    each, and the step cost on the chip is gather-volume bound. Nonempty
+    segments have strictly increasing starts, so the running max of the
+    marks names the segment that covers each slot."""
+    n_seg = flat_counts.shape[0]
+    j = jnp.arange(F, dtype=jnp.int32)
+    startpos = jnp.where(flat_counts > 0, offsets, F)  # empty segs drop
+    marks = jnp.zeros(F, jnp.int32).at[startpos].max(
+        jnp.arange(1, n_seg + 1, dtype=jnp.int32), mode="drop"
+    )
+    seg = jax.lax.cummax(marks) - 1  # -1 before the first segment
+    return jnp.clip(seg, 0, n_seg - 1), j
 
 
 def run_bfs_loop(step_fn, init, max_steps: int, n_queries: int):

@@ -6,9 +6,8 @@ reach?" (served there by the Leopard set index) and its dual "which
 subjects reach this object?". Both are set-valued graph joins that batch
 into the same bucketized-gather shape the check kernel runs (TrieJax /
 GraphBLAS formulation: frontier expansion = batched sparse gather), so
-they ride the identical backend-selected bounded loop
-(engine/kernel.bounded_loop), dedupe, and cause-coded host-fallback
-machinery.
+they ride the identical bounded loop (engine/kernel.bounded_loop),
+dedupe, and cause-coded host-fallback machinery.
 
 ListObjects — reverse BFS over the TRANSPOSED mirror
 (snapshot.build_reverse_tables / build_reverse_programs):
@@ -75,6 +74,7 @@ from .kernel import (
     _isolate,
     _multi_pair_key_probe,
     bounded_loop,
+    covering_segments,
     dedupe_phase,
     empty_launch_stats,
     flag_phase,
@@ -82,7 +82,6 @@ from .kernel import (
     pack_row_table,
     pack_rh_span_table,
     program_lookup,
-    scan_seg_map_backend,
     update_launch_stats,
 )
 from .snapshot import (
@@ -231,22 +230,6 @@ def _span_probe(tables, prefix: str, k1, k2, probes: int):
     return start, length
 
 
-def _seg_map(offsets: jnp.ndarray, flat_counts: jnp.ndarray, F: int):
-    """Covering-segment map over a [F] work list (backend-picked, see
-    kernel.expand_phase): slot j -> the segment whose span contains j."""
-    n_seg = flat_counts.shape[0]
-    j = jnp.arange(F, dtype=jnp.int32)
-    if scan_seg_map_backend():
-        startpos = jnp.where(flat_counts > 0, offsets, F)
-        marks = jnp.zeros(F, jnp.int32).at[startpos].max(
-            jnp.arange(1, n_seg + 1, dtype=jnp.int32), mode="drop"
-        )
-        seg = jax.lax.cummax(marks) - 1
-    else:
-        seg = jnp.searchsorted(offsets, j, side="right").astype(jnp.int32) - 1
-    return jnp.clip(seg, 0, n_seg - 1), j
-
-
 def _bump_emit(q, emit, counts_so_far, F: int, B: int):
     """Per-query bump allocation for <=1 emission per task: returns
     (slot_within_query[F]) for emitting tasks (garbage elsewhere). Same
@@ -345,7 +328,7 @@ def _list_objects_impl(
             CAUSE_FRONTIER_OVERFLOW, 0,
         ).astype(jnp.int32),
     )
-    seg, j = _seg_map(offsets, seed_counts, F)
+    seg, j = covering_segments(offsets, seed_counts, F)
     in_range = j < jnp.minimum(total, F)
     e = jnp.clip(s_start[seg] + (j - offsets[seg]), 0, max(n_sedges - 1, 0))
     if n_sedges:
@@ -462,7 +445,7 @@ def _list_objects_impl(
             ).astype(jnp.int32)
         )
 
-        seg, j = _seg_map(offsets, flat_counts, F)
+        seg, j = covering_segments(offsets, flat_counts, F)
         in_range = j < jnp.minimum(total, F)
 
         # ONE [F, 16] row-gather of the stacked per-(task, slot) source
@@ -759,7 +742,7 @@ def _list_subjects_impl(
             ).astype(jnp.int32)
         )
 
-        seg, j = _seg_map(offsets, flat_counts, F)
+        seg, j = covering_segments(offsets, flat_counts, F)
         in_range = j < jnp.minimum(total, F)
 
         srcmat = jnp.stack(

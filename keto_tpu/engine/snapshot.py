@@ -82,48 +82,9 @@ def hash_combine(*parts: np.ndarray) -> np.ndarray:
     return h
 
 
-_TABLE_LAYOUT: Optional[str] = None
-
-
-def table_layout() -> str:
-    """Process-global probe-table layout, keyed off the backend class:
-
-      - "bucketized" (TPU class): probes fill whole 256-byte bucket rows
-        so the device kernel pays ONE gathered row per spb slots of
-        probe depth — the gather-volume cost model the layout was built
-        for (tools/microbench_gather_layout.py).
-      - "compact" (CPU): classic double hashing (1 slot per bucket, 4n
-        capacity, r04 sizing). The CPU backend gathers single lanes, so
-        bucket rows buy nothing there while the 8n capacity doubles the
-        cache footprint — the measured CPU served regression (ROADMAP
-        item 1(e): 258.4k with bucketized vs the ≥320k compact
-        baseline) is exactly that cache cost.
-
-    `KETO_TABLE_LAYOUT=compact|bucketized` overrides (A/B harnesses and
-    the cross-layout checkpoint tests). Resolved lazily ONCE — every
-    builder, host probe, and kernel must agree on the sequence, so the
-    layout cannot flip mid-process; checkpoints carry the layout code
-    and a mismatched load rebuilds instead of mis-probing."""
-    global _TABLE_LAYOUT
-    if _TABLE_LAYOUT is None:
-        import os
-
-        env = os.environ.get("KETO_TABLE_LAYOUT", "").strip().lower()
-        if env in ("compact", "bucketized"):
-            _TABLE_LAYOUT = env
-        else:
-            import jax
-
-            _TABLE_LAYOUT = (
-                "compact" if jax.default_backend() == "cpu"
-                else "bucketized"
-            )
-    return _TABLE_LAYOUT
-
-
 def slots_per_bucket(n_key_cols: int) -> int:
-    """Open-addressing bucket size by table kind under the bucketized
-    layout: every bucket is one 256-byte gather row (64 int32 lanes —
+    """Open-addressing bucket size by table kind: every bucket is one
+    256-byte gather row (64 int32 lanes —
     the measured cost of a random row-gather is constant in row width up
     to at least 256 B, tools/microbench_gather_layout.py), so 2-key pair
     tables (4-int packed entries) hold 16 slots per bucket and 5-key
@@ -131,10 +92,7 @@ def slots_per_bucket(n_key_cols: int) -> int:
     at the build load factor a bucket holds ~2 keys on average and the
     MAX occupancy (which is the probe limit under the bucketized
     sequence) reaches 9-14 on real tables — 16 slots keep that inside
-    ONE gathered bucket row. Under the compact layout every table is 1
-    slot per bucket (probe_slot degenerates to classic double hashing)."""
-    if table_layout() == "compact":
-        return 1
+    ONE gathered bucket row."""
     return 16 if n_key_cols <= 2 else 8
 
 
@@ -153,10 +111,8 @@ def probe_slot(h1, h2, j, cap: int, spb: int = 8):
     in row width 32-256 B, so a bucket row costs the same as one slot
     row and cuts probe gathers ~P-fold).
 
-    Vectorized over numpy uint32 arrays (h1/h2/j broadcast). spb=1
-    (compact layout) degenerates to classic double hashing:
-    (h1 + j*h2) & (cap - 1)."""
-    sh = np.uint32(spb.bit_length() - 1)  # log2(spb); spb is 1, 8 or 16
+    Vectorized over numpy uint32 arrays (h1/h2/j broadcast)."""
+    sh = np.uint32(spb.bit_length() - 1)  # log2(spb); spb is 8 or 16
     bmask = np.uint32(cap // spb - 1)
     jb = np.asarray(j, dtype=np.uint32) >> sh
     js = np.asarray(j, dtype=np.uint32) & np.uint32(spb - 1)
@@ -199,12 +155,6 @@ def table_capacity(n: int, min_capacity: int = 64) -> int:
     static shapes, where occupancy is tiny and shape stability is the
     contract) pass boost_load=False to _build_hash_table instead."""
     cap = hash_table_capacity(n, min_capacity)
-    if table_layout() == "compact":
-        # compact layout keeps the classic r04 4n sizing — the capacity
-        # boost exists to bound BUCKET occupancy, which compact tables
-        # (1 slot per bucket) don't have; the halved footprint is the
-        # point of the CPU default (table_layout docstring)
-        return cap
     if cap < 8 * n:
         # bucketized tables run HALF the classic load: the probe limit
         # IS the max bucket occupancy, so average occupancy ~1 (8-slot

@@ -238,9 +238,8 @@ class StoreBusyError(StoreUnavailableError):
 
 class CheckpointIncompatibleError(KetoError):
     # A checkpoint file that is INTACT but unusable by this process —
-    # wrong format version or a cross-layout table build (bucketized vs
-    # compact place keys in different slots; probing one with the other
-    # mis-answers every lookup). Distinct from a torn/corrupt file,
+    # wrong format version or a table build of another layout (its keys
+    # lie in other slots; probing it mis-answers every lookup). Distinct from a torn/corrupt file,
     # which silently degrades to a rebuild: an explicit restore request
     # (the HA follower's cold start, engine/checkpoint.restore_snapshot)
     # answering from such a file would be WRONG, so the caller gets a

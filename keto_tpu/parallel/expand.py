@@ -16,8 +16,8 @@ and three collectives:
     all-reduce instead of per-step traffic
 
 The frontier, per-query counters, and needs_host masks stay replicated —
-every device runs the identical merged state, so the while_loop trip
-count agrees across the mesh.
+every device runs the identical merged state, so the loop's trip count
+agrees across the mesh.
 """
 
 from __future__ import annotations
@@ -225,9 +225,8 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
         def cond_fn(st: _ExpandState):
             return (st.step < max_steps) & (st.n_tasks > 0)
 
-        # loop construct per backend (engine/kernel.bounded_loop); the
-        # predicate is replicated, so all shards branch together and
-        # step_fn's collectives stay aligned either way
+        # the predicate is replicated, so all shards branch together
+        # and step_fn's collectives stay aligned
         final = bounded_loop(cond_fn, step_fn, init, max_steps)
         # single merge: each slot was written (value+1) by its owner only
         merged = [

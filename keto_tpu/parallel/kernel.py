@@ -10,7 +10,7 @@ slot and two ICI collectives per step:
     task's CSR row lives on one shard; other shards contribute nothing)
 
 The frontier and per-query result masks stay replicated: every device
-runs the identical merged state, so the while_loop trip count agrees
+runs the identical merged state, so the BFS loop's trip count agrees
 across the mesh and the host reads back one copy. This mirrors the
 scaling-book recipe — pick a mesh, shard the big arrays, let collectives
 ride ICI — rather than the reference's shared-SQL-database fan-out
@@ -148,10 +148,8 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
                 ctx_hit, needs_host, *isl_state, st.step + 1, stats,
             )
 
-        # loop construct per backend (engine/kernel.bounded_loop via
-        # run_bfs_loop: counted fori+cond on TPU-class backends, early-
-        # exiting while_loop on CPU meshes). The trip decision is a pure
-        # function of the REPLICATED state either way, so every shard
+        # engine/kernel.bounded_loop via run_bfs_loop: the trip decision
+        # is a pure function of the REPLICATED state, so every shard
         # takes the same branch and the collectives inside step_fn stay
         # aligned across the mesh.
         init = seed_state(q_obj, q_rel, q_depth, q_valid, F, n_island_cap, K)

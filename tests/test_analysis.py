@@ -197,6 +197,39 @@ class TestKetolintGoldens:
         ))
         assert rc == 1 and "fresh jax.jit" in out
 
+    @pytest.mark.parametrize("source, said", [
+        (
+            "import jax\n"
+            "def bounded_loop(cond, step, init):\n"
+            "    if jax.default_backend() == 'cpu':\n"
+            "        return jax.lax.while_loop(cond, step, init)\n",
+            "default_backend()",
+        ),
+        (
+            "def table_layout(device):\n"
+            "    return 'compact' if device.platform != 'tpu' else 'bucketized'\n",
+            ".platform",
+        ),
+    ], ids=["default_backend", "platform"])
+    def test_one_program_backend_branch(self, tmp_path, source, said):
+        # the directory decides: engine/ and parallel/ build the programs
+        (tmp_path / "engine").mkdir()
+        rc, out = run_lint_on(tmp_path, "engine/kernel.py", source)
+        assert rc == 1 and out.count("one-program") == 1 and said in out
+        rc, out = run_lint_on(tmp_path, "registry.py", source)
+        assert rc == 0, out
+
+    def test_one_program_holds_with_no_suppression(self):
+        from keto_tpu.analysis.source_scan import iter_py_files, package_root
+
+        programs = [
+            p for d in sorted(lint._PROGRAM_DIRS)
+            for p in iter_py_files(package_root() / d)
+        ]
+        assert len(programs) > 20
+        assert lint.lint_paths(programs, None, REPO) == []
+        assert not any("allow[one-program]" in p.read_text() for p in programs)
+
     def test_suppression_silences_with_reason(self, tmp_path):
         rc, out = run_lint_on(tmp_path, "mod.py", (
             "import threading, time\n"

@@ -44,6 +44,18 @@ TUPLES = ts(
 )
 
 
+def forge_retired_layout(path):
+    """Make a sound v4 checkpoint read as an older CPU process wrote one:
+    the meta vector's last entry the retired one-slot-a-bucket code. (The
+    tables are left as built: nothing may get as far as probing them.)"""
+    with np.load(path, allow_pickle=False) as z:
+        arrays = {k: z[k] for k in z.files}
+    assert arrays["meta"][0] == 4 and arrays["meta"][-1] == 0
+    arrays["meta"][-1] = 1
+    with open(path, "wb") as f:
+        np.savez(f, **arrays)
+
+
 class TestStableFingerprint:
     def test_deterministic(self):
         a = stable_fingerprint([{"x": 1}, "y"])
@@ -457,23 +469,35 @@ class TestStrictRestore:
             cp.restore_snapshot(path)
         assert "format" in str(ei.value.debug)
 
-    def test_cross_layout_raises_typed(self, tmp_path, monkeypatch):
-        # Write the checkpoint as if a bucketized-layout process (a TPU
-        # leader) had published it, then restore on this compact-layout
-        # process: the tables would mis-answer, so the restore must be
-        # refused with the typed error, not a crash and not a silent
-        # rebuild.
+    def test_cross_layout_raises_typed(self, tmp_path):
+        # A v4 file as a CPU process published it before the layouts were
+        # merged: its tables would mis-answer under today's probe
+        # sequence, so the audit says not loadable, the restore by name is
+        # refused with the typed error (not a crash, not a silent
+        # rebuild), and the engine's own probe degrades to a rebuild.
         from keto_tpu.engine import checkpoint as cp
-        from keto_tpu.engine import snapshot as snapmod
         from keto_tpu.errors import CheckpointIncompatibleError
 
-        if snapmod.table_layout() != "compact":
-            pytest.skip("needs a compact-layout host process")
-        monkeypatch.setattr(snapmod, "table_layout", lambda: "bucketized")
-        path = self._saved(tmp_path)
-        monkeypatch.undo()
+        m = MemoryManager()
+        m.write_relation_tuples(TUPLES)
+        cfg = Config({"check": {"mirror_cache": str(tmp_path)}})
+        cfg.set_namespaces(NAMESPACES)
+        e1 = TPUCheckEngine(m, cfg)
+        assert e1.check_is_member(ts("files:a#view@bob")[0])
+        e1.flush_checkpoints()
+        path = cp.mirror_cache_path(str(tmp_path), "default")
+        assert cp.FORMAT_VERSION == 4
+        assert cp.checkpoint_info(path)["loadable"] is True
+        forge_retired_layout(path)
+
         info = cp.checkpoint_info(path)
-        assert info["loadable"] is False
+        assert info["loadable"] is False and info["table_layout"] == "compact"
         with pytest.raises(CheckpointIncompatibleError) as ei:
             cp.restore_snapshot(path)
-        assert "layout" in str(ei.value.debug)
+        assert "'compact' table layout" in str(ei.value.debug)
+        assert cp.load_snapshot(path) is None
+        e2 = TPUCheckEngine(m, cfg)
+        assert e2.check_is_member(ts("files:a#view@bob")[0])
+        assert e2.stats["snapshot_builds"] == 1
+        assert e2.stats.get("snapshot_loads") is None
+        assert e2.stats.get("checkpoint_fallback_corrupt") == 1

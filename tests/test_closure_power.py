@@ -31,7 +31,6 @@ from keto_tpu.engine.closure_power import (
     PoweringUnsupported,
     power_closure_device,
 )
-from keto_tpu.engine.definitions import Membership
 from keto_tpu.engine.reference import ReferenceEngine
 from keto_tpu.ketoapi import RelationTuple
 from keto_tpu.namespace import Namespace
@@ -419,98 +418,36 @@ class TestSyncBudget:
         assert [f for f in findings if f.rule == "host-sync"] == []
 
 
-class TestTableLayoutDefaults:
-    """The backend-keyed table layout satellite (ROADMAP 1(e)): compact
-    r04 probing on CPU backends — where the bucketized gather costs
-    ~20% of the flagship leg — bucketized on TPU, overridable either
-    way with KETO_TABLE_LAYOUT."""
-
-    def _reset(self, monkeypatch, value=None):
-        import keto_tpu.engine.snapshot as snapshot
-
-        monkeypatch.setattr(snapshot, "_TABLE_LAYOUT", None)
-        if value is None:
-            monkeypatch.delenv("KETO_TABLE_LAYOUT", raising=False)
-        else:
-            monkeypatch.setenv("KETO_TABLE_LAYOUT", value)
-        return snapshot
-
-    def test_cpu_defaults_to_compact(self, monkeypatch):
-        import jax
-
-        snapshot = self._reset(monkeypatch)
-        want = "compact" if jax.default_backend() == "cpu" else "bucketized"
-        assert snapshot.table_layout() == want
-
-    @pytest.mark.parametrize("layout", ["compact", "bucketized"])
-    def test_env_override_wins(self, monkeypatch, layout):
-        snapshot = self._reset(monkeypatch, layout)
-        assert snapshot.table_layout() == layout
-
-    def test_compact_probes_are_classic_double_hashing(self, monkeypatch):
-        snapshot = self._reset(monkeypatch, "compact")
-        assert snapshot.slots_per_bucket(5) == 1
-        assert snapshot.slots_per_bucket(2) == 1
-        cap = 1 << 10
-        h1 = np.asarray([17, 923, 64], dtype=np.uint32)
-        h2 = np.asarray([3, 11, 7], dtype=np.uint32)
-        for j in range(4):
-            got = snapshot.probe_slot(h1, h2, j, cap, 1)
-            want = (h1 + np.uint32(j) * h2) & np.uint32(cap - 1)
-            assert (np.asarray(got) == want).all(), j
-
-    def test_compact_capacity_drops_bucket_boost(self, monkeypatch):
-        snapshot = self._reset(monkeypatch, "compact")
-        compact_cap = snapshot.table_capacity(1000)
-        snapshot = self._reset(monkeypatch, "bucketized")
-        bucket_cap = snapshot.table_capacity(1000)
-        assert compact_cap < bucket_cap
-
-    def test_engine_answers_identically_under_both_layouts(self, monkeypatch):
-        results = {}
-        for layout in ("compact", "bucketized"):
-            self._reset(monkeypatch, layout)
-            tuples, owners = deep_tuples()
-            engine = make_engine(tuples, closure=False)
-            queries = deep_queries(owners)
-            results[layout] = [
-                r.membership for r in engine.check_batch(queries)
-            ]
-        assert results["compact"] == results["bucketized"]
-        assert Membership.IS_MEMBER in results["compact"]
-
-
 class TestCheckpointLayoutVersioning:
     """Checkpoints record the table layout they were packed under: a
-    snapshot built bucketized must NOT warm-start an engine probing
-    compact (the packed hash tables are physically different)."""
+    snapshot a CPU process once built one slot to a bucket must NOT
+    warm-start an engine probing bucket rows (the packed hash tables are
+    physically different)."""
 
     def _small_snapshot(self):
         tuples, _ = deep_tuples(n_chains=2)
         engine = make_engine(tuples, closure=False)
         return engine._ensure_state().snapshot
 
-    def test_layout_mismatch_rejected(self, tmp_path, monkeypatch):
-        import keto_tpu.engine.snapshot as snapshot
+    def test_layout_mismatch_rejected(self, tmp_path):
+        from test_checkpoint import forge_retired_layout
+
         from keto_tpu.engine.checkpoint import (
             checkpoint_info,
             load_snapshot,
             save_snapshot,
         )
 
-        monkeypatch.setattr(snapshot, "_TABLE_LAYOUT", None)
-        monkeypatch.setenv("KETO_TABLE_LAYOUT", "compact")
         snap = self._small_snapshot()
         path = str(tmp_path / "ckpt")
         save_snapshot(snap, path)
 
         info = checkpoint_info(path)
-        assert info["table_layout"] == "compact"
+        assert info["table_layout"] == "bucketized"
         assert info["loadable"]
         assert load_snapshot(path) is not None
 
-        monkeypatch.setattr(snapshot, "_TABLE_LAYOUT", None)
-        monkeypatch.setenv("KETO_TABLE_LAYOUT", "bucketized")
+        forge_retired_layout(path)
         info = checkpoint_info(path)
         assert info["table_layout"] == "compact"
         assert not info["loadable"]

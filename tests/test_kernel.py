@@ -704,78 +704,79 @@ class TestHostFallbackCauses:
         assert 'keto_tpu_host_fallback_total{cause="rewrite_cap"} 2.0' in text
 
 
-class TestCountedLoopBranch:
-    """bounded_loop picks fori+cond on TPU-class backends and while_loop
-    on CPU (engine/kernel.counted_loop_backend). CPU test runs would
-    otherwise never execute the counted branch — force it and pin the
-    differential so the on-chip construct stays covered off-chip.
+class TestBoundedLoop:
+    """kernel.bounded_loop is the one loop of every BFS kernel, a counted
+    fori_loop whose body is a cond, and kernel.covering_segments the one
+    scan-form segment map: each is held to the plain construct it stands
+    for, and the check and expand kernels to engine/reference.py across
+    an early exit."""
 
-    Forcing requires clearing jit caches: earlier tests pre-warm traces
-    for the same (shapes, statics), and a cached executable would bypass
-    the patched selector entirely — each test asserts the selector
-    actually RAN during tracing (review r5 finding: the unasserted
-    version was vacuous)."""
-
-    @pytest.fixture(autouse=True)
-    def _cache_hygiene(self):
-        """Forced-branch executables must not leak into the global jit
-        cache (a later same-shape test would silently run the wrong
-        construct), and stale pre-force caches must not swallow the
-        forced trace — clear on both edges."""
+    @pytest.mark.parametrize(
+        "start,stop,max_steps",
+        [(0, 5, 8), (0, 8, 8), (0, 20, 8), (3, 3, 8), (0, 5, 0)],
+        ids=["early-exit", "exact", "capped", "never-true", "no-steps"],
+    )
+    def test_is_a_capped_while_loop(self, start, stop, max_steps):
         import jax
+        import jax.numpy as jnp
 
-        jax.clear_caches()
-        yield
-        jax.clear_caches()
+        from keto_tpu.engine.kernel import bounded_loop
 
-    def _force_counted(self, monkeypatch):
-        import jax
+        def cond_fn(st):
+            return st[0] < stop
 
-        from keto_tpu.engine import kernel as kmod
+        def step_fn(st):
+            return st[0] + 1, st[1] * 2 + st[0]
 
-        calls = {"n": 0}
+        want, steps = (start, 1), 0
+        while steps < max_steps and want[0] < stop:
+            want, steps = (want[0] + 1, want[1] * 2 + want[0]), steps + 1
+        got = jax.jit(
+            lambda i, acc: bounded_loop(cond_fn, step_fn, (i, acc), max_steps)
+        )(jnp.int32(start), jnp.int32(1))
+        assert (int(got[0]), int(got[1])) == want
 
-        def forced():
-            calls["n"] += 1
-            return True
+    @pytest.mark.parametrize("seed", range(4))
+    def test_covering_segments_is_the_binary_search(self, seed):
+        """Empty segments, a work list cut short at F and one that ends
+        before F: slot j's segment is the one searchsorted names."""
+        import numpy as np
 
-        # both TPU-class choices flip together: the point is covering
-        # the on-chip configuration (counted loop + scan seg map) on CPU
-        monkeypatch.setattr(kmod, "counted_loop_backend", forced)
-        monkeypatch.setattr(kmod, "scan_seg_map_backend", forced)
-        jax.clear_caches()
-        return calls
+        from keto_tpu.engine.kernel import covering_segments
 
-    def test_counted_branch_matches_reference(self, monkeypatch):
-        calls = self._force_counted(monkeypatch)
-        e = make_tpu_engine(REWRITE_NAMESPACES, REWRITE_TUPLES, max_depth=100)
-        for query, expected in REWRITE_CASES:
-            res = e.check_batch([RelationTuple.from_string(query)], 100)[0]
-            assert res.error is None
-            want = expected == Membership.IS_MEMBER
-            assert res.allowed == want, query
-        assert calls["n"] > 0, "counted branch never traced (cache hit?)"
+        rng = np.random.default_rng(seed)
+        F, n_seg = 64, 48
+        counts = rng.integers(0, 4 + 2 * seed, n_seg).astype(np.int32)
+        counts[rng.random(n_seg) < 0.4] = 0
+        offsets = (np.cumsum(counts) - counts).astype(np.int32)
+        seg, j = covering_segments(offsets, counts, F)
+        assert list(np.asarray(j)) == list(range(F))
+        live = np.arange(F) < counts.sum()
+        want = np.searchsorted(offsets, np.arange(F), side="right") - 1
+        assert np.asarray(seg)[live].tolist() == want[live].tolist()
+        assert ((np.asarray(seg) >= 0) & (np.asarray(seg) < n_seg)).all()
 
-    def test_counted_branch_early_exit_equivalence(self, monkeypatch):
-        """A batch that resolves in ~2 steps must produce identical
-        verdicts through both loop constructs (the cond pass-through
-        must not perturb state)."""
+    def test_early_exit_matches_reference(self):
+        """A batch that resolves in ~2 of its budgeted steps: the trips
+        the cond passes through must not perturb the verdicts."""
         ns = [Namespace(name="n", relations=[Relation(name="r")])]
         tuples = [f"n:o{i}#r@u{i}" for i in range(64)]
         queries = [
             RelationTuple.from_string(f"n:o{i}#r@u{i % 3}") for i in range(64)
         ]
-        e1 = make_tpu_engine(ns, tuples)
-        base = [r.allowed for r in e1.check_batch(queries)]
-        calls = self._force_counted(monkeypatch)
-        e2 = make_tpu_engine(ns, tuples)
-        forced = [r.allowed for r in e2.check_batch(queries)]
-        assert forced == base
-        assert calls["n"] > 0, "counted branch never traced (cache hit?)"
+        e = make_tpu_engine(ns, tuples)
+        got = [r.allowed for r in e.check_batch(queries)]
+        want = [
+            e.reference.check_relation_tuple(q, 0).membership
+            == Membership.IS_MEMBER
+            for q in queries
+        ]
+        assert got == want and sum(want) == 3
+        assert e.stats["host_checks"] == 0
 
-    def test_counted_branch_expand_kernel(self, monkeypatch):
-        """The expand kernel shares bounded_loop; its counted branch
-        must assemble identical trees."""
+    def test_expand_kernel_matches_reference(self):
+        """The expand kernel shares bounded_loop: a tree that is complete
+        after two of its four budgeted steps."""
         ns = [Namespace(name="n", relations=[
             Relation(name="r"), Relation(name="g"),
         ])]
@@ -783,12 +784,8 @@ class TestCountedLoopBranch:
             [f"n:o#r@(n:m{i}#g)" for i in range(4)]
             + [f"n:m{i}#g@u{j}" for i in range(4) for j in range(3)]
         )
-        e1 = make_tpu_engine(ns, tuples)
+        e = make_tpu_engine(ns, tuples)
         sub = SubjectSet("n", "o", "r")
-        base = e1.expand_batch([sub], 4)[0]
-        calls = self._force_counted(monkeypatch)
-        e2 = make_tpu_engine(ns, tuples)
-        forced = e2.expand_batch([sub], 4)[0]
-        assert str(forced) == str(base)
-        assert calls["n"] > 0, "counted branch never traced (cache hit?)"
-
+        device = e.expand_batch([sub], 4)[0]
+        assert str(device) == str(e.reference.expand(sub, 4))
+        assert e.stats["host_expands"] == 0

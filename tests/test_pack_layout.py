@@ -3,10 +3,13 @@
 Every `*_pack` a kernel probes is built, uploaded and kept as the bucket
 rows `_bucket_rows` gathers: `[cap / spb, spb * w]`, with
 `spb = snapshot.slots_per_bucket(n_key_cols)`. These cases hold the three
-builders every such table goes through to that shape under both layouts,
-and hold the device's probe to the host's: the buckets `_bucket_rows`
-fetches are the slots `snapshot.probe_slot` walks, so the kernel's probes
-find exactly what `compact.py`'s host probe finds.
+builders every such table goes through to that shape, and hold the
+device's probe to the host's: the buckets `_bucket_rows` fetches are the
+slots `snapshot.probe_slot` walks, so the kernel's probes find exactly
+what `compact.py`'s host probe finds. The `_overlay` tables are the delta
+overlay's (engine/delta.py): the fixed 4n capacity with no load boost, one
+bucket loaded past its slots, so that a chain crosses into a second
+gathered bucket row.
 """
 
 from __future__ import annotations
@@ -19,31 +22,37 @@ from keto_tpu.engine import compact, kernel, snapshot
 from keto_tpu.engine.snapshot import EMPTY
 
 N_KEYS = 300  # present keys; as many absent ones are probed beside them
+OVERLAY_CAPACITY = snapshot.hash_table_capacity(N_KEYS)  # the fixed 4n shape
 
 
 class Table:
     """One built table: its columns, and the pack its builder made."""
 
     def __init__(self, builder: str, rng: np.random.Generator):
-        self.builder = builder
-        self.n_key_cols = 5 if builder == "edge" else 2
-        self.width = 8 if builder == "edge" else 4
+        self.overlay = builder.endswith("_overlay")
+        self.builder = builder.removesuffix("_overlay")
+        self.n_key_cols = 5 if self.builder == "edge" else 2
+        self.width = 8 if self.builder == "edge" else 4
+        self.spb = snapshot.slots_per_bucket(self.n_key_cols)
         # distinct keys: the first half goes into the table, the rest stays out
         drawn = np.unique(
             rng.integers(0, 1 << 20, size=(4 * N_KEYS, self.n_key_cols)), axis=0
         )
         drawn = drawn[rng.permutation(len(drawn))][: 2 * N_KEYS].astype(np.int32)
         self.present, self.absent = drawn[:N_KEYS], drawn[N_KEYS:]
+        if self.overlay:
+            self.crowd_one_bucket(rng)
         self.values = np.arange(N_KEYS, dtype=np.int32)
+        fixed = {"min_capacity": OVERLAY_CAPACITY, "boost_load": False}
         *self.cols, self.probes = snapshot._build_hash_table(
-            tuple(self.present[:, i] for i in range(self.n_key_cols)), self.values
+            tuple(self.present[:, i] for i in range(self.n_key_cols)), self.values,
+            **(fixed if self.overlay else {}),
         )
         self.cap = len(self.cols[0])
-        self.spb = snapshot.slots_per_bucket(self.n_key_cols)
-        if builder == "edge":
+        if self.builder == "edge":
             self.pack = kernel.pack_edge_table(*self.cols)
             self.slots = self.cols
-        elif builder == "pair":
+        elif self.builder == "pair":
             self.pack = kernel.pack_pair_table(*self.cols)
             self.slots = self.cols
         else:  # the value is a CSR row; its span rides the two value lanes
@@ -58,6 +67,21 @@ class Table:
                 np.where(held, self.row_ptr[np.clip(row, 0, None)], EMPTY),
                 np.where(held, self.row_ptr[np.clip(row, 0, None) + 1], EMPTY),
             ]
+
+    def crowd_one_bucket(self, rng: np.random.Generator):
+        """Swap in keys that start in one bucket, more than it has slots
+        (and as many absent ones that start there too): the builder
+        spills them into their second buckets, so the longest chain, the
+        probe limit, is deeper than one gathered row."""
+        pool = np.unique(
+            rng.integers(1 << 20, 1 << 21, size=(1 << 16, self.n_key_cols)), axis=0
+        ).astype(np.int32)
+        h1, _ = self.hashes(pool)
+        first = h1 & np.uint32(OVERLAY_CAPACITY // self.spb - 1)
+        crowd = pool[first == first[0]]
+        n = self.spb + 4
+        assert len(crowd) >= 2 * n
+        self.present[:n], self.absent[:n] = crowd[:n], crowd[n : 2 * n]
 
     def queries(self) -> np.ndarray:
         return np.concatenate([self.present, self.absent])
@@ -79,22 +103,17 @@ class Table:
         return -1
 
 
-@pytest.fixture(params=["compact", "bucketized"])
-def layout(request, monkeypatch):
-    monkeypatch.setattr(snapshot, "_TABLE_LAYOUT", request.param)
-    return request.param
-
-
-@pytest.fixture(params=["edge", "pair", "rh_span"])
-def table(request, layout):
+@pytest.fixture(params=["edge", "pair", "rh_span", "edge_overlay", "pair_overlay"])
+def table(request):
     return Table(request.param, np.random.default_rng(29))
 
 
-def test_pack_is_stored_as_bucket_rows(table, layout):
-    want_spb = 1 if layout == "compact" else 64 // table.width
-    assert table.spb == want_spb
+def test_pack_is_stored_as_bucket_rows(table):
+    assert table.spb == 64 // table.width
     assert table.pack.shape == (table.cap // table.spb, table.spb * table.width)
     assert table.pack.dtype == np.int32
+    if table.overlay:
+        assert table.cap == OVERLAY_CAPACITY and table.probes > table.spb
 
 
 def test_slot_rows_hold_what_the_columns_gave(table):
@@ -160,7 +179,7 @@ def test_device_probe_finds_what_the_host_probe_finds(table):
     [
         ((1024, 64), True),  # bucket rows
         ((4, 512, 64), True),  # a stack of shards' bucket rows
-        ((8192, 8), False),  # slot rows of the compact layout
+        ((8192, 8), False),  # slot rows, before as_bucket_rows
         ((4099, 2), False),  # e_pack: two columns, read as columns
         ((4096,), False),
     ],

@@ -7,10 +7,9 @@ kernel's arguments and statics are captured where the engine passes them.
 What the TPU compiler would refuse on the chip — a program that does not
 fit its memory above all — it refuses here, at no chip time.
 
-The code picks its TPU branches (counted loops, bucketized tables) from
-`jax.default_backend()`, which says `cpu` during such a compile, so the
-`tpu_branches` fixture steers it. Nothing runs on a device: a compile that
-passes is not a chip run.
+The package builds one program on every backend (ketolint's `one-program`
+rule), so what is traced here on the CPU is what the chip traces. Nothing
+runs on a device: a compile that passes is not a chip run.
 
 This is the only file that describes a topology, and it does so inside a
 fixture: see /opt/skills/guides/on-chip-measurement section 2 for why no
@@ -34,7 +33,6 @@ from keto_tpu.engine import (
     filter_kernel,
     kernel,
     reverse_kernel,
-    snapshot,
 )
 from keto_tpu.engine.tpu_engine import TPUCheckEngine
 from keto_tpu.ketoapi import RelationTuple, SubjectSet
@@ -81,17 +79,6 @@ def no_compile_cache():
 
 
 @pytest.fixture(scope="module")
-def tpu_branches():
-    """Build and trace as on the chip: bucketized probe tables, counted
-    BFS loops, the scan-based segment map."""
-    mp = pytest.MonkeyPatch()
-    mp.setattr(kernel, "tpu_class_backend", lambda: True)
-    mp.setattr(snapshot, "_TABLE_LAYOUT", "bucketized")
-    yield
-    mp.undo()
-
-
-@pytest.fixture(scope="module")
 def drive():
     return chip_smoke.build_drive(chip_smoke.DEFAULT_SEED, chip_smoke.DEFAULT_TUPLES)
 
@@ -104,7 +91,7 @@ def store(drive):
 
 
 @pytest.fixture(scope="module")
-def engine(tpu_branches, store):
+def engine(store):
     return TPUCheckEngine(store, chip_smoke.drive_config(serve=False))
 
 
@@ -200,7 +187,7 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
 
 
 @pytest.fixture(scope="module")
-def closure_engine(tpu_branches):
+def closure_engine():
     """The differential tier's closure set (tools/tpu_test_tier.py): a
     32-deep chain behind the Leopard index, device powering on. The index
     is off by default, so the served smoke never launches these two
@@ -268,7 +255,7 @@ def test_kernel_compiles_for_the_chip(
 
 
 @pytest.fixture(scope="module")
-def sharded_engine(tpu_branches, store):
+def sharded_engine(store):
     return TPUCheckEngine(
         store, chip_smoke.drive_config(serve=False), mesh=default_mesh(4)
     )
