@@ -398,10 +398,14 @@ def check_launch_kinds(daemon) -> None:
         launches[kind] = len(entries)
         if kind == "check":
             for bucket in sorted({e["bucket"] for e in entries}):
-                walls = [e["wall_ms"] for e in entries if e["bucket"] == bucket]
+                rung = [e for e in entries if e["bucket"] == bucket]
                 check_ms[bucket] = {
-                    "launches": len(walls),
-                    "wall_ms_median": float(np.median(walls)),
+                    "launches": len(rung),
+                    "frontier_cap": sorted({e["frontier_cap"] for e in rung}),
+                    **{
+                        f"{ms}_median": float(np.median([e[ms] for e in rung]))
+                        for ms in ("wall_ms", "device_ms")
+                    },
                 }
     emit("launches", t0, flightrec_entries=launches,
          check_launch_by_bucket=check_ms)

@@ -68,7 +68,10 @@ from .snapshot import (
     encode_query_batch,
 )
 
-_BUCKETS = (16, 64, 256, 1024, 4096, 16384)
+# the launch ladder: a batch of n items runs in the smallest power of two
+# >= n, floor 16, so above the floor under half of a launch's static shape
+# is padding. Every batched verb reads this one ladder.
+_BUCKETS = tuple(1 << k for k in range(4, 15))
 
 _paginate = paginate_names
 
@@ -140,11 +143,13 @@ class TPUCheckEngine:
         self.nid = nid
         # the frontier must hold at least one task per batched query
         self.frontier_cap = max(frontier_cap, _BUCKETS[0])
-        # scale the per-launch frontier down for small buckets (step cost
-        # is O(frontier), so a 16-query launch must not pay a 16k-task
-        # frontier). False pins every launch at `frontier_cap` — for
-        # operators who sized it explicitly to keep wide-fanout queries
-        # on-device (overflow falls back to exact-but-slow host replay).
+        # size each launch's frontier to its bucket: four slots a query
+        # slot of the `_BUCKETS` ladder, `frontier_cap` at most (a step
+        # costs what its frontier holds, padding included, so a 16-query
+        # launch must not pay a 16k-task frontier). False pins every
+        # launch at `frontier_cap` — for operators who sized it
+        # explicitly to keep wide-fanout queries on-device (overflow
+        # falls back to exact-but-slow host replay).
         self.auto_frontier = auto_frontier
         self._allowed_buckets = [b for b in _BUCKETS if b <= self.frontier_cap]
         self.rewrite_instr_cap = rewrite_instr_cap
@@ -2578,17 +2583,20 @@ class TPUCheckEngine:
             )
             return self._launched("closure", outputs, meta, asm, dsp)
 
-        # per-launch frontier sizing: every BFS step's cost scales with the
-        # frontier length, not the query count, so a small bucket must not
-        # pay the full-size frontier (a 16-query launch at F=16384 costs
-        # the same ~130 ms as a 4096-query one). Small buckets get a
-        # proportional frontier; queries whose exploration outgrows it are
-        # flagged needs_host and replayed exactly — a safe (slower) path.
+        # per-launch frontier sizing: every gather, scatter and scan of a
+        # BFS step is dense over the frontier length F, padding included,
+        # so a launch costs what its F costs whatever is live in it. On
+        # the chip the same 2,048 items read 103.5 ms of device time at
+        # F=16384 and 50.2 ms at F=8192 over 20 steps, 14.4 and 7.1 ms
+        # over 2 (PERF.md section 6, PR 34). So F follows the bucket, and
+        # the bucket the batch (_BUCKETS); queries whose exploration
+        # outgrows it are flagged needs_host and replayed exactly — a
+        # safe (slower) path.
         if self.auto_frontier:
-            # 4x headroom over the seed tasks; measured on the serve path
-            # (1-core CPU host): B=16 at F=64 is 0.2 ms/launch vs 1.6 ms
-            # at the old 1024 floor — small-batch serve latency is the
-            # launch cost, so the floor must scale with the bucket
+            # 4x headroom over the seed tasks, at every rung: a 16-item
+            # launch at F=64 reads 1.6 ms of device time in 7.2 ms of
+            # wall on the chip (chip_smoke.py, PR 34) — small-batch serve
+            # latency is the launch's fixed cost, not its frontier
             launch_cap = min(self.frontier_cap, max(4 * B, 64))
         else:
             launch_cap = self.frontier_cap

@@ -703,7 +703,11 @@ class Metrics:
             "launch (capacity is the launch frontier_cap; peaks at cap "
             "mean frontier-overflow host replays are near)",
             registry=self.registry,
-            buckets=(16, 64, 256, 1024, 4096, 16384, 65536),
+            # the launch ladder's edges (engine/tpu_engine.py _BUCKETS)
+            # and the two frontier rungs above it: a launch's cap is four
+            # times its bucket, so a peak's bucket edge says how far from
+            # the cap it sat
+            buckets=tuple(1 << k for k in range(4, 17)),
         )
         self.launch_gather_bytes = prom.Histogram(
             "keto_tpu_launch_gather_bytes",

@@ -165,7 +165,7 @@ def smoke_batch(drive):
 
 def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     """The smoke's largest check launch, its 2,048-item BatchCheck (bucket
-    4,096, frontier 16,384), with tables and working set inside one v5e's
+    2,048, frontier 8,192), with tables and working set inside one v5e's
     16 GiB. dh_pack and rh_pack are stored as the 64-lane bucket rows the
     kernel gathers, so no launch relays them out: `temp` holds the frontier's
     working set and does not follow the tables' rows (ROADMAP S3)."""
@@ -175,7 +175,7 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     tables, qpack = tables_and_queries
     assert tables["dh_pack"].shape == (1 << 20, 64)
     assert tables["rh_pack"].shape == (1 << 19, 64)
-    assert qpack.shape == (7, 4096) and statics["frontier_cap"] == 16384
+    assert qpack.shape == (7, 2048) and statics["frontier_cap"] == 8192
     _, memory = compile_for_chip(
         "check_kernel_packed", kernel.check_kernel_packed, tables_and_queries,
         statics, lambda a: one_chip,
