@@ -13,6 +13,11 @@ has had an answer; then `{"event": "window_start"}` is printed, the window
 runs for `--seconds`, and one JSON line reports every RPC that was answered
 inside it. Latency is from send to answer at the caller; an RPC that fails
 counts as the timeout.
+
+Beside the window's totals the line says where in the window they fell: the
+window cut in halves and in quarters by the time of the answer, and the
+longest time in which no answer came. They are for reading a run's spread
+(run.py prints them on its `window` phase line); no metric reads them.
 """
 
 from __future__ import annotations
@@ -57,6 +62,33 @@ def caller(t, callers, workload, port, timeout, stop, done, records):
                 stop.wait(think_s)
     finally:
         client.close()
+
+
+def window_parts(answered_s, window_s: float, latency, good, n: int) -> list[dict]:
+    """The window cut into `n` parts of equal length by the time of each
+    RPC's answer, `answered_s` seconds into it; `latency` is the RPC's
+    seconds and `good` its checks answered right. Every RPC falls into one
+    part, so the parts' counts add up to the window's."""
+    latency, good = np.asarray(latency), np.asarray(good)
+    part = np.minimum((np.asarray(answered_s) * n / window_s).astype(int), n - 1)
+    parts = []
+    for k in range(n):
+        here = part == k
+        parts.append({
+            "rpcs": int(here.sum()),
+            "good_checks": int(good[here].sum()),
+            "p50_ms": 1e3 * float(np.median(latency[here])) if here.any() else None,
+        })
+    return parts
+
+
+def longest_gap(answered_s, window_s: float) -> tuple[float, float]:
+    """(seconds, start) of the longest time without an answer, the window's
+    two ends counted as answers: a stall shows here, wherever it falls."""
+    edges = np.concatenate(([0.0], np.sort(answered_s), [window_s]))
+    gaps = np.diff(edges)
+    k = int(np.argmax(gaps))
+    return float(gaps[k]), float(edges[k])
 
 
 def main(argv=None) -> int:
@@ -108,6 +140,9 @@ def main(argv=None) -> int:
     latency = [timeout if r[3] else r[2] - r[1] for r in window]
     errors = [r[3] for r in window if r[3]]
     wrong_rpcs = sum(1 for r in window if not r[3] and r[5])
+    answered_s = [r[2] - t0 for r in window]
+    good = [0 if r[3] else workload.items - r[5] for r in window]
+    gap_s, gap_at_s = longest_gap(answered_s, t1 - t0)
     np.savez(
         args.answers,
         rpc=np.array([r[0] for r in window], np.int64),
@@ -122,8 +157,12 @@ def main(argv=None) -> int:
         "first_error": errors[0] if errors else None,
         "wrong_rpcs": wrong_rpcs,
         "wrong_checks": sum(r[5] for r in window if not r[3]),
-        "good_checks": sum(workload.items - r[5] for r in window if not r[3]),
+        "good_checks": sum(good),
         "latency_s": latency,
+        "halves": window_parts(answered_s, t1 - t0, latency, good, 2),
+        "quarters": window_parts(answered_s, t1 - t0, latency, good, 4),
+        "longest_gap_s": gap_s,
+        "longest_gap_at_s": gap_at_s,
         "callers_stuck": stuck,
     }), flush=True)
     return 0

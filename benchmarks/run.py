@@ -32,6 +32,7 @@ marked by the device it reports, and takes no trace.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import os
 import shutil
@@ -207,6 +208,33 @@ def device_times(trace: dict) -> dict:
             f"device.window_s {window_s!r}"
         )
     return {"busy_s": busy_s, "window_s": window_s}
+
+
+class Collections:
+    """The collector's full collections in this process since this object
+    was made, and the seconds they held the interpreter, counted through
+    `gc.callbacks`. It observes: it neither starts a collection nor changes
+    a threshold, which are the program's to choose. A full collection of
+    the serving process stops every handler at once (PERF.md section 5), so
+    a run's count belongs beside its rate."""
+
+    def __init__(self):
+        self.count, self.seconds, self._began = 0, 0.0, None
+        gc.callbacks.append(self._on_collection)
+
+    def _on_collection(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._began = time.perf_counter()
+        elif self._began is not None:
+            self.count += 1
+            self.seconds += time.perf_counter() - self._began
+            self._began = None
+
+    def close(self) -> dict:
+        gc.callbacks.remove(self._on_collection)
+        return {"full_collections": self.count, "full_collection_s": self.seconds}
 
 
 def run_child(args, config_path, traffic_path, port, answers, on_window):
@@ -391,13 +419,18 @@ def main(argv=None) -> int:
             marks["setup_s"] = time.perf_counter() - t_start
             marks["programs"] = len(compiles)
             marks["before"] = Scrape.of(daemon.metrics_port)
+            marks["collections"] = Collections()
             if tracer is not None:
                 tracer.begin()
 
         answers = os.path.join(OUT, "answers.npz")
         child = run_child(args, config_path, traffic_path, daemon.read_port,
                           answers, on_window)
+        collected = marks["collections"].close()
         after = Scrape.of(daemon.metrics_port)
+        emit("window", **{k: child[k] for k in (
+            "window_s", "attempted", "good_checks", "halves", "quarters",
+            "longest_gap_s", "longest_gap_at_s")})
         compiles_in_window = len(compiles) - marks["programs"]
         trace = tracer.reduce() if tracer else None
 
@@ -416,7 +449,8 @@ def main(argv=None) -> int:
             check_batch_failed_total=failed_batches, breaker_state=breaker,
             host_fallback_total=after.by_label("keto_tpu_host_fallback_total", "cause"),
             compiles_in_window=compiles_in_window,
-            window_s=child["window_s"],
+            window_s=child["window_s"], **collected,
+            cpu_count=os.cpu_count(), cpus_allowed=sorted(os.sched_getaffinity(0)),
         )
         device["memory_peak_bytes"] = max(
             int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))

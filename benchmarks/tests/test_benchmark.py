@@ -19,6 +19,7 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH]
 
+import loadgen  # noqa: E402
 import run  # noqa: E402
 import trace_reduce  # noqa: E402
 from scrape import Scrape  # noqa: E402
@@ -26,9 +27,9 @@ from scrape import Scrape  # noqa: E402
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
-def rehearse(root: str, cell: str, trace: int, program=None) -> tuple[dict, str]:
-    """(the result line, the standard error) of a rehearsal of run.py, or
-    of `program`, a script that ends in run.main()."""
+def rehearse(root: str, cell: str, trace: int, program=None) -> tuple[dict, str, dict]:
+    """(the result line, the standard error, the phase lines by phase) of a
+    rehearsal of run.py, or of `program`, a script that ends in run.main()."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
     command = ["-c", program] if program else [os.path.join(root, "benchmarks", "run.py")]
     done = subprocess.run(
@@ -38,19 +39,47 @@ def rehearse(root: str, cell: str, trace: int, program=None) -> tuple[dict, str]
         capture_output=True, text=True, env=env, timeout=300,
     )
     assert done.returncode == 0, done.stderr[-2000:]
-    return json.loads(done.stdout.strip().splitlines()[-1]), done.stderr
+    lines = [json.loads(line) for line in done.stdout.strip().splitlines()]
+    phases = {line["phase"]: line for line in lines if "phase" in line}
+    return lines[-1], done.stderr, phases
+
+
+def read_bench() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
 
 
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return read_bench()
+
+
+@pytest.mark.parametrize("cell", read_bench()["workloads"], ids=lambda c: c["name"])
+def test_a_cells_files_are_there(cell, bench):
+    config = run.named(bench["configs"], cell["config"], "configuration")
+    assert os.path.isfile(os.path.join(ROOT, config["file"]))
+    assert os.path.isfile(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
+
+
+@pytest.mark.parametrize("metric", read_bench()["per_layer"], ids=lambda m: m["name"])
+def test_a_layer_metric_names_cells_and_an_end_to_end_metric(metric, bench):
+    cells = {cell["name"] for cell in bench["workloads"]}
+    assert set(metric.get("workloads", cells)) <= cells
+    assert metric["moves"] in {m["name"] for m in bench["end_to_end"]}
+    assert os.path.isfile(os.path.join(BENCH, "metrics", metric["name"] + ".json"))
+
+
+@pytest.mark.parametrize("metric", read_bench()["end_to_end"], ids=lambda m: m["name"])
+def test_an_end_to_end_bound_lies_within_the_contracts(metric):
+    assert 0 < metric["bound"] <= 0.25
+    assert os.path.isfile(os.path.join(BENCH, "metrics", metric["name"] + ".json"))
 
 
 @pytest.fixture(scope="module", params=[0, 1], ids=["end_to_end", "per_layer"])
 def rehearsal(request, bench):
     cell = bench["workloads"][0]["name"]
-    return request.param, rehearse(ROOT, cell, request.param)[0]
+    line, _, phases = rehearse(ROOT, cell, request.param)
+    return request.param, line, phases
 
 
 def test_rehearsal_prints_the_contracts_line(rehearsal, bench):
@@ -58,7 +87,7 @@ def test_rehearsal_prints_the_contracts_line(rehearsal, bench):
     no device metric from a CPU run; (e) a traced run fails if a metric file
     reads a Prometheus name the daemon does not expose, so this passing run
     has found every one."""
-    trace, line = rehearsal
+    trace, line, _ = rehearsal
     assert list(line) == RESULT_KEYS  # what was compared comes last
     assert line["correct"] is True and line["failed"] == 0 < line["attempted"]
     assert all(number <= limit for number, limit in line["compared"].values())
@@ -74,6 +103,63 @@ def test_rehearsal_prints_the_contracts_line(rehearsal, bench):
     else:
         assert set(line["metrics"]) == {m["name"] for m in bench["end_to_end"]}
     assert all(m["value"] > 0 or trace for m in line["metrics"].values())
+
+
+def test_the_rehearsals_phase_lines_say_where_the_spread_comes_from(rehearsal):
+    """The window's halves and quarters add up to the window, the longest
+    time without an answer lies inside it, and the `judged` line carries the
+    collector's full collections and the cores the serving process may use."""
+    _, line, phases = rehearsal
+    window, judged = phases["window"], phases["judged"]
+    assert window["attempted"] == line["attempted"]
+    for parts in (window["halves"], window["quarters"]):
+        assert sum(part["rpcs"] for part in parts) == window["attempted"]
+        assert sum(part["good_checks"] for part in parts) == window["good_checks"]
+        assert all(part["p50_ms"] > 0 for part in parts)
+    assert 0 < window["longest_gap_s"] < window["window_s"]
+    assert 0 <= window["longest_gap_at_s"] < window["window_s"]
+    assert judged["full_collections"] >= 0 <= judged["full_collection_s"]
+    assert (judged["full_collections"] == 0) == (judged["full_collection_s"] == 0)
+    assert judged["cpu_count"] >= len(judged["cpus_allowed"]) > 0
+
+
+def test_a_windows_parts_add_up_and_a_planted_stall_is_its_longest_gap():
+    """Ten answers a second for 8 s, none after 3.0 s and before 5.5 s."""
+    answered_s = [t / 10 for t in range(1, 80) if not 30 < t < 55]
+    latency = [0.1 + (0.2 if t > 5.4 else 0.0) for t in answered_s]
+    good = [2048 if k % 9 else 2047 for k in range(len(answered_s))]
+    halves = loadgen.window_parts(answered_s, 8.0, latency, good, 2)
+    quarters = loadgen.window_parts(answered_s, 8.0, latency, good, 4)
+    for parts in (halves, quarters):
+        assert sum(part["rpcs"] for part in parts) == len(answered_s)
+        assert sum(part["good_checks"] for part in parts) == sum(good)
+    assert [part["rpcs"] for part in quarters] == [19, 11, 5, 20]
+    assert [part["rpcs"] for part in halves] == [30, 25]
+    assert halves[0]["p50_ms"] == pytest.approx(100.0)
+    assert halves[1]["p50_ms"] == pytest.approx(300.0)
+    gap_s, at_s = loadgen.longest_gap(answered_s, 8.0)
+    assert (gap_s, at_s) == (pytest.approx(2.5), pytest.approx(3.0))
+    # a stall at either end of the window counts, and so does an empty window
+    assert loadgen.longest_gap([3.0, 3.5], 8.0) == (4.5, 3.5)
+    assert loadgen.longest_gap([], 8.0) == (8.0, 0.0)
+    assert loadgen.window_parts([], 8.0, [], [], 2) == [
+        {"rpcs": 0, "good_checks": 0, "p50_ms": None}] * 2
+
+
+def test_the_collectors_full_collections_are_counted_and_timed():
+    import gc
+
+    collections = run.Collections()
+    try:
+        gc.collect(0)
+        assert (collections.count, collections.seconds) == (0, 0.0)
+        gc.collect()
+        gc.collect(2)
+    finally:
+        seen = collections.close()
+    assert seen["full_collections"] == 2 and seen["full_collection_s"] > 0
+    gc.collect()
+    assert collections.count == 2  # closed: it counts no more
 
 
 def test_a_new_cell_is_files_and_an_entry(tmp_path, bench):
@@ -97,7 +183,7 @@ def test_a_new_cell_is_files_and_an_entry(tmp_path, bench):
     bench["per_layer"].append({"name": "queue_b_ms", "unit": "ms",
                                "workloads": ["other.few"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    line, _ = rehearse(str(tmp_path), "other.few", 1)
+    line, _, _ = rehearse(str(tmp_path), "other.few", 1)
     assert line["correct"] is True
     assert line["metrics"]["queue_b_ms"]["value"] > 0
 
@@ -235,7 +321,7 @@ def test_an_answer_altered_where_it_is_produced_makes_the_run_incorrect(bench):
     last of every batch's 2,048 answers, and the run says so, in `correct`,
     in `failed`, in what it compared and on its last lines of stderr."""
     cell = bench["workloads"][0]["name"]
-    line, stderr = rehearse(ROOT, cell, 0, program=ONE_ANSWER_ALTERED)
+    line, stderr, _ = rehearse(ROOT, cell, 0, program=ONE_ANSWER_ALTERED)
     assert line["correct"] is False
     assert line["failed"] == line["attempted"] > 0
     wrong, limit = line["compared"]["wrong_checks"]
