@@ -374,6 +374,58 @@ def bucket_row_layout(shape, dtype):
     return Layout(major_to_minor=tuple(range(len(shape))))
 
 
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def tiled_nbytes(shape, dtype) -> int:
+    """Bytes a table of this shape takes on the chip, by arithmetic on
+    its shape, dtype and layout alone, so the CPU gives the chip's answer.
+    HBM is tiled: the minor dimension in multiples of 128 lanes and the
+    next in sublanes of 8 (of 1, 2 or 4 under a table of so few columns);
+    a long vector in tiles of 1,024. Bucket rows lie row-major
+    (bucket_row_layout), so a 64-lane int32 row is padded to 128 lanes:
+    512 B for its 256. A narrower two-dimensional table the client lays
+    column-major, rows along the lanes, and only their count is padded."""
+    item = np.dtype(dtype).itemsize
+    if len(shape) == 0:
+        return item
+    if len(shape) == 1:
+        return item * _round_up(shape[0], 1024 if shape[0] >= 1024 else 128)
+    if bucket_row_layout(shape, dtype) is not None:
+        *major, sub, lanes = shape
+        sub = _round_up(sub, 8)
+    elif len(shape) == 2:
+        major, (lanes, sub) = (), shape
+        sub = _round_up(sub, 8) if sub > 4 else 1 << (sub - 1).bit_length()
+    else:
+        return item * int(np.prod(shape))  # no table has this shape
+    return item * int(np.prod(major, dtype=np.int64)) * sub * _round_up(lanes, 128)
+
+
+def table_nbytes(table) -> int:
+    """tiled_nbytes of a device table, every device's shard or copy of it
+    counted: what the devices hold of it."""
+    sharding = table.sharding
+    shard = sharding.shard_shape(table.shape)
+    return len(sharding.device_set) * tiled_nbytes(shard, table.dtype)
+
+
+UPLOAD_ROWS = 1 << 15  # bucket rows a step of device_table: 8 MB of int32
+
+
+@functools.lru_cache(maxsize=None)
+def _row_writer(fmt: Format):
+    """The jitted step of device_table for tables lying as `fmt`: `rows`
+    written into `table` from row `start` on. The table is donated and
+    comes back in the layout it came in, so the step writes in place."""
+
+    def write_rows(table, rows, start):
+        return jax.lax.dynamic_update_slice_in_dim(table, rows, start, 0)
+
+    return jax.jit(write_rows, donate_argnums=0, out_shardings=fmt)
+
+
 def device_table(host, sharding=None) -> jax.Array:
     """One host table onto the device (the default one, or as `sharding`
     says), lying the way the kernels read it (bucket_row_layout). Left to
@@ -381,12 +433,36 @@ def device_table(host, sharding=None) -> jax.Array:
     lane padding that way), and every program that gathers bucket rows
     from it first copies the whole table back to row-major, once a
     launch. A committed array carries its layout into every jit that
-    takes it, so nothing is said at the kernels."""
-    a = jnp.asarray(host) if sharding is None else jax.device_put(host, sharding)
-    layout = bucket_row_layout(a.shape, a.dtype)
-    if layout is not None:
-        a = jax.device_put(a, Format(layout, a.sharding))
-    return a
+    takes it, so nothing is said at the kernels.
+
+    The device holds the table once. A transfer lays an array the
+    client's way whatever is asked of it, so a table of more than
+    UPLOAD_ROWS rows is not sent whole and then copied (both copies live
+    until the first is dropped: 15.2 GB of a 16.9 GB chip for 13.0 GB
+    of tables at 1.25e7 tuples): it is made empty where and as it will
+    lie and filled UPLOAD_ROWS rows a step, each step ended before the
+    next begins, so that beside the table the device holds one step's
+    rows. A table sharded over a mesh is a shard's size a device and is
+    placed as before."""
+    if sharding is not None:
+        a = jax.device_put(host, sharding)
+        layout = bucket_row_layout(a.shape, a.dtype)
+        return a if layout is None else jax.device_put(a, Format(layout, a.sharding))
+    layout = bucket_row_layout(np.shape(host), host.dtype)
+    if layout is None:
+        return jnp.asarray(host)
+    # the default device's sharding, as jnp.asarray would choose it
+    fmt = Format(layout, jnp.zeros((), host.dtype).sharding)
+    if len(host) <= UPLOAD_ROWS:
+        return jax.device_put(host, fmt)
+    write_rows = _row_writer(fmt)
+    table = jax.jit(
+        functools.partial(jnp.zeros, host.shape, host.dtype), out_shardings=fmt
+    )()
+    for start in range(0, len(host), UPLOAD_ROWS):
+        table = write_rows(table, host[start : start + UPLOAD_ROWS], start)
+        table.block_until_ready()
+    return table
 
 
 def device_tables(host: dict, sharding=None) -> dict:
@@ -1229,12 +1305,18 @@ def pack_raw_tables(raw: dict) -> dict:
     return out
 
 
-def snapshot_tables(snapshot: GraphSnapshot, delta: dict | None = None) -> dict:
-    """Device-resident table dict for check_kernel (uploads once); the
-    delta-overlay tables default to empty (fixed shapes either way)."""
+def pack_snapshot_tables(snapshot: GraphSnapshot, delta: dict | None = None) -> dict:
+    """The host side of snapshot_tables: a snapshot's columns packed into
+    the table rows the device holds; the delta-overlay tables default to
+    empty (fixed shapes either way)."""
     raw = dict(snapshot.device_arrays())
     raw.update(delta or empty_delta_tables())
-    return device_tables(pack_raw_tables(raw))
+    return pack_raw_tables(raw)
+
+
+def snapshot_tables(snapshot: GraphSnapshot, delta: dict | None = None) -> dict:
+    """Device-resident table dict for check_kernel (uploads once)."""
+    return device_tables(pack_snapshot_tables(snapshot, delta))
 
 
 def refresh_delta_tables(tables: dict, delta: dict, vocab_arrays: dict) -> dict:

@@ -54,10 +54,13 @@ from .kernel import (
     CAUSE_NAMES,
     _KERNEL_STATICS,
     check_kernel,
+    device_tables,
     estimate_step_gather_bytes,
     kernel_static_config,
     launch_stats_dict,
+    pack_snapshot_tables,
     snapshot_tables,
+    table_nbytes,
 )
 from .reference import ReferenceEngine
 from .snapshot import (
@@ -76,14 +79,43 @@ _BUCKETS = tuple(1 << k for k in range(4, 15))
 _paginate = paginate_names
 
 
-def _tables_nbytes(tables) -> int:
-    """Device bytes held by a table dict (or the mesh path's
-    (sharded, replicated) tuple of dicts) — the snapshot_hbm_bytes gauge."""
+def _tables_nbytes_by_key(tables) -> dict:
+    """Device bytes of each table of a table dict (or of the mesh path's
+    (sharded, replicated) tuple of dicts, a key's tables summed), as the
+    device holds them (kernel.table_nbytes): a 64-lane int32 bucket row is
+    512 B on the chip, twice its `nbytes`."""
+    if tables is None:
+        return {}
     if isinstance(tables, tuple):
-        return sum(_tables_nbytes(t) for t in tables)
-    if isinstance(tables, dict):
-        return sum(int(getattr(v, "nbytes", 0) or 0) for v in tables.values())
-    return int(getattr(tables, "nbytes", 0) or 0)
+        merged: dict = {}
+        for part in tables:
+            for k, v in _tables_nbytes_by_key(part).items():
+                merged[k] = merged.get(k, 0) + v
+        return merged
+    return {k: table_nbytes(v) for k, v in tables.items()}
+
+
+def _tables_devices(tables) -> list:
+    """The devices that hold a table dict (or the mesh path's tuple of
+    dicts), in id order."""
+    parts = tables if isinstance(tables, tuple) else (tables,)
+    devices = {d for part in parts for v in part.values() for d in v.devices()}
+    return sorted(devices, key=lambda d: d.id)
+
+
+def _tables_nbytes(tables) -> int:
+    """The snapshot_hbm_bytes gauge: _tables_nbytes_by_key, summed."""
+    return sum(_tables_nbytes_by_key(tables).values())
+
+
+@contextlib.contextmanager
+def _mirror_phase(phases: dict, name: str):
+    """One phase of a mirror rebuild as a StageSpan `mirror.<name>`
+    (`keto.mirror.<name>` in a profiler trace's host plane); its seconds
+    go into `phases` when the phase ends without raising."""
+    with StageSpan(f"mirror.{name}") as span:
+        yield
+    phases[name] = span.seconds
 
 
 @dataclass
@@ -654,6 +686,7 @@ class TPUCheckEngine:
         m.delta_overlay_ops.set(0)
         m.compaction_lag_versions.set(0)
         m.snapshot_hbm_bytes.set(_tables_nbytes(tables))
+        m.watch_device_memory(_tables_devices(tables))
 
     @staticmethod
     def _pack_expand_csr(csr: dict) -> dict:
@@ -881,79 +914,56 @@ class TPUCheckEngine:
                     self.metrics.checkpoint_load_fallbacks_total.labels(
                         reason
                     ).inc()
-        build_start = time.perf_counter()
         # columnar fast path: stores exposing all_tuple_columns feed the
         # vectorized builder directly — no per-tuple Python objects on
-        # the ingest path (the 1e7..1e8-scale requirement)
+        # the ingest path (the 1e7..1e8-scale requirement), single-device
+        # AND mesh (the round-2 VERDICT's one structural gap)
         columns_fn = getattr(self.manager, "all_tuple_columns", None)
-        if columns_fn is not None:
-            # vectorized ingest: no per-tuple Python objects on the build
-            # path (the 1e7..1e8-scale requirement), single-device AND
-            # mesh (the round-2 VERDICT's one structural gap)
+        columnar = columns_fn is not None
+        phases: dict = {}
+        sharded = None
+        with _mirror_phase(phases, "build"):
+            if columnar:
+                source = columns_fn(nid=self.nid)
+            else:
+                # ketolint: allow[lock-blocking-call] reason=the O(edges) mirror rebuild must read the store under the engine lock: the built state is stamped covered_version=store_version, and a write landing mid-read would silently decouple the two; the store never calls back into the engine while holding its own lock (write hooks fire post-commit, outside store locks), so the engine->store lock order cannot invert
+                source = self.manager.all_relation_tuples(nid=self.nid)
             if self.mesh is not None:
-                from ..parallel.kernel import place_sharded_tables
+                from ..parallel import build_sharded_snapshot
                 from ..parallel.sharding import build_sharded_snapshot_columnar
 
-                sharded = build_sharded_snapshot_columnar(
-                    columns_fn(nid=self.nid), namespaces,
-                    n_shards=self.mesh.devices.size,
+                build = (
+                    build_sharded_snapshot_columnar if columnar
+                    else build_sharded_snapshot
+                )
+                sharded = build(
+                    source, namespaces, n_shards=self.mesh.devices.size,
                     K=self.rewrite_instr_cap, version=version,
                 )
                 snap = sharded.base
+            else:
+                build = build_snapshot_columnar if columnar else build_snapshot
+                snap = build(
+                    source, namespaces, K=self.rewrite_instr_cap,
+                    version=version,
+                )
+        if self.mesh is not None:
+            from ..parallel.kernel import place_sharded_tables
+
+            # a shard's tables are packed and placed one at a time (at
+            # 1e8 edges the packed copies held together would not fit the
+            # host), so on a mesh packing counts under `upload`
+            phases["pack"] = 0.0
+            with _mirror_phase(phases, "upload"):
                 tables = place_sharded_tables(
                     sharded, self.mesh, axis=self.mesh.axis_names[0],
                     release_columns=True,
                 )
-            else:
-                sharded = None
-                snap = build_snapshot_columnar(
-                    columns_fn(nid=self.nid), namespaces,
-                    K=self.rewrite_instr_cap, version=version,
-                )
-                tables = snapshot_tables(snap)
-            state = _EngineState(
-                snapshot=snap,
-                view=SnapshotView(snap),
-                sharded=sharded,
-                tables=tables,
-                delta_np=empty_delta_tables(),
-                base_version=store_version,
-                covered_version=store_version,
-                config_fp=config_fp,
-            )
-            self.stats["snapshot_builds"] += 1
-            if self.metrics is not None:
-                self.metrics.snapshot_builds_total.inc()
-                self.metrics.snapshot_tuples.set(snap.n_tuples)
-                self.metrics.snapshot_build_duration.observe(
-                    time.perf_counter() - build_start
-                )
-                self._set_mirror_gauges(tables)
-            return state, (snap if self.mesh is None else None)
-        # ketolint: allow[lock-blocking-call] reason=the O(edges) mirror rebuild must read the store under the engine lock: the built state is stamped covered_version=store_version, and a write landing mid-read would silently decouple the two; the store never calls back into the engine while holding its own lock (write hooks fire post-commit, outside store locks), so the engine->store lock order cannot invert
-        tuples = self.manager.all_relation_tuples(nid=self.nid)
-        sharded = None
-        if self.mesh is not None:
-            from ..parallel import build_sharded_snapshot
-            from ..parallel.kernel import place_sharded_tables
-
-            sharded = build_sharded_snapshot(
-                tuples,
-                namespaces,
-                n_shards=self.mesh.devices.size,
-                K=self.rewrite_instr_cap,
-                version=version,
-            )
-            snap = sharded.base
-            tables = place_sharded_tables(
-                sharded, self.mesh, axis=self.mesh.axis_names[0],
-                release_columns=True,
-            )
         else:
-            snap = build_snapshot(
-                tuples, namespaces, K=self.rewrite_instr_cap, version=version
-            )
-            tables = snapshot_tables(snap)
+            with _mirror_phase(phases, "pack"):
+                packed = pack_snapshot_tables(snap)
+            with _mirror_phase(phases, "upload"):
+                tables = device_tables(packed)
         state = _EngineState(
             snapshot=snap,
             view=SnapshotView(snap),
@@ -968,9 +978,7 @@ class TPUCheckEngine:
         if self.metrics is not None:
             self.metrics.snapshot_builds_total.inc()
             self.metrics.snapshot_tuples.set(snap.n_tuples)
-            self.metrics.snapshot_build_duration.observe(
-                time.perf_counter() - build_start
-            )
+            self.metrics.observe_mirror_build(phases)
             self._set_mirror_gauges(tables)
         # mirror checkpoints cover the single-device path only (the
         # sharded build re-derives per-shard tables anyway)
@@ -1030,7 +1038,8 @@ class TPUCheckEngine:
         mirror is relative to the live store. Served by
         `GET /admin/flightrec` and read by the bench; also refreshes the
         keto_tpu_hbm_table_bytes{buffer} gauges. Zero device contact —
-        nbytes is array metadata."""
+        the bytes are arithmetic on array metadata, as the chip tiles
+        the tables (kernel.tiled_nbytes)."""
         with self._lock:
             state = self._state
         if state is None:
@@ -1038,21 +1047,7 @@ class TPUCheckEngine:
         # store read OUTSIDE the engine lock (ketolint lock-discipline)
         store_version = self.manager.version(nid=self.nid)
 
-        def per_key(tables) -> dict:
-            if tables is None:
-                return {}
-            if isinstance(tables, tuple):
-                merged: dict = {}
-                for part in tables:
-                    for k, v in per_key(part).items():
-                        merged[k] = merged.get(k, 0) + v
-                return merged
-            return {
-                k: int(getattr(v, "nbytes", 0) or 0)
-                for k, v in tables.items()
-            }
-
-        check_keys = per_key(state.tables)
+        check_keys = _tables_nbytes_by_key(state.tables)
         delta_bytes = sum(
             v for k, v in check_keys.items()
             if k in ("dd_pack", "dirty_pack", "rd_pack")
@@ -1064,7 +1059,7 @@ class TPUCheckEngine:
         # closure CSR + its delta overlay broken out as their own buffer
         # families (the Leopard index lives in HBM beside the check
         # tables; capacity planning must see it separately)
-        closure_keys = per_key(self.closure_device_tables())
+        closure_keys = _tables_nbytes_by_key(self.closure_device_tables())
         # device-powering working set (engine/closure_power.py): packed
         # adjacency operands + bit matrices + unpacked step scratch of
         # the LAST device build — transient buffers, reported at their
@@ -1079,9 +1074,9 @@ class TPUCheckEngine:
                 }
         buffers = {
             "check": check_keys,
-            "expand": per_key(state.expand_tables),
-            "reverse": per_key(state.reverse_tables),
-            "subjects": per_key(state.subjects_tables),
+            "expand": _tables_nbytes_by_key(state.expand_tables),
+            "reverse": _tables_nbytes_by_key(state.reverse_tables),
+            "subjects": _tables_nbytes_by_key(state.subjects_tables),
             "closure": {
                 k: v for k, v in closure_keys.items() if k != "cd_pack"
             },

@@ -76,6 +76,11 @@ DEVICE_FEED_STATES = (
 )
 
 
+# the phases of a mirror rebuild, in order
+# (keto_tpu_mirror_build_seconds{phase}; `keto.mirror.<phase>` spans)
+MIRROR_BUILD_PHASES = ("build", "pack", "upload")
+
+
 class StageSpan:
     """One stage of the served check path, on two clocks at once: a
     `jax.profiler.TraceAnnotation` named `keto.<stage>` (the profiler's
@@ -511,6 +516,33 @@ class Metrics:
             "Device bytes held by the current check-table mirror "
             "(packed edge/rewrite/delta tables; expand/reverse extras "
             "not included)",
+            registry=self.registry,
+        )
+        self.device_bytes_in_use = prom.Gauge(
+            "keto_tpu_device_bytes_in_use",
+            "Bytes allocated on the mirror's device(s) now, by the "
+            "device's own memory_stats(): tables, launch buffers and "
+            "whatever else the process holds there. Read at every scrape "
+            "once a mirror is built; 0 before that, and on a backend that "
+            "keeps no such statistic (the CPU)",
+            registry=self.registry,
+        )
+        self.device_bytes_limit = prom.Gauge(
+            "keto_tpu_device_bytes_limit",
+            "Bytes the mirror's device(s) can allocate at all "
+            "(memory_stats() bytes_limit): the room "
+            "keto_tpu_device_bytes_in_use is a share of; 0 where the "
+            "backend does not say",
+            registry=self.registry,
+        )
+        self.mirror_build_seconds = prom.Gauge(
+            "keto_tpu_mirror_build_seconds",
+            "Seconds the last mirror rebuild spent in each phase: build "
+            "(store columns to the host snapshot), pack (columns to "
+            "packed table rows) and upload (host tables onto the "
+            "device); they add up to that rebuild's "
+            "keto_tpu_snapshot_build_duration_seconds sample",
+            ["phase"],
             registry=self.registry,
         )
         self.compaction_lag_versions = prom.Gauge(
@@ -1237,6 +1269,29 @@ class Metrics:
         from prometheus_client.openmetrics import exposition as om
 
         return om.generate_latest(self.registry)
+
+    def observe_mirror_build(self, phases: dict) -> None:
+        """One mirror rebuild, from the seconds of its phases (build,
+        pack, upload: the engine's `mirror.<phase>` StageSpans): each
+        phase's gauge, and their sum as the rebuild's duration sample, so
+        that the two are read off the same clock reads."""
+        for phase, seconds in phases.items():
+            self.mirror_build_seconds.labels(phase).set(seconds)
+        self.snapshot_build_duration.observe(sum(phases.values()))
+
+    def watch_device_memory(self, devices) -> None:
+        """Read the two device-memory gauges from `devices`' own
+        memory_stats(), summed, at every scrape from now on. A backend
+        that keeps no such statistic (the CPU's memory_stats() is None)
+        or lacks a key reads as 0: nothing is made up for it."""
+
+        def total(key: str) -> float:
+            return float(
+                sum((d.memory_stats() or {}).get(key, 0) for d in devices)
+            )
+
+        self.device_bytes_in_use.set_function(lambda: total("bytes_in_use"))
+        self.device_bytes_limit.set_function(lambda: total("bytes_limit"))
 
     def observe_launch(
         self,

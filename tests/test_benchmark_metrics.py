@@ -14,7 +14,12 @@ import sys
 
 import pytest
 
-from keto_tpu.observability import CHECK_STAGES, DEVICE_FEED_STATES, Metrics
+from keto_tpu.observability import (
+    CHECK_STAGES,
+    DEVICE_FEED_STATES,
+    MIRROR_BUILD_PHASES,
+    Metrics,
+)
 from keto_tpu.observability_workload import FOLD_WHERE
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -26,6 +31,7 @@ LABEL_VALUES = {
     ("keto_tpu_check_stage_duration_seconds", "stage"): set(CHECK_STAGES),
     ("keto_tpu_device_feed_seconds", "state"): set(DEVICE_FEED_STATES),
     ("keto_tpu_workload_fold_seconds", "where"): set(FOLD_WHERE),
+    ("keto_tpu_mirror_build_seconds", "phase"): set(MIRROR_BUILD_PHASES),
 }
 SAMPLE_SUFFIXES = ("_total", "_sum", "_count", "_bucket", "")
 

@@ -283,6 +283,87 @@ class TestStageMetrics:
         m = daemon.registry.metrics()
         assert m.snapshot_hbm_bytes._value.get() > 0
 
+    @pytest.fixture(scope="class")
+    def built(self):
+        """A registry of its own whose mirror was built exactly once."""
+        cfg = Config({"dsn": "memory", "check": {"engine": "tpu"}})
+        cfg.set_namespaces(NAMESPACES)
+        reg = Registry(cfg)
+        reg.relation_tuple_manager().write_relation_tuples(
+            [RelationTuple.from_string(TUPLE)]
+        )
+        return reg, reg.check_engine()._ensure_state()
+
+    def test_mirror_build_phases_add_up_to_the_builds_duration(self, built):
+        """Each phase of the rebuild is timed once, and the duration
+        sample is the sum of the same clock reads."""
+        from keto_tpu.observability import MIRROR_BUILD_PHASES
+
+        m = built[0].metrics()
+        phases = {
+            phase: m.mirror_build_seconds.labels(phase)._value.get()
+            for phase in MIRROR_BUILD_PHASES
+        }
+        assert all(seconds > 0 for seconds in phases.values()), phases
+        assert m.snapshot_builds_total._value.get() == 1
+        duration = m.snapshot_build_duration._sum.get()
+        assert abs(sum(phases.values()) - duration) <= 0.1 * duration
+        text = m.export().decode()
+        for phase in MIRROR_BUILD_PHASES:
+            assert f'keto_tpu_mirror_build_seconds{{phase="{phase}"}}' in text
+
+    def test_hbm_gauge_counts_what_the_device_holds(self, built):
+        """512 B a 64-lane int32 bucket row, not the 256 of `nbytes`:
+        the gauge and the flight recorder's `hbm` agree, by arithmetic
+        that is the chip's on every backend."""
+        from keto_tpu.engine.kernel import tiled_nbytes
+
+        reg, state = built
+        tables = state.tables
+        held = sum(tiled_nbytes(t.shape, t.dtype) for t in tables.values())
+        assert tables["dh_pack"].shape[1] == 64
+        assert tiled_nbytes(tables["dh_pack"].shape, "int32") == (
+            2 * tables["dh_pack"].nbytes
+        )
+        assert reg.metrics().snapshot_hbm_bytes._value.get() == held
+        assert held > sum(t.nbytes for t in tables.values())
+        hbm = reg.check_engine().hbm_snapshot()
+        assert sum(hbm["buffers"]["check"].values()) == held
+
+    @pytest.mark.parametrize(
+        "stats, in_use, limit",
+        [
+            ({"bytes_in_use": 3, "bytes_limit": 16, "peak_bytes_in_use": 9}, 6, 32),
+            ({"bytes_in_use": 3}, 6, 0),
+            (None, 0, 0),
+        ],
+        ids=["chip", "key_absent", "cpu"],
+    )
+    def test_device_memory_gauges_read_the_devices_at_a_scrape(
+        self, stats, in_use, limit
+    ):
+        """Summed over the mirror's devices; a backend without the
+        statistic, or without a key, reads as nothing."""
+        from types import SimpleNamespace
+
+        from keto_tpu.observability import Metrics
+
+        m = Metrics()
+        assert "keto_tpu_device_bytes_in_use 0.0" in m.export().decode()
+        reads = []
+
+        def memory_stats():
+            reads.append(1)
+            return stats
+
+        m.watch_device_memory([SimpleNamespace(memory_stats=memory_stats)] * 2)
+        text = m.export().decode()
+        assert f"keto_tpu_device_bytes_in_use {float(in_use)}" in text
+        assert f"keto_tpu_device_bytes_limit {float(limit)}" in text
+        seen = len(reads)
+        m.export()
+        assert len(reads) == 2 * seen  # read anew at every scrape
+
     def test_error_status_mirrored_into_request_counter(self, daemon):
         # bare check route mirrors deny as 403 — the outcome label must
         # say 403, not OK (the satellite fix: no error response counts

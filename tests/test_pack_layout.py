@@ -194,3 +194,85 @@ def test_only_bucket_rows_are_placed_row_major(shape, row_major):
     placed = kernel.device_table(np.ones(shape, np.int32))
     assert placed.shape == shape and bool(placed.committed) == row_major
     np.testing.assert_array_equal(np.asarray(placed), 1)
+
+
+def test_a_large_table_is_placed_once_and_filled_in_place(monkeypatch):
+    """More than UPLOAD_ROWS bucket rows: the device holds one array of the
+    table's size at every step of the upload and after it (sent whole, the
+    table lies the client's way first and is then copied: two of them
+    live, 15.2 GB of a 16.9 GB chip at 1.25e7 tuples), committed row-major
+    and equal to the host's. A last step of fewer rows is a step too."""
+    import jax
+
+    n = 3 * kernel.UPLOAD_ROWS + 8
+    host = np.arange(n * 64, dtype=np.int32).reshape(n, 64)
+    before = {id(a) for a in jax.live_arrays()}
+
+    def large_and_new():
+        return [
+            a.shape for a in jax.live_arrays()
+            if id(a) not in before and a.nbytes >= host.nbytes // 2
+        ]
+
+    steps = []
+    whole = kernel._row_writer
+
+    def watched(fmt):
+        write_rows = whole(fmt)
+
+        def step(table, rows, start):
+            steps.append((start, len(rows), large_and_new()))
+            return write_rows(table, rows, start)
+
+        return step
+
+    monkeypatch.setattr(kernel, "_row_writer", watched)
+    placed = kernel.device_table(host)
+    rows = kernel.UPLOAD_ROWS
+    assert [(start, count) for start, count, _ in steps] == [
+        (0, rows), (rows, rows), (2 * rows, rows), (3 * rows, 8),
+    ]
+    assert all(live == [host.shape] for _, _, live in steps)
+    assert large_and_new() == [host.shape]
+    assert placed.committed
+    assert placed.format.layout.major_to_minor == (0, 1)
+    np.testing.assert_array_equal(np.asarray(placed), host)
+
+
+DRIVE_1E6 = {  # the 1e6 drive store's tables (chip_smoke's, the 1e6 cells')
+    "objslot_ns": (988160,), "ns_has_config": (128,), "prog_flags": (10,),
+    "dh_pack": (1048576, 64), "rh_pack": (524288, 64), "e_pack": (975757, 2),
+    "instr_pack": (10, 8), "dd_pack": (1024, 64), "dirty_pack": (512, 64),
+    "rd_pack": (1024, 64),
+}
+DRIVE_4E6 = {  # drive-chip-share's
+    **DRIVE_1E6, "objslot_ns": (3988480,), "dh_pack": (4194304, 64),
+    "rh_pack": (2097152, 64), "e_pack": (3938717, 2),
+}
+
+
+@pytest.mark.parametrize(
+    "shapes, held",
+    [
+        ({"a bucket row": (1, 64)}, 8 * 512),  # a tile is 8 rows
+        ({"bucket rows": (1024, 64)}, 1024 * 512),
+        ({"shards of bucket rows": (4, 512, 64)}, 4 * 512 * 512),
+        ({"slot rows": (8192, 8)}, 8192 * 8 * 4),  # narrow: as they are,
+        ({"e_pack": (4099, 2)}, 4224 * 2 * 4),  # rows padded to 128 lanes
+        ({"instr_pack": (10, 8)}, 128 * 8 * 4),
+        ({"a long vector": (988160,)}, 988160 * 4),
+        ({"a short vector": (10,)}, 128 * 4),
+        (DRIVE_1E6, 818_381_824),  # memory_stats() read 818,884,608 there
+        (DRIVE_4E6, 3_270_005_760),  # 3,284,044,800 with its launch buffers
+        ({**DRIVE_4E6, "dh_pack": (16777216, 64), "rh_pack": (8388608, 64),
+          "objslot_ns": (12488704,), "e_pack": (12333837, 2)}, 13_034_844_160),
+    ],
+    ids=lambda case: "+".join(case) if isinstance(case, dict) else None,
+)
+def test_device_bytes_by_arithmetic(shapes, held):
+    """What the chip's tiling makes of a table's shape: every number but
+    the first three is what a v5e reported for that array or store
+    (`on_device_size_in_bytes`, `memory_stats()`; PR 35's runs)."""
+    assert sum(
+        kernel.tiled_nbytes(shape, np.int32) for shape in shapes.values()
+    ) == held

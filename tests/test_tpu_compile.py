@@ -186,6 +186,71 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     assert_no_table_relayout(memory)
 
 
+# drive-chip-share's store: the 4,000,000-tuple drive store's tables where
+# they differ from the 1e6 store's, and the probe depths a builder's run
+# read there (PR 35; a seed may draw a probe more or less)
+CHIP_SHARE_SHAPES = {
+    "dh_pack": (4194304, 64), "rh_pack": (2097152, 64),
+    "objslot_ns": (3988480,), "e_pack": (3938717, 2),
+}
+CHIP_SHARE_PROBES = {"dh_probes": 12, "rh_probes": 13}
+
+
+def test_check_kernel_fits_the_chip_at_the_chip_share(
+    no_compile_cache, one_chip, drive, engine
+):
+    """The same launch (bucket 2,048, frontier 8,192) over the tables of
+    `drive-chip-share`, from their shapes alone: no store of that size is
+    built here. 3.27 GB of arguments, and a working set that does not
+    follow the tables' rows."""
+    (tables, qpack), statics = capture_launch(
+        kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
+    )
+    assert set(CHIP_SHARE_SHAPES) <= set(tables)
+    resized = {
+        k: jax.ShapeDtypeStruct(CHIP_SHARE_SHAPES.get(k, a.shape), a.dtype)
+        for k, a in tables.items()
+    }
+
+    def place(a):
+        # described() lays a committed array as device_table placed it; a
+        # bare shape is laid as device_table would place it
+        layout = kernel.bucket_row_layout(a.shape, a.dtype)
+        sharding = one_chip if layout is None else Format(layout, one_chip)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    args = (jax.tree.map(place, resized), place(qpack))
+    compiled = kernel.check_kernel_packed.lower(
+        *args, **{**statics, **CHIP_SHARE_PROBES}
+    ).compile()
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"\nchip share: argument={memory.argument_size_in_bytes} "
+          f"temp={memory.temp_size_in_bytes}")
+    assert 3 * 1024**3 < memory.argument_size_in_bytes
+    assert total < 4 * 10**9
+    assert_no_table_relayout(memory)
+
+
+def test_a_table_is_filled_in_place_on_the_chip(no_compile_cache, one_chip):
+    """`kernel.device_table`'s step at `drive-chip-share`'s largest table:
+    the donated table comes back as the output (`alias` is the table), and
+    beside it the program holds no more than the step's rows."""
+    shape = CHIP_SHARE_SHAPES["dh_pack"]
+    fmt = Format(kernel.bucket_row_layout(shape, np.int32), one_chip)
+    rows = (kernel.UPLOAD_ROWS, shape[1])
+    compiled = kernel._row_writer(fmt).lower(
+        jax.ShapeDtypeStruct(shape, np.int32, sharding=fmt),
+        jax.ShapeDtypeStruct(rows, np.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), np.int32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    table = kernel.tiled_nbytes(shape, np.int32)
+    assert memory.alias_size_in_bytes == memory.output_size_in_bytes == table
+    assert memory.temp_size_in_bytes <= kernel.tiled_nbytes(rows, np.int32)
+    assert compiled.output_formats.layout.major_to_minor == (0, 1)
+
+
 @pytest.fixture(scope="module")
 def closure_engine():
     """The differential tier's closure set (tools/tpu_test_tier.py): a
