@@ -41,7 +41,7 @@ from ..observability import (
     reset_request_trace,
     set_request_trace,
 )
-from ..ketoapi import RelationQuery, RelationTuple, SubjectSet
+from ..ketoapi import CheckColumns, RelationQuery, RelationTuple, SubjectSet
 from .descriptors import (
     BATCH_CHECK_SERVICE,
     CHECK_SERVICE,
@@ -335,50 +335,100 @@ class _Services:
             admit_check(self.registry, None, rt)
             nid = self._nid(context)
             version = self._enforce_snaptoken(req.snaptoken, nid)
-            idx: list[int] = []
-            tuples: list[RelationTuple] = []
-            out = [None] * len(req.tuples)
-            for i, pt in enumerate(req.tuples):
-                sub = subject_from_proto(pt.subject)
-                if sub is None:
-                    out[i] = pb.BatchCheckResult(
-                        allowed=False, error="subject is not allowed to be nil"
-                    )
-                    continue
-                t = RelationTuple.make(pt.namespace, pt.object, pt.relation, sub)
-                try:
-                    # same per-tuple namespace semantics as the
-                    # single-check gRPC plane (an ERROR, not a silent
-                    # deny) — but scoped to the item
-                    self.registry.validate_namespaces(t)
-                except KetoError as e:
-                    out[i] = pb.BatchCheckResult(allowed=False, error=e.message)
-                    continue
-                idx.append(i)
-                tuples.append(t)
+            cols, refused = self._batch_check_columns(req.tuples)
+            n = len(cols)
+            if refused:
+                launched = [i for i in range(n) if i not in refused]
+                cols = cols.take(launched)
             engine = self.registry.check_engine(nid)
         # the engine adds assemble / dispatch / device_wait / resolve to
         # this RPC's trace itself (check_batch reads the contextvar)
-        results = engine.check_batch(tuples, int(req.max_depth))
+        results = engine.check_batch(cols, int(req.max_depth))
         with self.metrics.stage("respond", rt):
-            answered: list[RelationTuple] = []
-            verdicts: list[bool] = []
-            for i, t, r in zip(idx, tuples, results):
-                if r.error is not None:
-                    out[i] = pb.BatchCheckResult(allowed=False, error=str(r.error))
-                else:
-                    allowed = r.allowed
-                    out[i] = pb.BatchCheckResult(allowed=allowed)
-                    answered.append(t)
-                    verdicts.append(allowed)
+            # no object an item: the two results without an error are
+            # built once, and extend() copies a message it is handed
+            verdicts = [r.allowed for r in results]
+            yes = pb.BatchCheckResult(allowed=True)
+            no = pb.BatchCheckResult(allowed=False)
+            out = [yes if allowed else no for allowed in verdicts]
+            failed = [j for j, r in enumerate(results) if r.error is not None]
+            for j in failed:
+                out[j] = pb.BatchCheckResult(
+                    allowed=False, error=str(results[j].error)
+                )
             obs = self.registry.workload_observatory()
             if obs is not None:
                 # workload accounting, once a batch (the batch bypasses
-                # the single-check serve gate; no per-item tier)
-                obs.record_check_batch(nid, answered, verdicts)
+                # the single-check serve gate; no per-item tier), of the
+                # answered items: an errored one is left out
+                if failed:
+                    answered = [
+                        j for j, r in enumerate(results) if r.error is None
+                    ]
+                    cols = cols.take(answered)
+                    verdicts = [verdicts[j] for j in answered]
+                obs.record_check_batch(nid, cols, verdicts)
+            if refused:
+                launched_out, out = out, [None] * n
+                for i, result in zip(launched, launched_out):
+                    out[i] = result
+                for i, error in refused.items():
+                    out[i] = pb.BatchCheckResult(allowed=False, error=error)
             resp = pb.BatchCheckResponse(snaptoken=encode_snaptoken(version, nid))
             resp.results.extend(out)
         return resp
+
+    def _batch_check_columns(self, wire_tuples):
+        """A BatchCheckRequest's items as CheckColumns, read field by
+        field off the wire with no RelationTuple between, and {row: error
+        string} for the rows that must not launch: a nil subject, or a
+        namespace (the item's first, then its subject set's) that is not
+        configured, with the same per-item semantics and message as the
+        single-check plane's validate_namespaces (an ERROR, not a silent
+        deny). The caller takes those rows out of the columns."""
+        # one pass over the repeated field makes the 2,048 wrappers, the
+        # column reads go over the list of them
+        wire_tuples = list(wire_tuples)
+        n = len(wire_tuples)
+        ns = [pt.namespace for pt in wire_tuples]
+        obj = [pt.object for pt in wire_tuples]
+        rel = [pt.relation for pt in wire_tuples]
+        subjects = [pt.subject for pt in wire_tuples]
+        sobj = [s.id for s in subjects]
+        skind, sns, srel = [0] * n, [""] * n, [""] * n
+        refused: dict[int, str] = {}
+        names = set(ns)
+        # a subject that reads as a non-empty id is one; the oneof is
+        # asked only about the rest
+        for i in [i for i, subject_id in enumerate(sobj) if not subject_id]:
+            kind = subjects[i].WhichOneof("ref")
+            if kind == "set":
+                sset = subjects[i].set
+                skind[i] = 1
+                sns[i], sobj[i], srel[i] = (
+                    sset.namespace, sset.object, sset.relation
+                )
+                names.add(sset.namespace)
+            elif kind is None:
+                refused[i] = "subject is not allowed to be nil"
+        # once a distinct name, not once an item
+        unknown: dict[str, str] = {}
+        manager = self.registry.namespace_manager()
+        for name in names:
+            try:
+                manager.get_namespace_by_name(name)
+            except KetoError as e:
+                unknown[name] = e.message
+        if unknown:
+            for i in range(n):
+                if i in refused:
+                    continue
+                error = unknown.get(ns[i])
+                if error is None and skind[i]:
+                    error = unknown.get(sns[i])
+                if error is not None:
+                    refused[i] = error
+        return CheckColumns(ns, obj, rel, skind, sns, sobj, srel), refused
 
     # -- ExpandService --------------------------------------------------------
 

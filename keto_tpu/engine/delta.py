@@ -117,17 +117,24 @@ class SnapshotView:
         return slot, rel
 
     def encode_subject(self, t: RelationTuple):
-        if t.subject_set is not None:
-            s = t.subject_set
-            ns = self.ns_id(s.namespace)
+        s = t.subject_set
+        if s is not None:
+            return self.encode_subject_fields(1, s.namespace, s.object, s.relation)
+        return self.encode_subject_fields(0, "", t.subject_id or "", "")
+
+    def encode_subject_fields(self, skind, sns: str, sobj: str, srel: str):
+        """encode_subject on a row of CheckColumns: a subject set's three
+        names where `skind` is set, else the plain id in `sobj`."""
+        if skind:
+            ns = self.ns_id(sns)
             if ns is None:
                 return None
-            slot = self.obj_slot(ns, s.object)
-            rel = self.rel_id(s.relation)
+            slot = self.obj_slot(ns, sobj)
+            rel = self.rel_id(srel)
             if slot is None or rel is None:
                 return None
             return 1, slot, rel
-        sid = self.subj_id(t.subject_id or "")
+        sid = self.subj_id(sobj)
         if sid is None:
             return None
         return 0, sid, 0

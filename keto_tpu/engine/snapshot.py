@@ -34,7 +34,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ..ketoapi import RelationTuple
+from ..ketoapi import CheckColumns, RelationTuple
 from ..namespace import ast
 from ..namespace.definitions import Namespace
 from .definitions import WILDCARD_RELATION
@@ -1360,30 +1360,19 @@ def encode_query_batch(view, tuples, B: int):
     names written after the base snapshot); exact same semantics as the
     per-tuple loop.
 
+    `tuples` is a CheckColumns (a served BatchCheck's items, which
+    never were tuples) or a run of RelationTuples, whose columns are
+    taken here; either way the encoder reads columns and builds no
+    tuple.
+
     Returns (q_obj, q_rel, q_skind, q_sa, q_sb, q_valid) arrays of
     length B (tail rows beyond len(tuples) stay invalid)."""
     snap = view.snapshot
-    n = len(tuples)
-    ns_l = [""] * n
-    obj_l = [""] * n
-    rel_l = [""] * n
-    skind_l = np.zeros(n, dtype=np.int32)
-    sns_l = [""] * n
-    sobj_l = [""] * n
-    srel_l = [""] * n
-    for i, t in enumerate(tuples):
-        ns_l[i] = t.namespace
-        obj_l[i] = t.object
-        rel_l[i] = t.relation
-        if t.subject_set is not None:
-            skind_l[i] = 1
-            sns_l[i] = t.subject_set.namespace
-            sobj_l[i] = t.subject_set.object
-            srel_l[i] = t.subject_set.relation
-        else:
-            sobj_l[i] = t.subject_id or ""
+    cols = CheckColumns.of(tuples)
+    n = len(cols)
+    ns_l, obj_l, rel_l, skind_l, sns_l, sobj_l, srel_l = cols.columns()
 
-    is_set = skind_l == 1
+    is_set = np.asarray(skind_l, dtype=np.int32) == 1
     # node half: shared vectorized base lookups + overlay node patch
     node_obj, node_rel, node_valid = _encode_nodes(
         view, ns_l, obj_l, rel_l, np.ones(n, dtype=bool)
@@ -1429,18 +1418,16 @@ def encode_query_batch(view, tuples, B: int):
         unresolved = np.flatnonzero(node_valid & ~(set_ok | plain_ok))
         for i in unresolved:
             i = int(i)
-            t = tuples[i]
-            if t.subject_set is not None:
-                s = t.subject_set
+            if is_set[i]:
                 sns = int(s_ns[i])
                 if sns == -1:
-                    sns = ov.ns_ids.get(s.namespace, -1)
+                    sns = ov.ns_ids.get(sns_l[i], -1)
                 srl = int(s_rel[i])
                 if srl == -1:
-                    srl = ov.rel_ids.get(s.relation, -1)
+                    srl = ov.rel_ids.get(srel_l[i], -1)
                 ssl = int(s_slot[i])
                 if ssl == -1 and sns != -1:
-                    ssl = ov.obj_slots.get((sns, s.object), -1)
+                    ssl = ov.obj_slots.get((sns, sobj_l[i]), -1)
                 if sns != -1 and srl != -1 and ssl != -1:
                     q_skind[i], q_sa[i], q_sb[i] = 1, ssl, srl
                 else:
@@ -1448,7 +1435,7 @@ def encode_query_batch(view, tuples, B: int):
             else:
                 sv = int(sid[i])
                 if sv == -1:
-                    sv = ov.subj_ids.get(t.subject_id or "", -1)
+                    sv = ov.subj_ids.get(sobj_l[i], -1)
                 if sv != -1:
                     q_skind[i], q_sa[i], q_sb[i] = 0, sv, 0
                 else:

@@ -37,7 +37,7 @@ import numpy as np
 from .. import faults as _faults
 from ..config import Config
 from ..errors import StoreUnavailableError
-from ..ketoapi import RelationTuple, Subject, Tree
+from ..ketoapi import CheckColumns, RelationTuple, Subject, Tree
 from ..storage.definitions import DEFAULT_NETWORK, Manager
 from .definitions import (
     RESULT_IS_MEMBER,
@@ -2297,7 +2297,10 @@ class TPUCheckEngine:
         calling thread, so a served BatchCheck's RequestTrace rides the
         ambient contextvar into the launch as its one rider: the engine
         stages land on the RPC's breakdown as they do for the batcher's
-        riders."""
+        riders. `tuples` is a run of RelationTuples or, from a handler
+        that read them off the wire, CheckColumns: the same launch
+        either way, and of columns only the items that need the host
+        oracle are ever built as tuples (_resolve_items)."""
         from ..observability import current_request_trace
 
         return self.check_batch_resolve(
@@ -2700,8 +2703,11 @@ class TPUCheckEngine:
             q_sb = np.zeros(B, dtype=np.int32)
             q_valid = np.zeros(B, dtype=bool)
 
-            for i, t in enumerate(tuples):
-                node = state.view.encode_node(t.namespace, t.object, t.relation)
+            encode_node = state.view.encode_node
+            encode_subject = state.view.encode_subject_fields
+            rows = zip(*CheckColumns.of(tuples).columns())
+            for i, (ns, obj, rel, skind, sns, sobj, srel) in enumerate(rows):
+                node = encode_node(ns, obj, rel)
                 if node is None:
                     # namespace/object/relation absent from graph+config:
                     # no edge can match, but error semantics (missing
@@ -2710,7 +2716,7 @@ class TPUCheckEngine:
                     # to the replay loop)
                     continue
                 q_obj[i], q_rel[i] = node
-                subject = state.view.encode_subject(t)
+                subject = encode_subject(skind, sns, sobj, srel)
                 if subject is not None:
                     q_skind[i], q_sa[i], q_sb[i] = subject
                 # unknown subject keeps the sentinel: traversal still runs
@@ -2837,7 +2843,11 @@ class TPUCheckEngine:
         if leftover:
             sub_sink = [None] * len(leftover) if sink is not None else None
             sub_handle = self.check_batch_submit(
-                [tuples[i] for i in leftover],
+                (
+                    tuples.take(leftover)
+                    if isinstance(tuples, CheckColumns)
+                    else [tuples[i] for i in leftover]
+                ),
                 max_depth,
                 telemetry=(
                     [telemetry[i] for i in leftover] if telemetry else None
@@ -2991,7 +3001,8 @@ class TPUCheckEngine:
         # (an adversarial batch of 4096 same-tuple fallbacks would
         # otherwise serialize 4096 recursive walks)
         replay_memo: dict[tuple, CheckResult] = {}
-        for i, t in enumerate(meta["tuples"]):
+        tuples = meta["tuples"]
+        for i in range(meta["n"]):
             if i < B and q_valid[i] and not needs_host[i]:
                 # shared immutable singletons: 4096 CheckResult
                 # constructions per batch are measurable on the
@@ -3016,6 +3027,7 @@ class TPUCheckEngine:
                 else:
                     cause = CAUSE_NAME_UNINDEXED
                 host_causes[cause] = host_causes.get(cause, 0) + 1
+                t = tuples[i]  # of CheckColumns, built here
                 # field-structured key: the display string is NOT
                 # injective (a subject_id spelled "(ns:obj#rel)"
                 # renders like a real subject set)
@@ -3037,6 +3049,12 @@ class TPUCheckEngine:
                     sink[i] = {"tier": "host", "cause": cause}
                 if telemetry is not None and telemetry[i] is not None:
                     telemetry[i].tier = "host"
+        if self.metrics is not None and isinstance(tuples, CheckColumns):
+            # a batch that came as columns built one RelationTuple a
+            # replayed item, for the oracle, and none for the others:
+            # beside keto_tpu_checks_total, the share of a served
+            # BatchCheck's items that still cost an object
+            self.metrics.check_batch_tuples_built_total.inc(n_host)
         return results, versions, n_host, host_s
 
     def _finish_check_stages(

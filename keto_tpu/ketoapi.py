@@ -34,6 +34,7 @@ __all__ = [
     "SubjectSet",
     "Subject",
     "RelationTuple",
+    "CheckColumns",
     "RelationQuery",
     "PatchAction",
     "PatchDelta",
@@ -273,6 +274,84 @@ class RelationTuple:
 
     def __eq__(self, other):
         return isinstance(other, RelationTuple) and self._key() == other._key()
+
+
+class CheckColumns:
+    """The items of one batch of checks as seven parallel lists, the form
+    a BatchCheck has on the wire and the query encoder wants: namespace,
+    object, relation, subject kind (1 for a subject set, 0 for a plain
+    id), and the subject's namespace, object-or-id and relation ("" in
+    the two a plain id lacks). It reads as a sequence of RelationTuples,
+    each built when it is asked for and not before: a 2,048-item batch
+    that the device answers whole builds none."""
+
+    __slots__ = ("ns", "obj", "rel", "skind", "sns", "sobj", "srel")
+
+    def __init__(self, ns, obj, rel, skind, sns, sobj, srel):
+        self.ns, self.obj, self.rel = ns, obj, rel
+        self.skind, self.sns, self.sobj, self.srel = skind, sns, sobj, srel
+
+    @classmethod
+    def of(cls, tuples) -> "CheckColumns":
+        """`tuples` itself where it already is columns, else the columns
+        of a run of RelationTuples (a tuple without a subject reads as
+        the plain id "", as the query encoder has always read it)."""
+        if isinstance(tuples, cls):
+            return tuples
+        n = len(tuples)
+        ns = [t.namespace for t in tuples]
+        obj = [t.object for t in tuples]
+        rel = [t.relation for t in tuples]
+        sobj = [t.subject_id or "" for t in tuples]
+        skind, sns, srel = [0] * n, [""] * n, [""] * n
+        for i, s in enumerate([t.subject_set for t in tuples]):
+            if s is not None:
+                skind[i] = 1
+                sns[i], sobj[i], srel[i] = s.namespace, s.object, s.relation
+        return cls(ns, obj, rel, skind, sns, sobj, srel)
+
+    def columns(self) -> tuple:
+        return (
+            self.ns, self.obj, self.rel,
+            self.skind, self.sns, self.sobj, self.srel,
+        )
+
+    def __len__(self) -> int:
+        return len(self.ns)
+
+    def tuple_at(self, i: int) -> RelationTuple:
+        t = RelationTuple(self.ns[i], self.obj[i], self.rel[i])
+        if self.skind[i]:
+            t.subject_set = SubjectSet(self.sns[i], self.sobj[i], self.srel[i])
+        else:
+            t.subject_id = self.sobj[i]
+        return t
+
+    def __getitem__(self, i):
+        """Row i as a RelationTuple, or a slice as columns."""
+        if isinstance(i, slice):
+            return CheckColumns(*(col[i] for col in self.columns()))
+        return self.tuple_at(i)
+
+    def __iter__(self):
+        return map(self.tuple_at, range(len(self)))
+
+    def take(self, rows) -> "CheckColumns":
+        """The given rows, in the given order, as columns."""
+        return CheckColumns(*([col[i] for i in rows] for col in self.columns()))
+
+    def subjects(self) -> list:
+        """Every row's subject: the id string, or the SubjectSet of a
+        subject-set row. Not to be written to: without a subject set
+        among the rows it is the id column itself."""
+        if 1 not in self.skind:
+            return self.sobj
+        return [
+            SubjectSet(sns, sobj, srel) if kind else sobj
+            for kind, sns, sobj, srel in zip(
+                self.skind, self.sns, self.sobj, self.srel
+            )
+        ]
 
 
 @dataclass

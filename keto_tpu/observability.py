@@ -51,8 +51,9 @@ CHECK_STAGES = (
     "cache",          # check-cache fast-path lookup (hits only: a hit
                       # request records NO assemble/dispatch/device_wait
                       # because those stages never run)
-    "decode",         # BatchCheck handler: admission + wire tuples ->
-                      # RelationTuples + namespace validation
+    "decode",         # BatchCheck handler: admission + wire items ->
+                      # CheckColumns (gRPC) or RelationTuples (REST)
+                      # + namespace validation
     "queue",          # batcher queue wait (enqueue -> group dispatch)
     "assemble",       # state refresh + batch encoding + bucket padding
     "dispatch",       # device launch (H2D upload + async kernel dispatch)
@@ -412,6 +413,15 @@ class Metrics:
             "rewrite_cap) from semantic causes (relation_not_found, "
             "config_missing) and staleness (dirty_row)",
             ["cause"],
+            registry=self.registry,
+        )
+        self.check_batch_tuples_built_total = prom.Counter(
+            "keto_tpu_check_batch_tuples_built_total",
+            "Items of BatchChecks served as columns that were built as "
+            "RelationTuples after all, because the host oracle had to "
+            "answer them (host replay, or a host engine); over "
+            "keto_tpu_checks_total, the share of items that still cost "
+            "an object",
             registry=self.registry,
         )
         self.check_batch_size = prom.Histogram(

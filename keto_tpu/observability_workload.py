@@ -66,6 +66,8 @@ from collections import Counter
 from operator import itemgetter
 from typing import Callable, Optional
 
+from .ketoapi import CheckColumns
+
 logger = logging.getLogger("keto_tpu")
 
 # the answering-tier vocabulary (§5m's explain tiers + the REST-only
@@ -566,7 +568,10 @@ class SLOEngine:
 def _field_columns(tuples) -> tuple[list, list, list, list]:
     """(namespaces, objects, relations, subjects) of a run of tuples:
     references to the tuples' own strings (a subject set rides as the
-    object it is), so whoever holds the columns holds no tuple."""
+    object it is), so whoever holds the columns holds no tuple. A
+    BatchCheck that came as CheckColumns hands over the lists it has."""
+    if isinstance(tuples, CheckColumns):
+        return tuples.ns, tuples.obj, tuples.rel, tuples.subjects()
     return (
         [t.namespace for t in tuples],
         [t.object for t in tuples],
@@ -719,7 +724,8 @@ class WorkloadObservatory:
     def record_check_batch(self, nid: str, tuples, allowed, tier=None) -> None:
         """The answered items of one BatchCheck (`allowed[i]` is the
         verdict on `tuples[i]`; errored items are the caller's to leave
-        out): enqueue ONE event for all of them. It holds the items'
+        out), as RelationTuples or as the CheckColumns the handler read
+        off the wire: enqueue ONE event for all of them. It holds the items'
         fields as columns of string references, not the tuples: 2,048
         tuples pinned until the fold are 2,048 more objects for the
         collector to promote and walk, five lists are five."""

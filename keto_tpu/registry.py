@@ -25,7 +25,7 @@ from . import __version__
 from .config import Config
 from .engine.reference import ReferenceEngine
 from .errors import NamespaceNotFoundError
-from .ketoapi import RelationQuery, RelationTuple
+from .ketoapi import CheckColumns, RelationQuery, RelationTuple
 from .storage.definitions import DEFAULT_NETWORK
 from .storage.memory import MemoryManager
 
@@ -825,6 +825,9 @@ class _HostEngineFacade:
         if self.metrics is not None and tuples:
             self.metrics.check_batch_size.observe(len(tuples))
             self.metrics.checks_total.labels("host").inc(len(tuples))
+            if isinstance(tuples, CheckColumns):
+                # the oracle takes tuples: the loop below builds every one
+                self.metrics.check_batch_tuples_built_total.inc(len(tuples))
         return [self.check_relation_tuple(t, max_depth) for t in tuples]
 
     def explain_check(self, t, max_depth: int = 0, rt=None):

@@ -16,7 +16,7 @@ from keto_tpu.config import Config
 from keto_tpu.engine.definitions import Membership
 from keto_tpu.engine.reference import ReferenceEngine
 from keto_tpu.engine.tpu_engine import TPUCheckEngine
-from keto_tpu.ketoapi import RelationTuple
+from keto_tpu.ketoapi import CheckColumns, RelationTuple
 from keto_tpu.namespace import Namespace
 from keto_tpu.namespace.ast import (
     ComputedSubjectSet,
@@ -244,10 +244,12 @@ class TestCheckParity:
         ])
         assert all(r.membership == Membership.NOT_MEMBER for r in res)
 
-    def test_mixed_batch_splits_and_merges_in_order(self):
+    @pytest.mark.parametrize("as_columns", [False, True])
+    def test_mixed_batch_splits_and_merges_in_order(self, as_columns):
         # covered nodes + an uncovered (island) namespace in ONE batch:
         # resolved verdicts and BFS-leftover verdicts must interleave
-        # back into request order
+        # back into request order; a batch that arrives as CheckColumns
+        # hands its leftover rows on as columns
         ns = deep_namespaces() + [Namespace(name="acl", relations=[
             Relation(name="allow"), Relation(name="deny"),
             Relation(name="access", subject_set_rewrite=SubjectSetRewrite(
@@ -271,7 +273,7 @@ class TestCheckParity:
             RelationTuple.from_string("deep:c1f0#viewer@nobody"),
             RelationTuple.from_string("acl:e#access@u2"),
         ]
-        res = engine.check_batch(batch)
+        res = engine.check_batch(CheckColumns.of(batch) if as_columns else batch)
         assert [r.membership for r in res] == [
             Membership.IS_MEMBER, Membership.IS_MEMBER,
             Membership.NOT_MEMBER, Membership.NOT_MEMBER,

@@ -635,6 +635,35 @@ class TestBatchEvent:
             subject_key(t) for t in self.TUPLES
         }
 
+    def test_columns_give_the_counts_and_keys_the_tuples_give(self):
+        """A served BatchCheck hands over the CheckColumns it read off the
+        wire: the same accounting, and every sketch key byte for byte,
+        subject sets included."""
+        from keto_tpu.ketoapi import CheckColumns
+
+        cols = CheckColumns.of(self.TUPLES)
+        by_tuples, by_columns = _obs(metrics=Metrics()), _obs(metrics=Metrics())
+        by_columns.start_folder(interval_s=3600.0)  # hold the fold off
+        try:
+            by_columns.record_check_batch("net0", cols, self.ALLOWED)
+            (_, _, _, columns), = by_columns._batch_buf
+            assert columns[3] == [t.subject for t in self.TUPLES]
+            assert columns[0] is cols.ns  # the handler's lists, not copies
+        finally:
+            by_columns.stop_folder()
+        by_tuples.record_check_batch("net0", self.TUPLES, self.ALLOWED)
+        assert by_columns.accounting() == by_tuples.accounting()
+        assert _request_children(by_columns.metrics) == _request_children(
+            by_tuples.metrics
+        )
+        for kind in ("object", "subject", "check"):
+            assert sorted(by_columns.sketches[kind].top(16)) == sorted(
+                by_tuples.sketches[kind].top(16)
+            )
+        assert {k for k, _, _ in by_columns.sketches["check"].top(16)} == {
+            str(t) for t in self.TUPLES
+        }
+
     def test_the_event_holds_no_tuple(self):
         obs = _obs()
         obs.start_folder(interval_s=3600.0)
