@@ -200,6 +200,12 @@ def estimate_step_gather_bytes(cfg: dict) -> int:
     b += F * 32                                 # srcmat [F, 8] rows
     b += F * 8                                  # e_pack (obj, rel) rows
     b += 2 * F * 16                             # dedupe winner + key rows
+    if int(cfg.get("n_island_cap", 0)):
+        # the island variant's own per-step traffic: the isl_parent and
+        # isl_pid scatters, one int32 per task each. Its leaf ctxs ride
+        # the ctx_hit gather and scatter that every variant makes (one
+        # bool per task), and the circuits run on the host.
+        b += 2 * F * 4
     return b
 
 

@@ -2916,16 +2916,21 @@ class TPUCheckEngine:
                 _faults.inject("batch_corrupt")
                 # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
                 needs_host = np.maximum(np.asarray(needs_host), 1)
-            if n_isl:
-                from .islands import combine_islands
+        meta["n_isl"] = n_isl
+        islands = None
+        if n_isl:
+            from .islands import combine_islands
 
+            # the AND/NOT circuits over the leaf bits: host work of its
+            # own, outside device_wait, so that stage stays the device's
+            with StageSpan("islands", meta.get("launch_id")) as islands:
                 member = combine_islands(
                     # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
                     ctx_hit, np.asarray(isl_parent), np.asarray(isl_pid),
                     n_isl, state.snapshot.island_circuits, B, state.snapshot.K,
                 )
-            else:
-                member = ctx_hit[:B]
+        else:
+            member = ctx_hit[:B]
 
         sink = meta.get("explain_sink")
         telemetry = meta.get("telemetry")
@@ -2975,11 +2980,13 @@ class TPUCheckEngine:
                 self.metrics.checks_total.labels("device").inc(n - n_host)
                 if n_host:
                     self.metrics.checks_total.labels("host").inc(n_host)
+                if n_isl:
+                    self.metrics.island_instances_total.inc(n_isl)
                 for cause, cnt in host_causes.items():
                     self.metrics.host_fallback_total.labels(cause).inc(cnt)
         self._finish_check_stages(
             meta, waited, resolved, host_s, n, B,
-            stats=stats, host_causes=host_causes,
+            stats=stats, host_causes=host_causes, islands=islands,
         )
         return results, versions
 
@@ -3059,19 +3066,22 @@ class TPUCheckEngine:
 
     def _finish_check_stages(
         self, meta, waited, resolved, host_s: float, n: int, B: int,
-        stats=None, host_causes=None,
+        stats=None, host_causes=None, islands=None,
     ) -> None:
         """Finalize one batch's stage attribution: per-stage histogram
         samples (once per batch), each rider's RequestTrace stages
         (+ launch id), the flight-recorder entry, and per-request engine
-        spans when tracing. `waited` and `resolved` are the batch's
-        device_wait and resolve StageSpans; `resolve` is what followed
-        the readback less the `host_s` seconds of host replay inside it.
+        spans when tracing. `waited`, `islands` (None where the launch
+        allocated no island) and `resolved` are the batch's device_wait,
+        islands and resolve StageSpans; `resolve` is what followed the
+        combine less the `host_s` seconds of host replay inside it.
         Batch-shared stages are attributed identically to every rider —
         the breakdown says where the BATCH spent its time, which is what
         a tail-latency investigation needs."""
         stage_s = dict(meta.get("stage_s") or ())
         stage_s["device_wait"] = waited.seconds
+        if islands is not None:
+            stage_s["islands"] = islands.seconds
         stage_s["resolve"] = max(0.0, resolved.seconds - host_s)
         if host_s > 0.0:
             stage_s["host_fallback"] = host_s
@@ -3150,6 +3160,8 @@ class TPUCheckEngine:
         }
         if "closure_resolved" in meta:
             entry["closure_resolved"] = meta["closure_resolved"]
+        if "n_isl" in meta:
+            entry["n_isl"] = meta["n_isl"]
         if t_submit is not None:
             entry["wall_ms"] = round(
                 (time.perf_counter() - t_submit) * 1e3, 3

@@ -58,6 +58,8 @@ CHECK_STAGES = (
     "assemble",       # state refresh + batch encoding + bucket padding
     "dispatch",       # device launch (H2D upload + async kernel dispatch)
     "device_wait",    # block-until-ready + readback + unpack
+    "islands",        # the AND/NOT island circuits over the leaf bits
+                      # (launches that allocated an island only)
     "resolve",        # verdicts -> CheckResults + counters after the
                       # readback, less any host_fallback seconds
     "host_fallback",  # exact host replay of cause-flagged queries
@@ -424,6 +426,13 @@ class Metrics:
             "an object",
             registry=self.registry,
         )
+        self.island_instances_total = prom.Counter(
+            "keto_tpu_island_instances_total",
+            "AND/NOT island instances the check launches allocated, each "
+            "one circuit combined on the host after the launch; over "
+            "keto_tpu_checks_total, the islands a check costs",
+            registry=self.registry,
+        )
         self.check_batch_size = prom.Histogram(
             "keto_tpu_check_batch_size",
             "Queries per device batch",
@@ -487,7 +496,7 @@ class Metrics:
             "keto_tpu_check_stage_duration_seconds",
             "Check serving time per pipeline stage (transport | cache | "
             "decode | queue | assemble | dispatch | device_wait | "
-            "resolve | host_fallback | respond); batch-level stages "
+            "islands | resolve | host_fallback | respond); batch-level stages "
             "observe once per device batch; `cache` observes per cache "
             "hit (hit requests record no assemble/dispatch/device_wait "
             "time); decode/respond observe once per BatchCheck; "

@@ -18,6 +18,12 @@ from keto_tpu.registry import Registry
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
 TUPLES = 20_000
+# what a configuration's traffic asks: the drive's one relation, or the
+# rewrite namespaces' mix of AND, AND NOT and a union
+RELATIONS = {
+    "drive-chip-share": {"view"},
+    "rewrites-4e6": {"delete", "access", "read"},
+}
 
 
 @pytest.fixture(scope="module")
@@ -32,7 +38,7 @@ def workload_module():
 
 
 @pytest.mark.parametrize("seed", [3000003951, 11])
-@pytest.mark.parametrize("config_name", ["drive-chip-share"])
+@pytest.mark.parametrize("config_name", ["drive-chip-share", "rewrites-4e6"])
 def test_a_batch_of_the_cell_equals_reference_and_truth(
     workload_module, config_name, seed
 ):
@@ -50,7 +56,7 @@ def test_a_batch_of_the_cell_equals_reference_and_truth(
 
     queries, truth, _ = workload.request(0)
     assert len(queries) == traffic["items"] == 2048
-    assert {q.relation for q in queries} == {"view"}
+    assert {q.relation for q in queries} == RELATIONS[config_name]
     assert 0.4 < truth.mean() < 0.6  # half of the queries allowed
     answers = [result.allowed for result in engine.check_batch(queries)]
     reference = [

@@ -232,6 +232,52 @@ def test_check_kernel_fits_the_chip_at_the_chip_share(
     assert_no_table_relayout(memory)
 
 
+@pytest.fixture(scope="module")
+def rewrites_engine():
+    """`rewrites-4e6`'s namespaces and construction at 20,000 tuples:
+    Keto's AND and AND NOT rewrites, so the check launch compiles its
+    island variant. The variant's working set follows the launch's
+    bucket, frontier and island cap, not the tables' rows."""
+    import os
+    import sys
+
+    from keto_tpu.config import Config
+    from keto_tpu.registry import Registry
+
+    bench = os.path.join(os.path.dirname(chip_smoke.__file__), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import workload
+    finally:
+        sys.path.remove(bench)
+    config = workload.read_json(os.path.join(bench, "configs", "rewrites-4e6.json"))
+    traffic = workload.read_json(os.path.join(bench, "traffic", "batch_checks.json"))
+    wl = workload.Workload(config, traffic, 11, 20_000)
+    registry = Registry(Config(config["serve_config"]))
+    registry.relation_tuple_manager().bulk_load(wl.columns())
+    return registry.check_engine(), wl.request(0)[0]
+
+
+def test_island_check_kernel_compiles_for_the_chip(
+    no_compile_cache, one_chip, rewrites_engine
+):
+    """The island variant of the check launch at the cell's 2,048-item
+    BatchCheck: bucket 2,048, frontier 8,192, 4,096 island instances of
+    K = 2 leaves, so 10,240 ctx accumulators."""
+    engine, queries = rewrites_engine
+    args, statics = capture_launch(
+        kernel, "check_kernel_packed", lambda: engine.check_batch(queries)
+    )
+    assert args[1].shape == (7, 2048) and statics["frontier_cap"] == 8192
+    assert statics["n_island_cap"] == 4096 and statics["K"] == 2
+    _, memory = compile_for_chip(
+        "check_kernel_packed[islands]", kernel.check_kernel_packed, args,
+        statics, lambda a: one_chip,
+    )
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_HBM_BYTES
+    assert memory.temp_size_in_bytes < 1024**3
+
+
 def test_a_table_is_filled_in_place_on_the_chip(no_compile_cache, one_chip):
     """`kernel.device_table`'s step at `drive-chip-share`'s largest table:
     the donated table comes back as the output (`alias` is the table), and
