@@ -32,7 +32,8 @@ clock-monotonic      Deadline/backoff/retry math uses `time.monotonic`
                      `datetime.now()` never appear in keto_tpu. Wall
                      clocks jump (NTP, suspend) and break deadlines.
 host-sync            Inside the engine batch hot path (check/list/
-                     expand submit+resolve), every device
+                     expand submit+resolve, the check's frontier
+                     retry), every device
                      synchronization — `np.asarray` readback,
                      `.block_until_ready()`, `jax.device_get`, scalar
                      `int()`/`float()` coercion of a device value, or a
@@ -95,7 +96,7 @@ _HOT_FILES = {
 # by accident
 _HOT_FUNCS = re.compile(
     r"^_?(check_batch_submit|check_batch_resolve(_v)?|check_batch"
-    r"|closure_batch_resolve(_v)?|closure_power_resolve"
+    r"|closure_batch_resolve(_v)?|closure_power_resolve|frontier_retry"
     r"|list_objects_batch|list_subjects_batch|expand_batch"
     r"|filter_batch|filter_chunk)(_inner)?$"
 )

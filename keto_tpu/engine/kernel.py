@@ -123,11 +123,14 @@ STAT_PROBE_HITS = 4     # direct-edge probe hits accumulated (check only)
 STAT_EDGE_ROWS = 5      # candidate rows materially gathered (valid
                         # expansion children / emitted expand edges)
 STAT_DEDUPE_KEPT = 6    # dedupe survivors admitted to the next frontier
-STAT_RESERVED = 7
+STAT_DEMAND_MAX = 7     # max pre-truncation candidate total of a step
+                        # (check only): the frontier a launch needed. On
+                        # one device the candidates are cut at F, so
+                        # demand <= F is exactly "no frontier overflow"
 
 STAT_NAMES = (
     "steps", "frontier_sum", "frontier_max", "live_sum",
-    "probe_hits", "edge_rows", "dedupe_kept", "reserved",
+    "probe_hits", "edge_rows", "dedupe_kept", "demand_max",
 )
 
 
@@ -142,11 +145,13 @@ def update_launch_stats(
     n_hits: jnp.ndarray,
     n_children: jnp.ndarray,
     n_kept: jnp.ndarray,
+    demand=0,
 ) -> jnp.ndarray:
     """One step's counter accumulation (shared by the single-device and
     sharded check kernels so both report identical semantics). All
     operands must be REPLICATED values on a mesh — the sharded caller
-    passes post-collective quantities only."""
+    passes post-collective quantities only, and no demand (its candidate
+    totals are each shard's own)."""
     inc = jnp.stack([
         jnp.int32(1),
         n_tasks.astype(jnp.int32),
@@ -157,18 +162,17 @@ def update_launch_stats(
         n_kept.astype(jnp.int32),
         jnp.int32(0),
     ])
-    return (stats + inc).at[STAT_FRONTIER_MAX].max(n_tasks.astype(jnp.int32))
+    return (
+        (stats + inc)
+        .at[STAT_FRONTIER_MAX].max(n_tasks.astype(jnp.int32))
+        .at[STAT_DEMAND_MAX].max(jnp.asarray(demand, jnp.int32))
+    )
 
 
 def launch_stats_dict(stats) -> dict:
     """Host-side view of a stats vector as named fields (entry payload
     for the flight recorder and the bench aggregates)."""
-    vals = [int(v) for v in stats]
-    return {
-        name: vals[i]
-        for i, name in enumerate(STAT_NAMES)
-        if name != "reserved"
-    }
+    return dict(zip(STAT_NAMES, (int(v) for v in stats)))
 
 
 def estimate_step_gather_bytes(cfg: dict) -> int:
@@ -670,7 +674,7 @@ def expand_phase(
     n_island_cap: int,
     has_delta: bool = True,
     prog=None,
-) -> tuple[Expansion, jnp.ndarray, tuple]:
+) -> tuple[Expansion, jnp.ndarray, tuple, jnp.ndarray]:
     """Expand every live task through its CSR row + rewrite instructions.
 
     Monotone programs: instruction children inherit the task's ctx (any
@@ -687,10 +691,11 @@ def expand_phase(
     expansion is an or-branch BESIDE the rewrite, not inside it
     (engine.go:183-207).
 
-    Returns (candidates, per-query host flags, island updates):
+    Returns (candidates, per-query host flags, island updates, demand):
     candidates beyond the frontier capacity are truncated and their
     owning queries flagged for host replay; delta-dirty rows and island-
-    table overflow flag their queries too.
+    table overflow flag their queries too. `demand` is the candidate
+    total before the cut (STAT_DEMAND_MAX).
     """
     F = q.shape[0]
     S = K + 1  # expansion slots per task: CSR row + K instructions
@@ -874,6 +879,7 @@ def expand_phase(
         Expansion(src_q, src_ctx, child_obj, child_rel, child_depth, child_valid),
         overflow_q,
         (isl_parent, isl_pid, n_isl),
+        total,
     )
 
 
@@ -1134,8 +1140,13 @@ def _check_kernel_impl(
         # a task dies when its query is resolved (top-level or short-
         # circuit) or its own accumulator already hit (per-ctx
         # short-circuit: an island leaf is an OR accumulation too)
-        live = (idx < st.n_tasks) & ~root_done[q] & ~st.ctx_hit[ctx]
         obj, rel, depth = st.t_obj, st.t_rel, st.t_depth
+        # an invalid query's seed task (depth -1) is inert: it neither
+        # probes nor expands, computed hops and islands included
+        live = (
+            (idx < st.n_tasks) & (depth >= 0)
+            & ~root_done[q] & ~st.ctx_hit[ctx]
+        )
 
         prog = program_lookup(tables, obj, rel, live, n_config_rels=n_config_rels)
         flagged = flag_phase(
@@ -1154,7 +1165,7 @@ def _check_kernel_impl(
         # refresh liveness after accumulator updates (short-circuit)
         live = live & ~(ctx_hit[:B] | (needs_host > 0))[q] & ~ctx_hit[ctx]
 
-        children, overflow_q, isl_state = expand_phase(
+        children, overflow_q, isl_state, demand = expand_phase(
             tables, q, ctx, obj, rel, depth, live,
             (st.isl_parent, st.isl_pid, st.n_isl),
             K=K, rh_probes=rh_probes, n_config_rels=n_config_rels,
@@ -1168,15 +1179,16 @@ def _check_kernel_impl(
         )
         needs_host = jnp.maximum(needs_host, overflow2)
         # launch introspection: a handful of scalar reductions per step
-        # (measured in the committed A/B leg as within-noise); depth >= 0
-        # excludes the seed bucket's padding tasks from the live count
+        # (measured in the committed A/B leg as within-noise); the seed
+        # bucket's padding tasks are not live, so not in the live count
         stats = update_launch_stats(
             st.stats,
             st.n_tasks,
-            (live & (depth >= 0)).sum(),
+            live.sum(),
             hit.sum(),
             children.valid.sum(),
             n_new,
+            demand,
         )
         return _State(
             nt_q, nt_ctx, nt_obj, nt_rel, nt_depth, n_new,

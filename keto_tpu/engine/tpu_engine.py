@@ -26,6 +26,7 @@ micro-batcher feeds.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
@@ -50,8 +51,10 @@ from ..observability import StageSpan, next_launch_id
 from .device_feed import DeviceFeed
 from .delta import SnapshotView, empty_delta_tables
 from .kernel import (
+    CAUSE_FRONTIER_OVERFLOW,
     CAUSE_NAME_UNINDEXED,
     CAUSE_NAMES,
+    STAT_DEMAND_MAX,
     _KERNEL_STATICS,
     check_kernel,
     device_tables,
@@ -75,6 +78,13 @@ from .snapshot import (
 # >= n, floor 16, so above the floor under half of a launch's static shape
 # is padding. Every batched verb reads this one ladder.
 _BUCKETS = tuple(1 << k for k in range(4, 15))
+
+# the frontier rungs of a check launch of bucket B: r * B for each r,
+# between max(B, 64) and the widest, min(frontier_cap, max(4 * B, 64)).
+# A launch runs at the narrowest rung that held the frontier demand of
+# the bucket's last _DEMAND_HISTORY launches (2 * B before any)
+_FRONTIER_RUNGS = (1, 2, 4)
+_DEMAND_HISTORY = 16
 
 _paginate = paginate_names
 
@@ -175,15 +185,20 @@ class TPUCheckEngine:
         self.nid = nid
         # the frontier must hold at least one task per batched query
         self.frontier_cap = max(frontier_cap, _BUCKETS[0])
-        # size each launch's frontier to its bucket: four slots a query
-        # slot of the `_BUCKETS` ladder, `frontier_cap` at most (a step
-        # costs what its frontier holds, padding included, so a 16-query
-        # launch must not pay a 16k-task frontier). False pins every
-        # launch at `frontier_cap` — for operators who sized it
-        # explicitly to keep wide-fanout queries on-device (overflow
-        # falls back to exact-but-slow host replay).
+        # size each launch's frontier to its bucket: one, two or four
+        # slots a query slot of the `_BUCKETS` ladder, as the bucket's
+        # launches have needed, `frontier_cap` at most (a step costs what
+        # its frontier holds, padding included, so a 16-query launch must
+        # not pay a 16k-task frontier). False pins every launch at
+        # `frontier_cap` — for operators who sized it explicitly to keep
+        # wide-fanout queries on-device (overflow falls back to
+        # exact-but-slow host replay).
         self.auto_frontier = auto_frontier
         self._allowed_buckets = [b for b in _BUCKETS if b <= self.frontier_cap]
+        # bucket -> the frontier demand (STAT_DEMAND_MAX) of its last
+        # _DEMAND_HISTORY single-device check launches
+        self._demand: dict[int, collections.deque] = {}
+        self._demand_mu = threading.Lock()
         self.rewrite_instr_cap = rewrite_instr_cap
         # multi-chip: a 1-D jax.sharding.Mesh shards the edge tables and
         # runs the SPMD kernel (keto_tpu/parallel); None = single device
@@ -2587,17 +2602,12 @@ class TPUCheckEngine:
         # the chip the same 2,048 items read 103.5 ms of device time at
         # F=16384 and 50.2 ms at F=8192 over 20 steps, 14.4 and 7.1 ms
         # over 2 (PERF.md section 6, PR 34). So F follows the bucket, and
-        # the bucket the batch (_BUCKETS); queries whose exploration
-        # outgrows it are flagged needs_host and replayed exactly — a
-        # safe (slower) path.
-        if self.auto_frontier:
-            # 4x headroom over the seed tasks, at every rung: a 16-item
-            # launch at F=64 reads 1.6 ms of device time in 7.2 ms of
-            # wall on the chip (chip_smoke.py, PR 34) — small-batch serve
-            # latency is the launch's fixed cost, not its frontier
-            launch_cap = min(self.frontier_cap, max(4 * B, 64))
-        else:
-            launch_cap = self.frontier_cap
+        # the bucket the batch (_BUCKETS); on one device F also follows
+        # the demand the bucket's launches reported, and the queries that
+        # overflow a narrowed F go to a second launch at the widest rung
+        # at resolve. What outgrows that is flagged needs_host and
+        # replayed exactly — a safe (slower) path.
+        launch_cap, frontier_top = self._launch_frontier(B)
 
         # islands: one ctx block of K leaves per instance; cap scales with
         # the batch so island-heavy workloads don't immediately overflow
@@ -2637,19 +2647,57 @@ class TPUCheckEngine:
                 # single-buffer I/O: ONE host->device upload (the packed
                 # query array) and ONE device->host readback at resolve,
                 # in place of seven uploads and five readbacks that each
-                # pay a transfer's fixed cost.
+                # pay a transfer's fixed cost. A frontier retry launches
+                # the same array again.
+                meta["qpack"] = pack_queries(*queries)
+                meta["cfg"] = cfg
                 outputs = check_kernel_packed(
-                    state.tables, pack_queries(*queries), **cfg
+                    state.tables, meta["qpack"], **cfg
                 )
         # everything past the launch is deferred to resolve: touching the
         # outputs here would block on the device round-trip
         meta.update(
             island_cap=island_cap if self.mesh is None else None,
             launch_cap=launch_cap,
+            frontier_top=frontier_top,
             step_cap=int(cfg["max_steps"]),
             gather_step_bytes=n_shards * estimate_step_gather_bytes(cfg),
         )
         return self._launched("batch", outputs, meta, asm, dsp)
+
+    def _launch_frontier(self, B: int) -> tuple[int, int]:
+        """(F, the widest rung) of a check launch of bucket B.
+
+        auto_frontier=False launches at `frontier_cap`. The mesh path
+        keeps four slots a query slot: its sharded candidates are cut
+        shard by shard, so no launch reports one demand, and no cell
+        measures it. On one device F is the narrowest rung that held the
+        largest demand of the bucket's recent launches, two slots a query
+        slot before any."""
+        if not self.auto_frontier:
+            return self.frontier_cap, self.frontier_cap
+        # 4x headroom over the seed tasks at the widest: a 16-item launch
+        # at F=64 reads 1.6 ms of device time in 7.2 ms of wall on a v5e
+        # (chip_smoke.py) — small-batch serve latency is the launch's
+        # fixed cost, not its frontier
+        top = min(self.frontier_cap, max(4 * B, 64))
+        if self.mesh is not None:
+            return top, top
+        with self._demand_mu:
+            seen = self._demand.get(B)
+            demand = max(seen) if seen else 2 * B
+        rungs = sorted(min(top, max(B, 64, r * B)) for r in _FRONTIER_RUNGS)
+        return next((F for F in rungs if F >= demand), top), top
+
+    def _note_demand(self, B: int, demand: int) -> None:
+        """One launch of bucket B needed a frontier of `demand`."""
+        with self._demand_mu:
+            seen = self._demand.get(B)
+            if seen is None:
+                seen = self._demand[B] = collections.deque(
+                    maxlen=_DEMAND_HISTORY
+                )
+            seen.append(demand)
 
     def _launched(self, kind: str, outputs, meta: dict, asm, dsp):
         """The in-flight handle of a launch just dispatched. The stage
@@ -2909,13 +2957,25 @@ class TPUCheckEngine:
                 n_isl = int(n_isl)
                 # ketolint: allow[host-sync] reason=part of the same designated resolve sync point: the mesh path's replicated stats vector reads back with the batch results, not as an extra round-trip
                 stats = np.asarray(stats)
-            if _faults.get("batch_corrupt") is not None:
-                # fault-injection point: poison every slot's device verdict
-                # so each query takes the exact-host-replay escape hatch the
-                # capacity overflows use — answers must stay byte-correct
-                _faults.inject("batch_corrupt")
-                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                needs_host = np.maximum(np.asarray(needs_host), 1)
+        retry = None
+        if meta.get("island_cap") is not None:
+            demand = int(stats[STAT_DEMAND_MAX])
+            self._note_demand(B, demand)
+            # a demand within F is exactly no frontier overflow: only a
+            # launch that overflowed a rung below the widest looks further
+            F = meta["launch_cap"]
+            if F < demand and F < meta["frontier_top"]:
+                overflowed = needs_host == CAUSE_FRONTIER_OVERFLOW
+                if overflowed.any():
+                    retry = self._frontier_retry(meta, overflowed)
+                    needs_host = np.where(overflowed, retry[1], needs_host)
+        if _faults.get("batch_corrupt") is not None:
+            # fault-injection point: poison every slot's device verdict
+            # so each query takes the exact-host-replay escape hatch the
+            # capacity overflows use — answers must stay byte-correct
+            _faults.inject("batch_corrupt")
+            # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+            needs_host = np.maximum(np.asarray(needs_host), 1)
         meta["n_isl"] = n_isl
         islands = None
         if n_isl:
@@ -2931,6 +2991,9 @@ class TPUCheckEngine:
                 )
         else:
             member = ctx_hit[:B]
+        if retry is not None:
+            # the retried queries' verdicts stand for the first launch's
+            member[overflowed] = retry[0][overflowed]
 
         sink = meta.get("explain_sink")
         telemetry = meta.get("telemetry")
@@ -2984,11 +3047,62 @@ class TPUCheckEngine:
                     self.metrics.island_instances_total.inc(n_isl)
                 for cause, cnt in host_causes.items():
                     self.metrics.host_fallback_total.labels(cause).inc(cnt)
+                if meta.get("island_cap") is not None:
+                    self.metrics.observe_frontier(
+                        B, meta["launch_cap"], demand
+                    )
         self._finish_check_stages(
             meta, waited, resolved, host_s, n, B,
             stats=stats, host_causes=host_causes, islands=islands,
+            retry=None if retry is None else retry[2],
         )
         return results, versions
+
+    def _frontier_retry(self, meta, overflowed):
+        """Launch the queries that overflowed a narrowed frontier again,
+        at the bucket's widest rung, and read them back: the same packed
+        query array, valid only where `overflowed`, through the program
+        a launch of this bucket at that rung runs anyway. Any query that
+        one launch at the widest rung keeps on the device stays there:
+        the retried subset asks for no more frontier than the whole batch
+        did. Returns (verdicts[B], causes[B], the frontier_retry span)."""
+        from .kernel import check_kernel_packed, unpack_results
+
+        state = meta["state"]
+        B, top = meta["B"], meta["frontier_top"]
+        qpack = meta["qpack"]
+        # the first launch has been read back, so its upload is spent
+        qpack[6] = overflowed
+        with StageSpan("frontier_retry", meta.get("launch_id")) as span:
+            outputs = check_kernel_packed(
+                state.tables, qpack, **{**meta["cfg"], "frontier_cap": top}
+            )
+            ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = (
+                unpack_results(
+                    # ketolint: allow[host-sync] reason=the frontier retry's designated sync point: a second launch of the queries the first one could not hold, read back in one transfer before the batch's answers can be formed
+                    np.asarray(outputs), B, meta["island_cap"],
+                    state.snapshot.K,
+                )
+            )
+            if n_isl:
+                from .islands import combine_islands
+
+                member = combine_islands(
+                    ctx_hit.copy(), isl_parent, isl_pid, n_isl,
+                    state.snapshot.island_circuits, B, state.snapshot.K,
+                )
+            else:
+                member = ctx_hit[:B]
+        n_retried = int(overflowed.sum())
+        meta["frontier_retried"] = n_retried
+        # the bucket's next launches go straight to the widest rung
+        self._note_demand(B, top)
+        if self.metrics is not None:
+            self.metrics.frontier_retry_total.inc(n_retried)
+            self.metrics.observe_frontier(B, top, int(stats[STAT_DEMAND_MAX]))
+            if n_isl:
+                self.metrics.island_instances_total.inc(n_isl)
+        return member, needs_host, span
 
     def _resolve_items(self, meta, member, needs_host, host_causes: dict):
         """The per-item bookkeeping loop of a batch that did NOT wholly
@@ -3066,20 +3180,23 @@ class TPUCheckEngine:
 
     def _finish_check_stages(
         self, meta, waited, resolved, host_s: float, n: int, B: int,
-        stats=None, host_causes=None, islands=None,
+        stats=None, host_causes=None, islands=None, retry=None,
     ) -> None:
         """Finalize one batch's stage attribution: per-stage histogram
         samples (once per batch), each rider's RequestTrace stages
         (+ launch id), the flight-recorder entry, and per-request engine
-        spans when tracing. `waited`, `islands` (None where the launch
-        allocated no island) and `resolved` are the batch's device_wait,
-        islands and resolve StageSpans; `resolve` is what followed the
-        combine less the `host_s` seconds of host replay inside it.
+        spans when tracing. `waited`, `retry` and `islands` (None where
+        the launch needed no frontier retry or allocated no island) and
+        `resolved` are the batch's device_wait, frontier_retry, islands
+        and resolve StageSpans; `resolve` is what followed the combine
+        less the `host_s` seconds of host replay inside it.
         Batch-shared stages are attributed identically to every rider —
         the breakdown says where the BATCH spent its time, which is what
         a tail-latency investigation needs."""
         stage_s = dict(meta.get("stage_s") or ())
         stage_s["device_wait"] = waited.seconds
+        if retry is not None:
+            stage_s["frontier_retry"] = retry.seconds
         if islands is not None:
             stage_s["islands"] = islands.seconds
         stage_s["resolve"] = max(0.0, resolved.seconds - host_s)
@@ -3162,6 +3279,8 @@ class TPUCheckEngine:
             entry["closure_resolved"] = meta["closure_resolved"]
         if "n_isl" in meta:
             entry["n_isl"] = meta["n_isl"]
+        if "frontier_retried" in meta:
+            entry["frontier_retried"] = meta["frontier_retried"]
         if t_submit is not None:
             entry["wall_ms"] = round(
                 (time.perf_counter() - t_submit) * 1e3, 3

@@ -58,6 +58,9 @@ CHECK_STAGES = (
     "assemble",       # state refresh + batch encoding + bucket padding
     "dispatch",       # device launch (H2D upload + async kernel dispatch)
     "device_wait",    # block-until-ready + readback + unpack
+    "frontier_retry", # the queries that overflowed a narrowed frontier,
+                      # launched again at the bucket's widest and read
+                      # back (launches that needed it only)
     "islands",        # the AND/NOT island circuits over the leaf bits
                       # (launches that allocated an island only)
     "resolve",        # verdicts -> CheckResults + counters after the
@@ -78,6 +81,12 @@ DEVICE_FEED_STATES = (
     "starved_dispatch",    # empty while the launch was being dispatched
 )
 
+
+# the edges of the launch-geometry histograms: the launch ladder
+# (engine/tpu_engine.py _BUCKETS) and the two frontier rungs above it. A
+# launch's frontier is one, two or four times its bucket, so a peak's
+# bucket edge says how far from the frontier it sat
+_LADDER_EDGES = tuple(1 << k for k in range(4, 17))
 
 # the phases of a mirror rebuild, in order
 # (keto_tpu_mirror_build_seconds{phase}; `keto.mirror.<phase>` spans)
@@ -496,7 +505,8 @@ class Metrics:
             "keto_tpu_check_stage_duration_seconds",
             "Check serving time per pipeline stage (transport | cache | "
             "decode | queue | assemble | dispatch | device_wait | "
-            "islands | resolve | host_fallback | respond); batch-level stages "
+            "frontier_retry | islands | resolve | host_fallback | respond); "
+            "batch-level stages "
             "observe once per device batch; `cache` observes per cache "
             "hit (hit requests record no assemble/dispatch/device_wait "
             "time); decode/respond observe once per BatchCheck; "
@@ -754,11 +764,36 @@ class Metrics:
             "launch (capacity is the launch frontier_cap; peaks at cap "
             "mean frontier-overflow host replays are near)",
             registry=self.registry,
-            # the launch ladder's edges (engine/tpu_engine.py _BUCKETS)
-            # and the two frontier rungs above it: a launch's cap is four
-            # times its bucket, so a peak's bucket edge says how far from
-            # the cap it sat
-            buckets=tuple(1 << k for k in range(4, 17)),
+            buckets=_LADDER_EDGES,
+        )
+        self.launch_demand_peak = prom.Histogram(
+            "keto_tpu_launch_demand_peak",
+            "Largest candidate total of one BFS step, before the frontier "
+            "cut, per single-device check launch (engine/kernel.py "
+            "STAT_DEMAND_MAX): the frontier the launch needed. Above the "
+            "launch's frontier means its frontier overflowed",
+            registry=self.registry,
+            buckets=_LADDER_EDGES,
+        )
+        self.launch_frontier_slots_total = prom.Counter(
+            "keto_tpu_launch_frontier_slots_total",
+            "Frontier slots (F) of the single-device check launches, "
+            "summed; over keto_tpu_launch_bucket_slots_total, the "
+            "frontier slots a launch paid for each query slot",
+            registry=self.registry,
+        )
+        self.launch_bucket_slots_total = prom.Counter(
+            "keto_tpu_launch_bucket_slots_total",
+            "Query slots (the bucket B) of the single-device check "
+            "launches, summed",
+            registry=self.registry,
+        )
+        self.frontier_retry_total = prom.Counter(
+            "keto_tpu_frontier_retry_total",
+            "Queries launched again on the device at their bucket's widest "
+            "frontier after they overflowed the narrower one their launch "
+            "ran at (what overflows that too is replayed on the host)",
+            registry=self.registry,
         )
         self.launch_gather_bytes = prom.Histogram(
             "keto_tpu_launch_gather_bytes",
@@ -1329,6 +1364,13 @@ class Metrics:
         self.launch_gather_bytes.observe(gather_bytes)
         self.launch_edge_rows.observe(edge_rows)
         self.launch_padding_waste.observe(padding_waste)
+
+    def observe_frontier(self, bucket: int, frontier: int, demand: int) -> None:
+        """One single-device check launch's geometry: its query slots, its
+        frontier slots, and the frontier its steps asked for."""
+        self.launch_bucket_slots_total.inc(bucket)
+        self.launch_frontier_slots_total.inc(frontier)
+        self.launch_demand_peak.observe(demand)
 
     def observe_stage(
         self, stage: str, seconds: float, trace_id: Optional[str] = None

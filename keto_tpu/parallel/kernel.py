@@ -78,8 +78,13 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             q = st.t_q
             ctx = st.t_ctx
             root_done = st.ctx_hit[:B] | (st.needs_host > 0)
-            live = (idx < st.n_tasks) & ~root_done[q] & ~st.ctx_hit[ctx]
             obj, rel, depth = st.t_obj, st.t_rel, st.t_depth
+            # an invalid query's seed task (depth -1) is inert: it neither
+            # probes nor expands, computed hops and islands included
+            live = (
+                (idx < st.n_tasks) & (depth >= 0)
+                & ~root_done[q] & ~st.ctx_hit[ctx]
+            )
 
             # flags depend only on replicated tables: identical everywhere
             prog = program_lookup(
@@ -106,7 +111,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             # the REPLICATED frontier + program tables, so every shard
             # derives the identical island table and leaf-ctx assignment
             # with no collective
-            children, overflow_q, isl_state = expand_phase(
+            children, overflow_q, isl_state, _ = expand_phase(
                 tables, q, ctx, obj, rel, depth, live,
                 (st.isl_parent, st.isl_pid, st.n_isl),
                 K=K, rh_probes=rh_probes, n_config_rels=n_config_rels,
@@ -138,7 +143,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             stats = update_launch_stats(
                 st.stats,
                 st.n_tasks,
-                (live & (depth >= 0)).sum(),
+                live.sum(),
                 hit.sum(),
                 gathered.valid.sum(),
                 n_new,

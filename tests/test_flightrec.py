@@ -71,7 +71,9 @@ def kernel_walk_oracle(namespaces, tuples, query: str, max_depth: int,
     Counter semantics mirror engine/kernel.py STAT_*: frontier_sum
     counts the padded bucket at step 1 (the seed frontier is B tasks),
     live_sum counts only genuinely-live tasks, edge_rows counts valid
-    pre-dedupe candidates, dedupe_kept the admitted survivors."""
+    pre-dedupe candidates, dedupe_kept the admitted survivors,
+    demand_max the largest candidate count of a step before the
+    wildcard-relation edges are skipped."""
     q = RelationTuple.from_string(query)
     direct: set[tuple] = set()
     rows: dict[tuple, list[tuple]] = {}
@@ -110,7 +112,7 @@ def kernel_walk_oracle(namespaces, tuples, query: str, max_depth: int,
 
     frontier = [(q.namespace, q.object, q.relation, max_depth)]
     counters = dict(steps=0, frontier_sum=0, frontier_max=0, live_sum=0,
-                    probe_hits=0, edge_rows=0, dedupe_kept=0)
+                    probe_hits=0, edge_rows=0, dedupe_kept=0, demand_max=0)
     n_tasks = bucket  # the seed frontier is the padded bucket
     resolved = False
     while counters["steps"] < step_cap and n_tasks > 0 and not resolved:
@@ -127,12 +129,15 @@ def kernel_walk_oracle(namespaces, tuples, query: str, max_depth: int,
         live = 0 if resolved else len(frontier)
         counters["live_sum"] += live
         children: list[tuple] = []
+        skipped = 0
         if not resolved:
             for (ns, obj, rel, depth) in frontier:
                 if depth >= 1:
                     for (cns, cobj, crel) in rows.get((ns, obj, rel), ()):
                         if crel != WILDCARD:
                             children.append((cns, cobj, crel, depth - 1))
+                        else:
+                            skipped += 1
                 for instr in rewrites.get((ns, rel), ()):
                     if instr[0] == "computed":
                         children.append((ns, obj, instr[1], depth))
@@ -142,6 +147,9 @@ def kernel_walk_oracle(namespaces, tuples, query: str, max_depth: int,
                         ):
                             children.append((cns, cobj, instr[2], depth - 1))
         counters["edge_rows"] += len(children)
+        counters["demand_max"] = max(
+            counters["demand_max"], len(children) + skipped
+        )
         best: dict[tuple, int] = {}
         for (cns, cobj, crel, cdepth) in children:
             key = (cns, cobj, crel)
@@ -206,7 +214,7 @@ class TestCounterDifferential:
         )
         assert entry["member"] == want["member"]
         for key in ("steps", "frontier_sum", "frontier_max", "live_sum",
-                    "probe_hits", "edge_rows", "dedupe_kept"):
+                    "probe_hits", "edge_rows", "dedupe_kept", "demand_max"):
             assert entry[key] == want[key], (
                 f"{key}: device={entry[key]} oracle={want[key]} "
                 f"(entry={entry}, oracle={want})"
@@ -257,6 +265,29 @@ class TestCounterDifferential:
         entry, _ = self._compare(CYCLE_NS, CYCLE_TUPLES, "g:y#member@alice", 8)
         assert entry["member"] is True
         assert entry["steps"] == 2  # y -> x, then x's direct probe hits
+
+    def test_demand_is_the_candidate_total_before_the_frontier_cut(self):
+        """STAT_DEMAND_MAX against a hand count: 8 queries of a group
+        with 6 nested groups ask for 48 children in their first step, and
+        a frontier of 16 holds 16 of them. The cut flags 6 of the 8 (the
+        first two have their 12 within the 16) for host replay."""
+        namespaces = [Namespace(name="g", relations=[Relation(name="member")])]
+        tuples = [
+            f"g:top{t}#member@(g:sub{t}_{s}#member)"
+            for t in range(8) for s in range(6)
+        ]
+        fr = FlightRecorder(capacity=4)
+        engine = make_engine(
+            namespaces, tuples, flightrec=fr, frontier_cap=16
+        )
+        engine.check_batch([
+            RelationTuple.from_string(f"g:top{t}#member@u") for t in range(8)
+        ])
+        (entry,) = fr.entries()
+        assert entry["frontier_cap"] == 16
+        assert entry["demand_max"] == 8 * 6
+        assert entry["edge_rows"] == 16
+        assert entry["host_causes"] == {"frontier_overflow": 6}
 
     def test_gather_bytes_scale_with_iterations(self):
         fr = FlightRecorder(capacity=16)
@@ -525,6 +556,9 @@ class TestNoAdditionalDeviceSyncs:
         "check_batch_submit": 0,
         "check_batch_resolve_v": 0,
         "_check_batch_resolve_v_inner": 7,
+        # the frontier retry: ONE packed readback of the second launch
+        # of a batch whose queries overflowed a narrowed frontier
+        "_frontier_retry": 1,
         # the closure fast path (engine/closure_kernel.py) keeps the
         # same budget shape: zero syncs at submit, ONE packed readback
         # at resolve carrying verdicts + causes + the stats vector

@@ -2,15 +2,19 @@
 
 `tpu_engine._BUCKETS` is every power of two from 16 to 16,384, so a batch
 of n items runs in the smallest power of two >= n (floor 16) and under half
-of a launch's static shape is padding; the frontier gets four slots a query
-slot (`min(frontier_cap, max(4 * B, 64))`). Held here, on the CPU, as counts:
-the shapes the engine hands `check_kernel_packed`, the verdicts against the
-reference engine, and the one thing the finer ladder can cost, a fan-out
-that overflows the smaller frontier and is replayed exactly on the host.
+of a launch's static shape is padding. The frontier gets one, two or four
+slots a query slot, between `max(B, 64)` and `min(frontier_cap, max(4 * B,
+64))`: the narrowest that held what the bucket's recent launches asked for,
+two before any. Held here, on the CPU, as counts: the shapes the engine
+hands `check_kernel_packed`, the verdicts against the reference engine, a
+fan-out that overflows the narrowed frontier and stays on the device
+through a second launch at the widest, and one that overflows that too and
+is replayed exactly on the host.
 """
 
 from __future__ import annotations
 
+import numpy as np
 import pytest
 
 from keto_tpu.config import Config
@@ -72,6 +76,11 @@ def launches_of(engine, queries, monkeypatch):
     return engine.check_batch(queries), seen
 
 
+def frontier_of(B: int, slots: int, cap: int = FRONTIER_CAP) -> int:
+    """The frontier rung of `slots` slots a query slot of bucket B."""
+    return min(cap, max(4 * B, 64), max(B, 64, slots * B))
+
+
 def expected_widths(n: int, largest: int = FRONTIER_CAP) -> list[int]:
     """Smallest power of two >= n, floor 16; more than the largest bucket
     is cut along it and each slice sized alone."""
@@ -109,9 +118,9 @@ def test_a_launch_is_sized_to_its_batch(n, engine, monkeypatch):
     before = dict(engine.stats)
     results, launches = launches_of(engine, queries, monkeypatch)
     widths = expected_widths(n)
-    assert launches == [
-        ((7, B), min(FRONTIER_CAP, max(4 * B, 64))) for B in widths
-    ]
+    # two children a query slot at most: every launch at two frontier
+    # slots a query slot, the rung it starts at and the one it keeps
+    assert launches == [((7, B), frontier_of(B, 2)) for B in widths]
     # the reference engine's verdict, asked once a distinct query
     reference = {
         str(q): engine.reference.check_is_member(q) for q in queries[:2 * FILES]
@@ -121,7 +130,8 @@ def test_a_launch_is_sized_to_its_batch(n, engine, monkeypatch):
     assert got == [i % 2 == 0 for i in range(n)]
     # two live tasks a query: nothing leaves the device while the frontier
     # has two slots a query slot; a full 16,384 bucket under the default
-    # cap has one, and what overflows is replayed (the cap's rule, as ever)
+    # cap has one, at its widest, and what overflows is replayed (the
+    # cap's rule, as ever)
     replayed = engine.stats["host_checks"] - before["host_checks"]
     assert (replayed > 0) == (2 * widths[0] > FRONTIER_CAP)
     assert engine.stats["device_checks"] + engine.stats["host_checks"] == (
@@ -141,27 +151,69 @@ def test_a_capped_engine_splits_along_its_largest_bucket(monkeypatch):
     assert all(r.allowed for r in results)
 
 
-# groups of six nested groups: a `member` check of a group has six children
-# a step, between the four frontier slots a query slot that every launch
-# gets and the eight that a half-full bucket enjoyed under a x4 ladder
-FAN_OUT, GROUPS = 6, 32
+# groups of nested groups: a `member` check of a group has FAN_OUT children
+# a step. Three lie between the two frontier slots a query slot that a
+# launch starts at and the four of the widest rung; six lie beyond both
+GROUPS = 32
 FAN_NAMESPACES = [Namespace(name="g", relations=[Relation(name="member")])]
-FAN_TUPLES = [
-    f"g:top{t}#member@(g:sub{t}_{s}#member)"
-    for t in range(GROUPS) for s in range(FAN_OUT)
-] + [f"g:sub{t}_{FAN_OUT - 1}#member@u{t}" for t in range(GROUPS)]
+
+
+def fan_engine(fan_out: int, metrics: Metrics):
+    tuples = [
+        f"g:top{t}#member@(g:sub{t}_{s}#member)"
+        for t in range(GROUPS) for s in range(fan_out)
+    ] + [f"g:sub{t}_{fan_out - 1}#member@u{t}" for t in range(GROUPS)]
+    return engine_for(FAN_NAMESPACES, tuples, metrics=metrics)
+
+
+def fan_queries():
+    return [
+        RelationTuple.from_string(f"g:top{t}#member@u{t if t % 2 else 'x'}")
+        for t in range(GROUPS)
+    ]
+
+
+def sample(metrics: Metrics, name: str) -> float:
+    return metrics.registry.get_sample_value(name) or 0.0
+
+
+def test_a_fan_out_of_three_stays_on_the_device_through_a_retry(monkeypatch):
+    metrics = Metrics()
+    engine = fan_engine(3, metrics)
+    queries = fan_queries()
+    results, launches = launches_of(engine, queries, monkeypatch)
+    # 32 queries fill a 32 bucket: 64 frontier slots for 96 children, so
+    # the queries whose children lie past slot 64 are launched again at
+    # 128, where all of theirs fit
+    assert launches == [((7, 32), 64), ((7, 32), 128)]
+    flagged = sum(1 for t in range(GROUPS) if 3 * t + 3 > 64)
+    assert sample(metrics, "keto_tpu_frontier_retry_total") == flagged == 11
+    assert engine.stats["host_checks"] == 0
+    assert engine.stats["device_checks"] == GROUPS
+    got = [r.allowed for r in results]
+    assert got == [engine.reference.check_is_member(q) for q in queries]
+    assert got == [t % 2 == 1 for t in range(GROUPS)]
+    # both launches paid for their frontier: 64 + 128 slots for 2 x 32
+    assert sample(metrics, "keto_tpu_launch_frontier_slots_total") == 192
+    assert sample(metrics, "keto_tpu_launch_bucket_slots_total") == 64
+    assert sample(metrics, "keto_tpu_launch_demand_peak_count") == 2
+    # the bucket's next launch goes straight to the widest rung
+    results, launches = launches_of(engine, queries, monkeypatch)
+    assert launches == [((7, 32), 128)]
+    assert [r.allowed for r in results] == got
+    assert sample(metrics, "keto_tpu_frontier_retry_total") == 11
+    assert engine.stats["host_checks"] == 0
 
 
 def test_a_fan_out_over_four_slots_a_query_is_replayed_exactly(monkeypatch):
     metrics = Metrics()
-    engine = engine_for(FAN_NAMESPACES, FAN_TUPLES, metrics=metrics)
-    queries = [
-        RelationTuple.from_string(f"g:top{t}#member@u{t if t % 2 else 'x'}")
-        for t in range(GROUPS)
-    ]
+    engine = fan_engine(6, metrics)
+    queries = fan_queries()
     results, launches = launches_of(engine, queries, monkeypatch)
-    # 32 queries fill a 32 bucket: 128 frontier slots for 192 children
-    assert launches == [((7, 32), 128)]
+    # 32 queries fill a 32 bucket: 64 frontier slots for 192 children,
+    # then 128 for the 132 of the 22 queries past slot 64
+    assert launches == [((7, 32), 64), ((7, 32), 128)]
+    assert sample(metrics, "keto_tpu_frontier_retry_total") == 22
     assert engine.stats["host_cause"].get("frontier_overflow", 0) > 0
     assert engine.stats["host_checks"] == sum(
         engine.stats["host_cause"].values()
@@ -172,3 +224,38 @@ def test_a_fan_out_over_four_slots_a_query_is_replayed_exactly(monkeypatch):
     assert metrics.registry.get_sample_value(
         "keto_tpu_launch_padding_waste_sum"
     ) == 0.0
+
+
+def test_an_invalid_query_slot_is_inert():
+    """A retry clears `valid` of the queries it does not launch again and
+    leaves their object and relation: such a slot neither probes nor
+    expands, computed hops included, so it asks for no frontier."""
+    engine = engine_for(NAMESPACES, TUPLES)
+    state = engine._ensure_state()
+    queries = [
+        RelationTuple.from_string(f"files:f{i}#view@u0") for i in range(16)
+    ]
+    qpack = kernel.pack_queries(
+        *engine._encode_queries(state, queries, 16, 5, False, None)
+    )
+    qpack[6] = 0
+    cfg = kernel.kernel_static_config(
+        state.snapshot, 5, 64, has_delta=state.has_delta
+    )
+    out = np.asarray(kernel.check_kernel_packed(state.tables, qpack, **cfg))
+    stats = kernel.launch_stats_dict(
+        kernel.unpack_results(out, 16, 0, state.snapshot.K)[-1]
+    )
+    assert stats["steps"] == 1
+    assert stats["live_sum"] == stats["edge_rows"] == stats["demand_max"] == 0
+
+
+def test_the_widest_rung_is_taken_without_a_retry_where_no_narrower_is():
+    """A 16 bucket's rungs all clamp to 64, and an engine with
+    auto_frontier off launches at its cap: neither ever retries."""
+    metrics = Metrics()
+    engine = fan_engine(6, metrics)
+    assert engine._launch_frontier(16) == (64, 64)
+    assert engine._launch_frontier(2048) == (4096, 8192)
+    fixed = engine_for(NAMESPACES, TUPLES, auto_frontier=False)
+    assert fixed._launch_frontier(2048) == (FRONTIER_CAP, FRONTIER_CAP)

@@ -165,17 +165,19 @@ def smoke_batch(drive):
 
 def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     """The smoke's largest check launch, its 2,048-item BatchCheck (bucket
-    2,048, frontier 8,192), with tables and working set inside one v5e's
-    16 GiB. dh_pack and rh_pack are stored as the 64-lane bucket rows the
-    kernel gathers, so no launch relays them out: `temp` holds the frontier's
-    working set and does not follow the tables' rows (ROADMAP S3)."""
+    2,048, frontier 4,096: two slots a query slot, the rung a bucket starts
+    at and the drive store's two children a query keep it at), with tables
+    and working set inside one v5e's 16 GiB. dh_pack and rh_pack are
+    stored as the 64-lane bucket rows the kernel gathers, so no launch
+    relays them out: `temp` holds the frontier's working set and does not
+    follow the tables' rows (ROADMAP S3)."""
     tables_and_queries, statics = capture_launch(
         kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
     )
     tables, qpack = tables_and_queries
     assert tables["dh_pack"].shape == (1 << 20, 64)
     assert tables["rh_pack"].shape == (1 << 19, 64)
-    assert qpack.shape == (7, 2048) and statics["frontier_cap"] == 8192
+    assert qpack.shape == (7, 2048) and statics["frontier_cap"] == 4096
     _, memory = compile_for_chip(
         "check_kernel_packed", kernel.check_kernel_packed, tables_and_queries,
         statics, lambda a: one_chip,
@@ -199,7 +201,7 @@ CHIP_SHARE_PROBES = {"dh_probes": 12, "rh_probes": 13}
 def test_check_kernel_fits_the_chip_at_the_chip_share(
     no_compile_cache, one_chip, drive, engine
 ):
-    """The same launch (bucket 2,048, frontier 8,192) over the tables of
+    """The same launch (bucket 2,048, frontier 4,096) over the tables of
     `drive-chip-share`, from their shapes alone: no store of that size is
     built here. 3.27 GB of arguments, and a working set that does not
     follow the tables' rows."""
@@ -262,13 +264,13 @@ def test_island_check_kernel_compiles_for_the_chip(
     no_compile_cache, one_chip, rewrites_engine
 ):
     """The island variant of the check launch at the cell's 2,048-item
-    BatchCheck: bucket 2,048, frontier 8,192, 4,096 island instances of
+    BatchCheck: bucket 2,048, frontier 4,096, 4,096 island instances of
     K = 2 leaves, so 10,240 ctx accumulators."""
     engine, queries = rewrites_engine
     args, statics = capture_launch(
         kernel, "check_kernel_packed", lambda: engine.check_batch(queries)
     )
-    assert args[1].shape == (7, 2048) and statics["frontier_cap"] == 8192
+    assert args[1].shape == (7, 2048) and statics["frontier_cap"] == 4096
     assert statics["n_island_cap"] == 4096 and statics["K"] == 2
     _, memory = compile_for_chip(
         "check_kernel_packed[islands]", kernel.check_kernel_packed, args,

@@ -176,7 +176,7 @@ def main() -> int:
     isl0 = (jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32), jnp.int32(0))
 
     def expand_body(o, sink):
-        ch, oq, _ = expand_phase(
+        ch, oq, _, _ = expand_phase(
             tables, q0, q0, o, rel0, depth0, live0, isl0,
             K=statics["K"], rh_probes=statics["rh_probes"],
             n_config_rels=statics["n_config_rels"],
@@ -209,7 +209,7 @@ def main() -> int:
             tables, o, rel0, skind0, sa0, sb0, depth0, live0,
             dh_probes=statics["dh_probes"], has_delta=statics["has_delta"],
         )
-        ch, oq, _ = expand_phase(
+        ch, oq, _, _ = expand_phase(
             tables, q0, q0, o, rel0, depth0, live0, isl0,
             K=statics["K"], rh_probes=statics["rh_probes"],
             n_config_rels=statics["n_config_rels"],
