@@ -343,6 +343,8 @@ class Daemon:
         from ..observability import configure_logging
 
         configure_logging(reg.config)
+        # the collector's pauses, counted from inside the serving process
+        reg.metrics().watch_collector()
         # workload observatory folder: with a daemon serving traffic,
         # event folding moves off the request threads onto this ticker
         # (observability_workload.WorkloadObservatory.start_folder)

@@ -2,8 +2,9 @@
 
 Wire-compatible with the reference's v1alpha2 services (the route strings
 and message bytes match; see protos/keto.proto). Handlers are registered
-through `grpc.method_handlers_generic_handler` against the runtime message
-classes from descriptors.py, so no generated service stubs are needed.
+through one `grpc.GenericRpcHandler` (`_EdgeHandler`) against the runtime
+message classes from descriptors.py, so no generated service stubs are
+needed; it times each unary call's edge outside the handler (`_EdgeCall`).
 
 Behavioral parity:
   - Check: `tuple` field preferred over the deprecated flat fields
@@ -35,6 +36,7 @@ import grpc
 from ..errors import KetoError
 from ..observability import (
     RequestTrace,
+    StageSpan,
     current_request_trace,
     finish_request_telemetry,
     parse_traceparent,
@@ -740,117 +742,138 @@ class _Services:
             self._watch_slots.release()
 
 
-def _unary(services: _Services, name: str, fn, req_cls):
-    def handler(request, context):
-        return services._observed(name, context, fn, request)
+class _Route:
+    """One unary method of a server: its handler behind the edge, the
+    request's parser and the method's edge histogram children."""
 
-    return grpc.unary_unary_rpc_method_handler(
-        handler,
-        request_deserializer=req_cls.FromString,
-        response_serializer=lambda m: m.SerializeToString(),
-    )
+    __slots__ = ("behavior", "from_string", "queued", "parsed", "serialized")
+
+    def __init__(self, services: _Services, method: str, fn, req_cls):
+        def behavior(request, context):
+            return services._observed(method, context, fn, request)
+
+        self.behavior = behavior
+        self.from_string = req_cls.FromString
+        self.queued, self.parsed, self.serialized = (
+            services.metrics.grpc_edge(method)
+        )
+
+
+class _EdgeCall:
+    """One unary call at the server's edge (observability.EDGE_PHASES).
+    The sync server asks the generic handler for the call's method
+    handler on its polling thread as the call arrives, before a worker
+    takes it up, so `accepted` is stamped there. The request is parsed
+    where its message lands (the polling thread) and the response
+    serialized on the worker, each a StageSpan `keto.grpc.<phase>`; the
+    parse carries the call's `queued_s` as a stat. None of it is inside
+    the handler's clock, which `transport`'s residual is taken against,
+    so none of it enters the RequestTrace."""
+
+    __slots__ = ("route", "accepted")
+
+    def __init__(self, route: _Route):
+        self.route = route
+        self.accepted = _time.perf_counter()
+
+    def parse(self, data: bytes):
+        route = self.route
+        queued = _time.perf_counter() - self.accepted
+        route.queued.observe(queued)
+        with StageSpan("grpc.parse", queued_s=queued) as span:
+            request = route.from_string(data)
+        route.parsed.observe(span.seconds)
+        return request
+
+    def serialize(self, response) -> bytes:
+        with StageSpan("grpc.serialize") as span:
+            data = response.SerializeToString()
+        self.route.serialized.observe(span.seconds)
+        return data
+
+
+class _EdgeHandler(grpc.GenericRpcHandler):
+    """A server's routes by full method path: each unary call gets a
+    method handler of its own around one _EdgeCall; streaming methods
+    are served as they are."""
+
+    def __init__(self, unary: dict, streams: dict):
+        self._unary = unary
+        self._streams = streams
+
+    def service(self, handler_call_details):
+        route = self._unary.get(handler_call_details.method)
+        if route is None:
+            return self._streams.get(handler_call_details.method)
+        call = _EdgeCall(route)
+        return grpc.unary_unary_rpc_method_handler(
+            route.behavior,
+            request_deserializer=call.parse,
+            response_serializer=call.serialize,
+        )
 
 
 def _service_handlers(services: _Services, write: bool):
-    """Generic handlers for one server. Version + Health live on both
+    """The generic handler of one server. Version + Health live on both
     (daemon.go:387-419)."""
     s = services
-    handlers = [
-        grpc.method_handlers_generic_handler(
-            VERSION_SERVICE,
-            {"GetVersion": _unary(s, "GetVersion", s.get_version, pb.GetVersionRequest)},
+    # (service, rpc) -> (method label, implementation, request class)
+    unary = {
+        (VERSION_SERVICE, "GetVersion"): (
+            "GetVersion", s.get_version, pb.GetVersionRequest,
         ),
-        grpc.method_handlers_generic_handler(
-            HEALTH_SERVICE,
-            {
-                "Check": _unary(s, "HealthCheck", s.health_check, pb.HealthCheckRequest),
-                "Watch": grpc.unary_stream_rpc_method_handler(
-                    lambda req, ctx: s.health_watch(req, ctx),
-                    request_deserializer=pb.HealthCheckRequest.FromString,
-                    response_serializer=lambda m: m.SerializeToString(),
-                ),
-            },
+        (HEALTH_SERVICE, "Check"): (
+            "HealthCheck", s.health_check, pb.HealthCheckRequest,
         ),
-    ]
+    }
+    streams = {
+        (HEALTH_SERVICE, "Watch"): grpc.unary_stream_rpc_method_handler(
+            lambda req, ctx: s.health_watch(req, ctx),
+            request_deserializer=pb.HealthCheckRequest.FromString,
+            response_serializer=lambda m: m.SerializeToString(),
+        ),
+    }
     if write:
-        handlers.append(
-            grpc.method_handlers_generic_handler(
-                WRITE_SERVICE,
-                {
-                    "TransactRelationTuples": _unary(
-                        s, "TransactRelationTuples", s.transact_relation_tuples,
-                        pb.TransactRelationTuplesRequest,
-                    ),
-                    "DeleteRelationTuples": _unary(
-                        s, "DeleteRelationTuples", s.delete_relation_tuples,
-                        pb.DeleteRelationTuplesRequest,
-                    ),
-                },
-            )
-        )
+        unary.update({
+            (WRITE_SERVICE, "TransactRelationTuples"): (
+                "TransactRelationTuples", s.transact_relation_tuples,
+                pb.TransactRelationTuplesRequest,
+            ),
+            (WRITE_SERVICE, "DeleteRelationTuples"): (
+                "DeleteRelationTuples", s.delete_relation_tuples,
+                pb.DeleteRelationTuplesRequest,
+            ),
+        })
     else:
-        handlers.extend(
-            [
-                grpc.method_handlers_generic_handler(
-                    CHECK_SERVICE,
-                    {"Check": _unary(s, "Check", s.check, pb.CheckRequest)},
-                ),
-                grpc.method_handlers_generic_handler(
-                    BATCH_CHECK_SERVICE,
-                    {
-                        "BatchCheck": _unary(
-                            s, "BatchCheck", s.batch_check,
-                            pb.BatchCheckRequest,
-                        )
-                    },
-                ),
-                grpc.method_handlers_generic_handler(
-                    EXPAND_SERVICE,
-                    {"Expand": _unary(s, "Expand", s.expand, pb.ExpandRequest)},
-                ),
-                grpc.method_handlers_generic_handler(
-                    READ_SERVICE,
-                    {
-                        "ListRelationTuples": _unary(
-                            s, "ListRelationTuples", s.list_relation_tuples,
-                            pb.ListRelationTuplesRequest,
-                        )
-                    },
-                ),
-                grpc.method_handlers_generic_handler(
-                    REVERSE_READ_SERVICE,
-                    {
-                        "ListObjects": _unary(
-                            s, "ListObjects", s.list_objects,
-                            pb.ListObjectsRequest,
-                        ),
-                        "ListSubjects": _unary(
-                            s, "ListSubjects", s.list_subjects,
-                            pb.ListSubjectsRequest,
-                        ),
-                    },
-                ),
-                grpc.method_handlers_generic_handler(
-                    FILTER_SERVICE,
-                    {
-                        "Filter": _unary(
-                            s, "Filter", s.filter, pb.FilterRequest
-                        ),
-                    },
-                ),
-                grpc.method_handlers_generic_handler(
-                    WATCH_SERVICE,
-                    {
-                        "Watch": grpc.unary_stream_rpc_method_handler(
-                            lambda req, ctx: s.watch_tuples(req, ctx),
-                            request_deserializer=pb.WatchRequest.FromString,
-                            response_serializer=lambda m: m.SerializeToString(),
-                        ),
-                    },
-                ),
-            ]
+        unary.update({
+            (CHECK_SERVICE, "Check"): ("Check", s.check, pb.CheckRequest),
+            (BATCH_CHECK_SERVICE, "BatchCheck"): (
+                "BatchCheck", s.batch_check, pb.BatchCheckRequest,
+            ),
+            (EXPAND_SERVICE, "Expand"): ("Expand", s.expand, pb.ExpandRequest),
+            (READ_SERVICE, "ListRelationTuples"): (
+                "ListRelationTuples", s.list_relation_tuples,
+                pb.ListRelationTuplesRequest,
+            ),
+            (REVERSE_READ_SERVICE, "ListObjects"): (
+                "ListObjects", s.list_objects, pb.ListObjectsRequest,
+            ),
+            (REVERSE_READ_SERVICE, "ListSubjects"): (
+                "ListSubjects", s.list_subjects, pb.ListSubjectsRequest,
+            ),
+            (FILTER_SERVICE, "Filter"): ("Filter", s.filter, pb.FilterRequest),
+        })
+        streams[(WATCH_SERVICE, "Watch")] = grpc.unary_stream_rpc_method_handler(
+            lambda req, ctx: s.watch_tuples(req, ctx),
+            request_deserializer=pb.WatchRequest.FromString,
+            response_serializer=lambda m: m.SerializeToString(),
         )
-    return handlers
+    return [
+        _EdgeHandler(
+            {f"/{svc}/{rpc}": _Route(s, *spec) for (svc, rpc), spec in unary.items()},
+            {f"/{svc}/{rpc}": h for (svc, rpc), h in streams.items()},
+        )
+    ]
 
 
 def build_grpc_server(

@@ -2709,6 +2709,11 @@ class TPUCheckEngine:
             "assemble": dsp.start - asm.start,
             "dispatch": dsp.seconds,
         }
+        # the same stretches on the thread's CPU clock
+        meta["stage_cpu_s"] = {
+            "assemble": dsp.cpu_start - asm.cpu_start,
+            "dispatch": dsp.cpu_seconds,
+        }
         # the requests this launch answers: the batcher's riders, or the
         # one request a direct batch belongs to
         meta["riders"] = [
@@ -3194,12 +3199,21 @@ class TPUCheckEngine:
         the breakdown says where the BATCH spent its time, which is what
         a tail-latency investigation needs."""
         stage_s = dict(meta.get("stage_s") or ())
-        stage_s["device_wait"] = waited.seconds
-        if retry is not None:
-            stage_s["frontier_retry"] = retry.seconds
-        if islands is not None:
-            stage_s["islands"] = islands.seconds
+        cpu_s = dict(meta.get("stage_cpu_s") or ())
+        for name, span in (
+            ("device_wait", waited), ("frontier_retry", retry),
+            ("islands", islands),
+        ):
+            if span is not None:
+                stage_s[name] = span.seconds
+                cpu_s[name] = span.cpu_seconds
         stage_s["resolve"] = max(0.0, resolved.seconds - host_s)
+        # host replay's share of the resolve span's CPU goes with it, in
+        # proportion to its wall: the replay is timed an item, the CPU
+        # clock only a launch
+        cpu_s["resolve"] = resolved.cpu_seconds * (
+            stage_s["resolve"] / resolved.seconds if host_s > 0.0 else 1.0
+        )
         if host_s > 0.0:
             stage_s["host_fallback"] = host_s
         riders = meta["riders"]
@@ -3211,6 +3225,8 @@ class TPUCheckEngine:
             exemplar_tid = riders[0].ctx.trace_id if riders else None
             for name, dur in stage_s.items():
                 self.metrics.observe_stage(name, dur, trace_id=exemplar_tid)
+            for name, cpu in cpu_s.items():
+                self.metrics.observe_stage_cpu(name, cpu)
         self._record_launch(meta, stats, n, B, host_causes, stage_s, riders)
         spans = getattr(self.tracer, "active", False)
         launch_id = meta.get("launch_id")

@@ -93,22 +93,35 @@ _LADDER_EDGES = tuple(1 << k for k in range(4, 17))
 MIRROR_BUILD_PHASES = ("build", "pack", "upload")
 
 
+# the phases of a unary gRPC call at the server's edge, outside the
+# handler (keto_tpu_grpc_edge_seconds{method, phase}; api/grpc_server.py)
+EDGE_PHASES = (
+    "queued",     # accepted -> parse starts: a free worker, then the bytes
+    "parse",      # request bytes -> message (`keto.grpc.parse`)
+    "serialize",  # response message -> bytes (`keto.grpc.serialize`)
+)
+
+
 class StageSpan:
     """One stage of the served check path, on two clocks at once: a
     `jax.profiler.TraceAnnotation` named `keto.<stage>` (the profiler's
     clock: while a trace is being taken the span lands in the host plane
     of the same .xplane.pb as the device's `XLA Ops`, which is what lets
     an idle gap of the device be put down to a host span; `launch_id`
-    rides it as a stat) and `seconds` of `time.perf_counter()` between
-    `start` and the exit (the clock the stage histograms use). Outside a
-    trace the annotation costs under a microsecond. Per RPC and per
-    launch only, never per item."""
+    and any other `stats` ride it) and `seconds` of `time.perf_counter()`
+    between `start` and the exit (the clock the stage histograms use).
+    Beside the wall, `cpu_seconds` of `time.thread_time()` over the same
+    stretch: what the span's thread spent on a core. Wall minus CPU is the
+    time the thread was off the CPU: waiting for the interpreter, a lock
+    or the device, or a core taken by another process. Outside a trace
+    the annotation costs under a microsecond. Per RPC and per launch
+    only, never per item."""
 
-    __slots__ = ("start", "seconds", "_annotation")
+    __slots__ = ("start", "seconds", "cpu_start", "cpu_seconds", "_annotation")
 
-    def __init__(self, name: str, launch_id: Optional[int] = None):
-        self.start = 0.0
-        self.seconds = 0.0
+    def __init__(self, name: str, launch_id: Optional[int] = None, **stats):
+        self.start = self.seconds = 0.0
+        self.cpu_start = self.cpu_seconds = 0.0
         # a process that never imported JAX cannot be taking a JAX
         # trace: host-only processes (CLI, check.engine=host) pay no
         # import for a span nobody could read
@@ -116,7 +129,8 @@ class StageSpan:
         if jax is None:
             self._annotation = contextlib.nullcontext()
         else:
-            stats = {} if launch_id is None else {"launch_id": launch_id}
+            if launch_id is not None:
+                stats["launch_id"] = launch_id
             self._annotation = jax.profiler.TraceAnnotation(
                 f"keto.{name}", **stats
             )
@@ -124,11 +138,81 @@ class StageSpan:
     def __enter__(self) -> "StageSpan":
         self._annotation.__enter__()
         self.start = time.perf_counter()
+        self.cpu_start = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> None:
+        self.cpu_seconds = time.thread_time() - self.cpu_start
         self.seconds = time.perf_counter() - self.start
         self._annotation.__exit__(*exc)
+
+
+class CollectorWatch:
+    """The interpreter's collector, timed from inside: one `gc.callbacks`
+    entry per process, installed once whatever number of daemons asks
+    (`install`), feeding every `Metrics` that called `watch_collector`
+    (each once; held weakly). A collection holds the interpreter on the
+    thread that runs it, so its wall is a pause of every serving thread.
+    Each collection adds to `keto_tpu_gc_collections_total{generation}`
+    and its wall to `keto_tpu_gc_pause_seconds_total{generation}`; a full
+    one (generation 2) is also a `keto.gc` annotation on the profiler's
+    clock, entered at `start` and left at `stop` on the collecting
+    thread. Generations 0 and 1 come a few times an RPC and are not
+    annotated."""
+
+    _installed: Optional["CollectorWatch"] = None
+    _lock = threading.Lock()
+
+    def __init__(self):
+        # replaced whole under _lock, never changed in place: the
+        # callback iterates it while another thread may be adding
+        self._refs: tuple = ()
+        self._started = 0.0
+        self._annotation = None
+
+    @classmethod
+    def install(cls) -> "CollectorWatch":
+        import gc
+
+        with cls._lock:
+            if cls._installed is None:
+                cls._installed = cls()
+                gc.callbacks.append(cls._installed)
+            return cls._installed
+
+    def watch(self, metrics: "Metrics") -> None:
+        import weakref
+
+        with self._lock:
+            others = tuple(
+                ref for ref in self._refs if ref() not in (None, metrics)
+            )
+            self._refs = (*others, weakref.ref(metrics))
+
+    def sinks(self) -> list:
+        """The live registries fed."""
+        return [m for m in (ref() for ref in self._refs) if m is not None]
+
+    def __call__(self, phase: str, info: dict) -> None:
+        # collections never overlap (the interpreter runs one at a time),
+        # so one start stamp serves every thread
+        if phase == "start":
+            if info["generation"] == 2:
+                jax = sys.modules.get("jax")
+                if jax is not None:
+                    self._annotation = jax.profiler.TraceAnnotation("keto.gc")
+                    self._annotation.__enter__()
+            self._started = time.perf_counter()
+            return
+        seconds = time.perf_counter() - self._started
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        generation = info["generation"]
+        for ref in self._refs:
+            metrics = ref()
+            if metrics is not None:
+                metrics.observe_collection(generation, seconds)
 
 
 # -- W3C trace context --------------------------------------------------------
@@ -517,6 +601,53 @@ class Metrics:
                 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                 0.025, 0.05, 0.1, 0.25, 1.0,
             ),
+        )
+        self.check_stage_cpu_seconds = prom.Summary(
+            "keto_tpu_check_stage_cpu_seconds",
+            "CPU seconds (time.thread_time of the stage's own thread) "
+            "beside each wall sample of "
+            "keto_tpu_check_stage_duration_seconds that a StageSpan "
+            "timed: decode, assemble, dispatch, device_wait, "
+            "frontier_retry, islands, resolve, respond. The stage's wall "
+            "less this is time its thread spent off the CPU, waiting for "
+            "the interpreter, a lock or the device",
+            ["stage"],
+            registry=self.registry,
+        )
+        self.grpc_edge_seconds = prom.Histogram(
+            "keto_tpu_grpc_edge_seconds",
+            "Unary gRPC calls at the server's edge, outside the handler "
+            "(api/grpc_server.py): `queued` from the call's acceptance to "
+            "the start of its parse (the wait for a free worker, then for "
+            "the message's bytes), `parse` the request's FromString, "
+            "`serialize` the response's SerializeToString. None of it is "
+            "inside keto_tpu_request_duration_seconds",
+            ["method", "phase"],
+            registry=self.registry,
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 1.0,
+            ),
+        )
+        self.gc_pause_seconds = prom.Counter(
+            "keto_tpu_gc_pause_seconds_total",
+            "Wall seconds the interpreter's collector ran, by generation "
+            "(CollectorWatch: one gc.callbacks entry a process); every "
+            "thread of the process is paused meanwhile",
+            ["generation"],
+            registry=self.registry,
+        )
+        self.gc_collections = prom.Counter(
+            "keto_tpu_gc_collections_total",
+            "Collections the interpreter's collector ran, by generation",
+            ["generation"],
+            registry=self.registry,
+        )
+        # by generation, declared from the start (CollectorWatch feeds
+        # them once `watch_collector` is called)
+        self._gc_children = tuple(
+            (self.gc_collections.labels(str(g)), self.gc_pause_seconds.labels(str(g)))
+            for g in range(3)
         )
         self.batcher_queue_depth = prom.Gauge(
             "keto_tpu_batcher_queue_depth",
@@ -1304,6 +1435,7 @@ class Metrics:
         # stage -> histogram child (stage names are the CHECK_STAGES
         # constants, so this cache is bounded by construction)
         self._stage_cache: dict = {}
+        self._stage_cpu_cache: dict = {}
         # tier -> histogram child (tier names are the TIERS constants
         # of observability_workload.py — bounded by construction)
         self._tier_cache: dict = {}
@@ -1393,21 +1525,48 @@ class Metrics:
         else:
             child.observe(seconds)
 
+    def observe_stage_cpu(self, stage: str, seconds: float) -> None:
+        """The CPU seconds beside one observe_stage sample."""
+        child = self._stage_cpu_cache.get(stage)
+        if child is None:
+            child = self._stage_cpu_cache[stage] = (
+                self.check_stage_cpu_seconds.labels(stage)
+            )
+        child.observe(seconds)
+
     @contextlib.contextmanager
     def stage(self, name: str, rt: Optional[RequestTrace] = None):
         """Time one handler stage as a StageSpan and, when the body ends
         without raising, feed the SAME elapsed seconds to the stage
-        histogram and to the request's breakdown. A body that raises (a
-        shed or malformed request) never rode the pipeline and records
-        nothing."""
+        histogram and to the request's breakdown, and its CPU seconds to
+        the stage's CPU summary. A body that raises (a shed or malformed
+        request) never rode the pipeline and records nothing."""
         with StageSpan(name) as span:
             yield span
         self.observe_stage(
             name, span.seconds,
             trace_id=rt.ctx.trace_id if rt is not None else None,
         )
+        self.observe_stage_cpu(name, span.cpu_seconds)
         if rt is not None:
             rt.add_stage(name, span.seconds)
+
+    def grpc_edge(self, method: str) -> tuple:
+        """The histogram children of one gRPC method's EDGE_PHASES, in
+        that order (resolved once a route, when a server is built)."""
+        return tuple(
+            self.grpc_edge_seconds.labels(method, phase) for phase in EDGE_PHASES
+        )
+
+    def watch_collector(self) -> None:
+        """Count the process's collections here from now on (idempotent:
+        one callback a process, one feed a Metrics)."""
+        CollectorWatch.install().watch(self)
+
+    def observe_collection(self, generation: int, seconds: float) -> None:
+        collections, pause = self._gc_children[generation]
+        collections.inc()
+        pause.inc(seconds)
 
     def observe_tier(
         self, tier: str, seconds: float, trace_id: Optional[str] = None

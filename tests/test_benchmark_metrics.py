@@ -17,6 +17,7 @@ import pytest
 from keto_tpu.observability import (
     CHECK_STAGES,
     DEVICE_FEED_STATES,
+    EDGE_PHASES,
     MIRROR_BUILD_PHASES,
     Metrics,
 )
@@ -29,6 +30,8 @@ BENCH = os.path.join(ROOT, "benchmarks")
 # appears with its first sample: the program's own vocabularies
 LABEL_VALUES = {
     ("keto_tpu_check_stage_duration_seconds", "stage"): set(CHECK_STAGES),
+    ("keto_tpu_check_stage_cpu_seconds", "stage"): set(CHECK_STAGES),
+    ("keto_tpu_grpc_edge_seconds", "phase"): set(EDGE_PHASES),
     ("keto_tpu_device_feed_seconds", "state"): set(DEVICE_FEED_STATES),
     ("keto_tpu_workload_fold_seconds", "where"): set(FOLD_WHERE),
     ("keto_tpu_mirror_build_seconds", "phase"): set(MIRROR_BUILD_PHASES),

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Metrics-golden check: every Counter/Gauge/Histogram registered in
+"""Metrics-golden check: every Counter/Gauge/Histogram/Summary registered in
 keto_tpu/observability.py must appear in the docs metrics table
 (docs/architecture.md §5d). Run by the CI test job and by
 tests/test_observability.py, so a new metric cannot land undocumented —
@@ -29,7 +29,7 @@ DOCS = REPO / "docs" / "architecture.md"
 
 # prom.Counter( \n "metric_name"  — the registration shape used in
 # observability.Metrics (name is always the first string literal)
-_REGISTRATION = r"prom\.(?:Counter|Gauge|Histogram)\(\s*\"(keto_tpu_[a-z0-9_]+)\""
+_REGISTRATION = r"prom\.(?:Counter|Gauge|Histogram|Summary)\(\s*\"(keto_tpu_[a-z0-9_]+)\""
 # docs table rows cite metrics as `keto_tpu_...` code spans
 _DOCUMENTED = r"`(keto_tpu_[a-z0-9_]+)`"
 
