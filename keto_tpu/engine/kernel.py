@@ -76,11 +76,10 @@ _GOLDEN = jnp.uint32(0x9E3779B9)
 
 # Host-replay cause codes, priority-ordered (VERDICT r2 item 7: a host
 # fallback because of an AND/NOT cap must be distinguishable from one
-# because of an error). Each flag site scatter-maxes its code into the
-# per-query needs_host array — the SAME single scatter per site as the
-# old boolean scheme, so observability costs no extra device work. A
-# query flagged for several reasons reports the highest code (more
-# specific/semantic causes outrank capacity ones).
+# because of an error). A step folds its codes per task and scatter-maxes
+# them into the per-query needs_host array only where one is raised
+# (raise_causes). A query flagged for several reasons reports the highest
+# code (more specific/semantic causes outrank capacity ones).
 CAUSE_NONE = 0
 CAUSE_STEP_EXHAUSTED = 1  # step budget ran out with live tasks
 CAUSE_FRONTIER_OVERFLOW = 2  # expansion truncated / dedupe survivors > F
@@ -113,7 +112,7 @@ CAUSE_NAME_UNINDEXED = "unindexed"
 # the flight recorder (observability.FlightRecorder) and the bench
 # summaries can treat every launch kind uniformly; kernels that have no
 # value for a slot leave it zero.
-N_LAUNCH_STATS = 8
+N_LAUNCH_STATS = 9
 STAT_STEPS = 0          # loop iterations actually executed (vs the cap)
 STAT_FRONTIER_SUM = 1   # sum of n_tasks over executed steps (task-steps)
 STAT_FRONTIER_MAX = 2   # max n_tasks over executed steps
@@ -127,10 +126,12 @@ STAT_DEMAND_MAX = 7     # max pre-truncation candidate total of a step
                         # (check only): the frontier a launch needed. On
                         # one device the candidates are cut at F, so
                         # demand <= F is exactly "no frontier overflow"
+STAT_FLAG_STEPS = 8     # steps that scattered a host-replay cause (check
+                        # only): how often raise_causes' gate opened
 
 STAT_NAMES = (
     "steps", "frontier_sum", "frontier_max", "live_sum",
-    "probe_hits", "edge_rows", "dedupe_kept", "demand_max",
+    "probe_hits", "edge_rows", "dedupe_kept", "demand_max", "flag_steps",
 )
 
 
@@ -146,6 +147,7 @@ def update_launch_stats(
     n_children: jnp.ndarray,
     n_kept: jnp.ndarray,
     demand=0,
+    flag_step=False,
 ) -> jnp.ndarray:
     """One step's counter accumulation (shared by the single-device and
     sharded check kernels so both report identical semantics). All
@@ -161,6 +163,7 @@ def update_launch_stats(
         n_children.astype(jnp.int32),
         n_kept.astype(jnp.int32),
         jnp.int32(0),
+        jnp.asarray(flag_step, jnp.int32),
     ])
     return (
         (stats + inc)
@@ -598,6 +601,27 @@ def program_lookup(tables, obj, rel, live, *, n_config_rels: int):
     return ns, has_prog, pid, flags
 
 
+def raise_causes(needs_host, q, code):
+    """`needs_host.at[q].max(code)`, scattered only in a step that raises a
+    code: the codes are all zero in nearly every step of every launch, and
+    a scatter into [B] costs about as much as one of the BFS's own. The
+    skipped branch returns `needs_host` as it is, which is what a
+    scatter-max of zeros gives."""
+    return jax.lax.cond(
+        jnp.any(code > 0),
+        lambda n, q, c: n.at[q].max(c),
+        lambda n, q, c: n,
+        needs_host, q, code,
+    )
+
+
+def cause_raised(flagged, overflow_q):
+    """Whether either of a step's gated cause scatters ran (STAT_FLAG_STEPS):
+    any task flagged, or any query raised by expand_phase. From replicated
+    values on a mesh (the sharded kernel passes its pmax-merged overflow)."""
+    return jnp.any(flagged > 0) | jnp.any(overflow_q > 0)
+
+
 @jax.named_scope("keto.flag")
 def flag_phase(
     tables, obj, rel, live, *, n_config_rels: int, island_is_host: bool = False,
@@ -694,8 +718,10 @@ def expand_phase(
     Returns (candidates, per-query host flags, island updates, demand):
     candidates beyond the frontier capacity are truncated and their
     owning queries flagged for host replay; delta-dirty rows and island-
-    table overflow flag their queries too. `demand` is the candidate
-    total before the cut (STAT_DEMAND_MAX).
+    table overflow flag their queries too. The three causes fold into one
+    code a task, scattered into [n_queries] once and only where one is
+    raised (raise_causes). `demand` is the candidate total before the cut
+    (STAT_DEMAND_MAX).
     """
     F = q.shape[0]
     S = K + 1  # expansion slots per task: CSR row + K instructions
@@ -739,9 +765,8 @@ def expand_phase(
         axis=1,
     )  # [F, S]
 
-    # per-query host-replay cause codes raised by this phase (int32;
-    # scatter-max per flag site — same scatter count as the old booleans)
-    overflow_q = jnp.zeros(n_queries, dtype=jnp.int32)
+    # the host-replay cause code each task raises in this phase (int32)
+    task_code = jnp.zeros(F, dtype=jnp.int32)
 
     # delta-dirty rows (stale CSR contents): slot-0 expansion or TTU rows
     if has_delta:
@@ -752,9 +777,7 @@ def expand_phase(
         dirty = (can_expand & row_dirty[:, 0]) | jnp.any(
             is_ttu & row_dirty[:, 1:], axis=1
         )
-        overflow_q = overflow_q.at[q].max(
-            jnp.where(dirty, CAUSE_DIRTY, 0).astype(jnp.int32)
-        )
+        task_code = jnp.where(dirty, CAUSE_DIRTY, task_code)
 
     # island allocation: one instance per live task whose program has
     # AND/NOT; its instruction slots seed leaf ctxs B + idx*K + (k-1)
@@ -766,10 +789,9 @@ def expand_phase(
         idx = n_isl + rank
         isl_ok = is_island & (idx < NI)
         # island-table overflow: exact host replay for those queries
-        overflow_q = overflow_q.at[q].max(
-            jnp.where(is_island & (idx >= NI), CAUSE_ISLAND_OVERFLOW, 0).astype(
-                jnp.int32
-            )
+        task_code = jnp.maximum(
+            task_code,
+            jnp.where(is_island & (idx >= NI), CAUSE_ISLAND_OVERFLOW, 0),
         )
         dest = jnp.where(isl_ok, idx, NI)
         isl_parent = isl_parent.at[dest].set(ctx, mode="drop")
@@ -816,13 +838,16 @@ def expand_phase(
     offsets = jnp.cumsum(flat_counts) - flat_counts  # exclusive scan
     total = offsets[-1] + flat_counts[-1]
 
-    # queries whose expansions overflow the frontier need host replay
-    truncated_seg = (offsets + flat_counts) > F
-    seg_q = jnp.repeat(q, S, total_repeat_length=F * S)
-    overflow_q = overflow_q.at[seg_q].max(
-        jnp.where(
-            truncated_seg & (flat_counts > 0), CAUSE_FRONTIER_OVERFLOW, 0
-        ).astype(jnp.int32)
+    # queries whose expansions overflow the frontier need host replay;
+    # a task's S slots share its query, so the cut folds over them first
+    truncated = (
+        ((offsets + flat_counts) > F) & (flat_counts > 0)
+    ).reshape(F, S).any(axis=1)
+    task_code = jnp.maximum(
+        task_code, jnp.where(truncated, CAUSE_FRONTIER_OVERFLOW, 0)
+    )
+    overflow_q = raise_causes(
+        jnp.zeros(n_queries, dtype=jnp.int32), q, task_code
     )
 
     # build candidate children by segmented gather; all per-(task, slot)
@@ -894,7 +919,9 @@ def dedupe_phase(
     and pack the survivors into the next frontier (ctx implies the query:
     root ctxs ARE query ids, leaf ctxs belong to one island instance).
     Candidates may be longer than F (multi-shard gather); survivors
-    beyond F flag their queries for host replay.
+    beyond F flag their queries for host replay. Where they are not (the
+    static count G <= F), no survivor can land past F and no scatter is
+    emitted.
 
     Sort-free: candidates race for a hash bucket (scatter-max of a
     priority encoding depth then candidate index); each candidate then
@@ -958,16 +985,14 @@ def dedupe_phase(
     n_keep = keep.sum().astype(jnp.int32)
     kept_in_cap = keep & (pos < F)
     # survivors that don't fit in the frontier: their queries go to host
-    overflow_q = (
-        jnp.zeros(n_queries, dtype=jnp.int32)
-        .at[children.q]
-        .max(
-            jnp.where(
-                keep & (pos >= F), CAUSE_FRONTIER_OVERFLOW, 0
-            ).astype(jnp.int32),
+    overflow_q = jnp.zeros(n_queries, dtype=jnp.int32)
+    if G > F:
+        overflow_q = overflow_q.at[children.q].max(
+            jnp.where(keep & (pos >= F), CAUSE_FRONTIER_OVERFLOW, 0).astype(
+                jnp.int32
+            ),
             mode="drop",
         )
-    )
     # non-kept entries park at index F: out-of-bounds scatter drops them
     dest = jnp.where(kept_in_cap, pos, F)
     nt_q = jnp.zeros(F, jnp.int32).at[dest].set(children.q, mode="drop")
@@ -1160,7 +1185,7 @@ def _check_kernel_impl(
             dh_probes=dh_probes, has_delta=has_delta,
         )
         ctx_hit = st.ctx_hit.at[ctx].max(hit)
-        needs_host = st.needs_host.at[q].max(flagged)
+        needs_host = raise_causes(st.needs_host, q, flagged)
 
         # refresh liveness after accumulator updates (short-circuit)
         live = live & ~(ctx_hit[:B] | (needs_host > 0))[q] & ~ctx_hit[ctx]
@@ -1189,6 +1214,7 @@ def _check_kernel_impl(
             children.valid.sum(),
             n_new,
             demand,
+            cause_raised(flagged, overflow_q),
         )
         return _State(
             nt_q, nt_ctx, nt_obj, nt_rel, nt_depth, n_new,

@@ -3263,7 +3263,7 @@ class TPUCheckEngine:
         if self.metrics is not None and sd:
             self.metrics.observe_launch(
                 sd["steps"], step_cap, sd["frontier_max"], gather_bytes,
-                sd["edge_rows"], round(1.0 - occupancy, 4),
+                sd["edge_rows"], round(1.0 - occupancy, 4), sd["flag_steps"],
             )
         fr = self.flightrec
         if fr is None or not fr.enabled:

@@ -883,6 +883,15 @@ class Metrics:
             registry=self.registry,
             buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48),
         )
+        self.launch_flag_steps = prom.Counter(
+            "keto_tpu_launch_flag_steps_total",
+            "BFS steps of the device check launches that scattered a "
+            "host-replay cause into their per-query codes (engine/"
+            "kernel.py STAT_FLAG_STEPS, raise_causes): over the sum of "
+            "keto_tpu_launch_iterations, how often a step's cause gate "
+            "opened",
+            registry=self.registry,
+        )
         self.launch_step_cap = prom.Gauge(
             "keto_tpu_launch_step_cap",
             "Static step budget (max_steps) of the most recent device "
@@ -1487,10 +1496,12 @@ class Metrics:
         gather_bytes: float,
         edge_rows: int,
         padding_waste: float,
+        flag_steps: int,
     ) -> None:
         """One check launch's counter samples (called once per device
         batch at its resolve sync point)."""
         self.launch_iterations.observe(steps)
+        self.launch_flag_steps.inc(flag_steps)
         self.launch_step_cap.set(step_cap)
         self.launch_frontier_peak.observe(frontier_max)
         self.launch_gather_bytes.observe(gather_bytes)

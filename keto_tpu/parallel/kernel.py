@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..engine.kernel import (
     Expansion,
     _State,
+    cause_raised,
     dedupe_phase,
     expand_phase,
     finalize,
@@ -36,6 +37,7 @@ from ..engine.kernel import (
     kernel_static_config,
     probe_phase,
     program_lookup,
+    raise_causes,
     run_bfs_loop,
     seed_state,
     update_launch_stats,
@@ -104,7 +106,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             # a direct edge lives on exactly one shard: OR-merge the hits
             hit = jax.lax.psum(hit_local.astype(jnp.int32), axis) > 0
             ctx_hit = st.ctx_hit.at[ctx].max(hit)
-            needs_host = st.needs_host.at[q].max(flagged)
+            needs_host = raise_causes(st.needs_host, q, flagged)
             live = live & ~(ctx_hit[:B] | (needs_host > 0))[q] & ~ctx_hit[ctx]
 
             # island allocation inside expand_phase is a pure function of
@@ -120,10 +122,11 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
             )
             # per-shard expansions differ (CSR rows are shard-local), so
             # the cause codes merge with pmax — same priority semantics
-            # as the single-chip maximum
-            needs_host = jnp.maximum(
-                needs_host, jax.lax.pmax(overflow_q, axis)
-            )
+            # as the single-chip maximum. expand_phase's scatter is gated
+            # on each shard's own codes, which is sound: its branch holds
+            # no collective
+            overflow_q = jax.lax.pmax(overflow_q, axis)
+            needs_host = jnp.maximum(needs_host, overflow_q)
 
             # merge candidate frontiers: [ndev, F] -> [ndev * F]
             gathered = Expansion(
@@ -147,6 +150,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
                 hit.sum(),
                 gathered.valid.sum(),
                 n_new,
+                flag_step=cause_raised(flagged, overflow_q),
             )
             return _State(
                 nt_q, nt_ctx, nt_obj, nt_rel, nt_depth, n_new,

@@ -789,3 +789,316 @@ class TestBoundedLoop:
         device = e.expand_batch([sub], 4)[0]
         assert str(device) == str(e.reference.expand(sub, 4))
         assert e.stats["host_expands"] == 0
+
+
+def _jaxprs_of(eqn):
+    """The sub-jaxprs an equation holds (jit, scan and cond bodies)."""
+    import jax.extend.core as jc
+
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (tuple, list)) else (value,):
+            if isinstance(item, jc.ClosedJaxpr):
+                yield item.jaxpr
+            elif isinstance(item, jc.Jaxpr):
+                yield item
+
+
+def _eqns_outside_conds(jaxpr):
+    """Every equation of `jaxpr` and of the jits inside it, except what
+    lies in a branch of a `cond`."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            continue
+        yield eqn
+        for sub in _jaxprs_of(eqn):
+            yield from _eqns_outside_conds(sub)
+
+
+def _int_scatters_into(eqns, n):
+    return [
+        eqn for eqn in eqns
+        if eqn.primitive.name.startswith("scatter")
+        and eqn.invars[0].aval.shape == (n,)
+        and eqn.invars[0].aval.dtype == "int32"
+    ]
+
+
+def _step_of(jaxpr):
+    """The BFS step of a check kernel's jaxpr: the live branch of the
+    cond that is bounded_loop's body."""
+    (loop,) = [
+        eqn for eqn in _eqns_outside_conds(jaxpr)
+        if eqn.primitive.name in ("scan", "while")
+    ]
+    (body,) = [j for j in _jaxprs_of(loop) if any(
+        eqn.primitive.name == "cond" for eqn in j.eqns
+    )]
+    (trip,) = [eqn for eqn in body.eqns if eqn.primitive.name == "cond"]
+    return max(_jaxprs_of(trip), key=lambda j: len(j.eqns))
+
+
+def _per_slot_causes(
+    tables, q, ctx, obj, rel, depth, live, isl_state, *, K, rh_probes,
+    n_config_rels, wildcard_rel, n_queries, n_island_cap, has_delta,
+    prog=None,
+):
+    """The oracle of expand_phase's cause codes: one scatter a cause, the
+    frontier's per (task, slot) through a jnp.repeat of the task's query,
+    as the kernel scattered them before it folded them per task."""
+    import jax.numpy as jnp
+
+    from keto_tpu.engine import kernel
+    from keto_tpu.engine.delta import DELTA_PROBES, DIRTY_FOR_CHECK
+    from keto_tpu.engine.snapshot import FLAG_ISLAND, INSTR_COMPUTED, INSTR_TTU
+
+    F, S = q.shape[0], K + 1
+    _, has_prog, pid, prog_flags = prog
+    ipack = tables["instr_pack"][pid].reshape(F, K, 4)
+    ik = jnp.where(has_prog[:, None], ipack[..., 0], 0)
+    ir = jnp.where(has_prog[:, None], ipack[..., 1], 0)
+    rels = jnp.concatenate([rel[:, None], ir], axis=1)
+    spans = kernel._multi_pair_key_probe(tables, "rh", obj, rels, rh_probes, n_vals=2)
+    row_len = jnp.where(spans[..., 0] < 0, 0, spans[..., 1] - spans[..., 0])
+    can_expand = live & (depth >= 1)
+    is_comp = (ik == INSTR_COMPUTED) & live[:, None]
+    is_ttu = (ik == INSTR_TTU) & can_expand[:, None]
+    counts = jnp.concatenate([
+        jnp.where(can_expand, row_len[:, 0], 0)[:, None],
+        jnp.where(is_comp, 1, jnp.where(is_ttu, row_len[:, 1:], 0)),
+    ], axis=1)
+    out = jnp.zeros(n_queries, jnp.int32)
+    if has_delta:
+        dirty_vals = kernel._multi_pair_key_probe(tables, "dirty", obj, rels, DELTA_PROBES)
+        row_dirty = (jnp.maximum(dirty_vals, 0) & DIRTY_FOR_CHECK) != 0
+        dirty = (can_expand & row_dirty[:, 0]) | jnp.any(is_ttu & row_dirty[:, 1:], axis=1)
+        out = out.at[q].max(jnp.where(dirty, kernel.CAUSE_DIRTY, 0))
+    if n_island_cap:
+        is_island = ((prog_flags & FLAG_ISLAND) != 0) & live
+        inc = is_island.astype(jnp.int32)
+        over = is_island & (isl_state[2] + jnp.cumsum(inc) - inc >= n_island_cap)
+        out = out.at[q].max(jnp.where(over, kernel.CAUSE_ISLAND_OVERFLOW, 0))
+        counts = counts.at[:, 1:].set(jnp.where(over[:, None], 0, counts[:, 1:]))
+    flat = counts.reshape(-1)
+    offsets = jnp.cumsum(flat) - flat
+    seg_q = jnp.repeat(q, S, total_repeat_length=F * S)
+    return out.at[seg_q].max(jnp.where(
+        ((offsets + flat) > F) & (flat > 0), kernel.CAUSE_FRONTIER_OVERFLOW, 0
+    ))
+
+
+def _oracle_check(tables, qpack, statics):
+    """check_kernel's results with every cause scattered as before: the
+    flag site ungated, expand_phase's causes by _per_slot_causes."""
+    import jax
+
+    from keto_tpu.engine import kernel
+
+    real_expand = kernel.expand_phase
+
+    def expand_phase(*args, **kwargs):
+        children, _, isl_state, demand = real_expand(*args, **kwargs)
+        return children, _per_slot_causes(*args, **kwargs), isl_state, demand
+
+    def raise_causes(needs_host, q, code):
+        return needs_host.at[q].max(code)
+
+    def run(tables, qpack):
+        return kernel._check_kernel_impl(
+            tables, *qpack[:6], qpack[6].astype(bool), **statics
+        )
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel, "expand_phase", expand_phase)
+        mp.setattr(kernel, "raise_causes", raise_causes)
+        return jax.device_get(jax.jit(run)(tables, qpack))
+
+
+_GROUPS = [Namespace(name="g", relations=[Relation(name="member")])]
+# 8 groups of 6 nested groups each: 48 children in the first step, so a
+# frontier of 16 holds the first two groups' 12 and cuts the other six
+_FAN = [f"g:top{t}#member@(g:sub{t}_{s}#member)" for t in range(8) for s in range(6)]
+_FAN_QUERIES = [f"g:top{t}#member@u" for t in range(8)]
+
+
+def _acl_namespace():
+    from keto_tpu.namespace.ast import InvertResult, Operator
+
+    return Namespace(name="acl", relations=[
+        Relation(name="allow"),
+        Relation(name="deny"),
+        Relation(name="parent"),
+        Relation(name="access", subject_set_rewrite=SubjectSetRewrite(
+            operation=Operator.AND,
+            children=[
+                ComputedSubjectSet(relation="allow"),
+                InvertResult(child=ComputedSubjectSet(relation="deny")),
+            ],
+        )),
+        Relation(name="super", subject_set_rewrite=SubjectSetRewrite(children=[
+            TupleToSubjectSet(relation="parent", computed_subject_set_relation="access"),
+        ])),
+    ])
+
+
+def _chain_namespace():
+    return Namespace(name="d", relations=[
+        Relation(name="owner"),
+        Relation(name="parent"),
+        Relation(name="w", subject_set_rewrite=SubjectSetRewrite(children=[
+            ComputedSubjectSet(relation="owner"),
+            TupleToSubjectSet(relation="parent", computed_subject_set_relation="v"),
+        ])),
+        Relation(name="v", subject_set_rewrite=SubjectSetRewrite(children=[
+            ComputedSubjectSet(relation="w"),
+        ])),
+    ])
+
+
+# cause case -> (namespaces, tuples, tuples written after the snapshot
+# (a delta overlay), queries, max depth, frontier, island slots, the codes
+# expected by query index)
+_CAUSE_CASES = {
+    "clean": (
+        _GROUPS, _FAN[:12], [], _FAN_QUERIES, 5, 64, 0, {},
+    ),
+    "frontier_overflow": (
+        _GROUPS, _FAN, [], _FAN_QUERIES, 5, 16, 0,
+        {0: 0, 1: 0, **{t: 2 for t in range(2, 8)}},
+    ),
+    "dirty_row": (
+        _GROUPS, _FAN[:12], ["g:top1#member@(g:late#member)"], _FAN_QUERIES[:2],
+        5, 64, 0, {0: 0, 1: 4},
+    ),
+    "island_overflow": (
+        [_acl_namespace()],
+        [f"acl:root#parent@(acl:doc{i}#...)" for i in range(40)]
+        + ["acl:doc39#allow@alice"],
+        [], ["acl:root#super@alice"], 5, 64, 32, {0: 3},
+    ),
+    "relation_not_found": (
+        [Namespace(name="n", relations=[Relation(name="known")])],
+        ["n:o#rogue@u", "n:o#known@u"], [], ["n:o#rogue@v", "n:o#known@u"],
+        5, 64, 0, {0: 5, 1: 0},
+    ),
+    "step_exhausted": (
+        [_chain_namespace()],
+        [f"d:f{i}#parent@(d:f{i + 1}#...)" for i in range(60)] + ["d:f60#owner@user"],
+        [], ["d:f0#v@user"], 100, 64, 0, {0: 1},
+    ),
+    # a dirty row (4) on a query whose expansion is also cut (2)
+    "two_causes": (
+        _GROUPS, _FAN, ["g:top5#member@(g:late#member)"], _FAN_QUERIES, 5, 16, 0,
+        {0: 0, 3: 2, 5: 4},
+    ),
+}
+
+
+class TestCauseScatters:
+    """A BFS step folds its host-replay causes per task and scatters them
+    into needs_host only where one is raised (kernel.raise_causes), and
+    dedupe_phase emits no overflow scatter where its candidates fit the
+    frontier: held to the scatters they replace, and by the traced
+    program's shape."""
+
+    @pytest.mark.parametrize("case", list(_CAUSE_CASES))
+    def test_needs_host_is_the_per_slot_scatters(self, case):
+        import numpy as np
+
+        from keto_tpu.engine import kernel
+
+        (namespaces, tuples, late, queries, max_depth, F, n_island,
+         want) = _CAUSE_CASES[case]
+        e = make_tpu_engine(namespaces, tuples, max_depth=max_depth)
+        e._ensure_state()
+        if late:
+            e.manager.write_relation_tuples(
+                [RelationTuple.from_string(s) for s in late]
+            )
+        state = e._ensure_state()
+        assert state.has_delta == bool(late)
+        B = 16
+        qpack = kernel.pack_queries(*e._encode_queries(
+            state, [RelationTuple.from_string(s) for s in queries],
+            B, max_depth, False, None,
+        ))
+        statics = kernel.kernel_static_config(
+            state.snapshot, max_depth, F, n_island_cap=n_island,
+            has_delta=state.has_delta,
+        )
+        assert statics["n_island_cap"] == n_island
+        ctx_hit, needs_host, *_, stats = kernel.unpack_results(
+            np.asarray(kernel.check_kernel_packed(state.tables, qpack, **statics)),
+            B, n_island, statics["K"],
+        )
+        o_ctx_hit, o_needs_host, *_ = _oracle_check(state.tables, qpack, statics)
+        assert needs_host.tolist() == o_needs_host.tolist()
+        assert ctx_hit.tolist() == o_ctx_hit.astype(bool).tolist()
+        assert {i: int(needs_host[i]) for i in want} == want
+        assert not needs_host[len(queries):].any()
+        # a step raised a cause (finalize's step exhaustion is not a step's)
+        raised = bool((needs_host > kernel.CAUSE_STEP_EXHAUSTED).any())
+        assert (stats[kernel.STAT_FLAG_STEPS] > 0) == raised
+        assert raised == (case not in ("clean", "step_exhausted"))
+
+    @pytest.mark.parametrize("case", ["clean", "dirty_row", "island_overflow"])
+    def test_no_cause_scatter_outside_a_cond_in_the_step(self, case):
+        """At B 64, F 128: no int32 scatter into the [B] codes and no
+        [F * S] copy of the frontier's queries outside a cond in the step."""
+        import functools
+
+        import jax
+
+        from keto_tpu.engine import kernel
+
+        namespaces, tuples, late, _, max_depth, _, n_island, _ = _CAUSE_CASES[case]
+        e = make_tpu_engine(namespaces, tuples, max_depth=max_depth)
+        e._ensure_state()
+        if late:
+            e.manager.write_relation_tuples(
+                [RelationTuple.from_string(s) for s in late]
+            )
+        state = e._ensure_state()
+        B, F = 64, 128
+        statics = kernel.kernel_static_config(
+            state.snapshot, max_depth, F, n_island_cap=2 * B if n_island else 0,
+            has_delta=state.has_delta,
+        )
+        qpack = kernel.pack_queries(*e._encode_queries(
+            state, [], B, max_depth, False, None
+        ))
+        step = _step_of(jax.make_jaxpr(
+            functools.partial(kernel.check_kernel_packed, **statics)
+        )(state.tables, qpack).jaxpr)
+        outside = list(_eqns_outside_conds(step))
+        assert not _int_scatters_into(outside, B)
+        S = statics["K"] + 1
+        assert not [
+            eqn for eqn in outside
+            if eqn.primitive.name == "gather"
+            and eqn.outvars[0].aval.shape == (F * S,)
+        ]
+        # the gated scatters are there, inside the step's conds
+        inside = [
+            eqn for eqn in step.eqns if eqn.primitive.name == "cond"
+            for branch in _jaxprs_of(eqn)
+            for eqn in _eqns_outside_conds(branch)
+        ]
+        assert len(_int_scatters_into(inside, B)) == 2
+
+    @pytest.mark.parametrize("shards", [1, 2])
+    def test_dedupe_scatters_overflow_only_past_the_frontier(self, shards):
+        """G <= F (one device) emits no scatter into [n_queries]; the
+        shard_map kernel's gathered candidates (G = shards * F) keep it."""
+        import jax
+        import jax.numpy as jnp
+
+        from keto_tpu.engine.kernel import Expansion, dedupe_phase
+
+        F, n_queries = 128, 48
+        G = shards * F
+        z = jnp.zeros(G, jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda c: dedupe_phase(c, F, n_queries)
+        )(Expansion(z, z, z, z, z, jnp.ones(G, bool))).jaxpr
+        scatters = _int_scatters_into(_eqns_outside_conds(jaxpr), n_queries)
+        assert len(scatters) == (0 if G <= F else 1)

@@ -18,6 +18,8 @@ import, `skipif`, `parametrize` or conftest hook may.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -158,6 +160,22 @@ def assert_no_table_relayout(memory):
     assert memory.temp_size_in_bytes < memory.argument_size_in_bytes
 
 
+def assert_cause_scatters_gated(hlo: str, B: int):
+    """A step scatters its host-replay causes into the [B] codes only in a
+    conditional of its own (kernel.raise_causes), nested in the loop's
+    step, and builds no [F * S] copy of its queries (`jnp.repeat`'s
+    `_take`) to do so: one cond in a step's op_name is the step itself."""
+    step = "while/body/closed_call/cond/"
+    scatters = re.findall(
+        rf'= s32\[{B}\]\S* scatter\(.*op_name="([^"]*)"', hlo
+    )
+    in_step = [name for name in scatters if step in name]
+    assert in_step and all(
+        name.split(step, 1)[1].count("cond/") >= 1 for name in in_step
+    ), in_step
+    assert "jit(_take)" not in hlo
+
+
 def smoke_batch(drive):
     queries, _ = chip_smoke.view_queries(drive, np.random.default_rng(1), 2048)
     return queries
@@ -178,7 +196,7 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     assert tables["dh_pack"].shape == (1 << 20, 64)
     assert tables["rh_pack"].shape == (1 << 19, 64)
     assert qpack.shape == (7, 2048) and statics["frontier_cap"] == 4096
-    _, memory = compile_for_chip(
+    compiled, memory = compile_for_chip(
         "check_kernel_packed", kernel.check_kernel_packed, tables_and_queries,
         statics, lambda a: one_chip,
     )
@@ -186,6 +204,7 @@ def test_check_kernel_fits_the_chip(no_compile_cache, one_chip, drive, engine):
     print(f"argument + temp = {total} of {V5E_HBM_BYTES}")
     assert total < V5E_HBM_BYTES
     assert_no_table_relayout(memory)
+    assert_cause_scatters_gated(compiled.as_text(), 2048)
 
 
 # drive-chip-share's store: the 4,000,000-tuple drive store's tables where
