@@ -176,7 +176,7 @@ def build_drive(seed: int, tuples: int) -> Drive:
     return Drive(cols, f_names, owners, n_co, files_per, n_roles)
 
 
-def drive_config(serve: bool):
+def drive_config(serve: bool, mesh_devices: int = 1):
     from keto_tpu.config import Config
     from keto_tpu.namespace import Namespace
     from keto_tpu.namespace.ast import (
@@ -187,6 +187,8 @@ def drive_config(serve: bool):
     )
 
     values: dict = {"dsn": "columnar"}
+    if mesh_devices > 1:
+        values["check"] = {"mesh_devices": mesh_devices}
     if serve:
         loopback = {"host": "127.0.0.1", "port": 0}
         values["serve"] = {
@@ -646,20 +648,20 @@ def device_ids(tables: dict) -> dict:
 
 def run_mesh4(args, drive: Drive) -> None:
     """The sharded path and what it is compared with, and no other phase:
-    one check batch and one expand batch through TPUCheckEngine(mesh=...)
-    against the same batches on a single-device engine and the oracle."""
+    one check batch and one expand batch through the engine a registry
+    builds for `check.mesh_devices: 4` against the same batches on a
+    single-device engine and the oracle."""
     from keto_tpu.engine.tpu_engine import TPUCheckEngine
     from keto_tpu.ketoapi import SubjectSet
-    from keto_tpu.parallel import default_mesh
-    from keto_tpu.storage.columnar import ColumnarStore
+    from keto_tpu.registry import Registry
 
     rng = np.random.default_rng(args.seed + 2)
     t0 = time.perf_counter()
-    store = ColumnarStore()
+    registry = Registry(drive_config(serve=False, mesh_devices=4))
+    store = registry.relation_tuple_manager()
     store.bulk_load(drive.cols)
-    cfg = drive_config(serve=False)
-    mesh = default_mesh(4)
-    sharded = TPUCheckEngine(store, cfg, mesh=mesh)
+    sharded = registry.check_engine()
+    mesh = sharded.mesh
     state = sharded._ensure_state()
     sharded_tables, replicated_tables = state.tables
     holders = device_ids(sharded_tables)
@@ -680,7 +682,7 @@ def run_mesh4(args, drive: Drive) -> None:
     )
 
     t0 = time.perf_counter()
-    single = TPUCheckEngine(store, cfg)
+    single = TPUCheckEngine(store, drive_config(serve=False))
     queries, want = view_queries(drive, rng, BATCH)
     got_sharded = [r.allowed for r in sharded.check_batch(queries)]
     sharded_s = time.perf_counter() - t0

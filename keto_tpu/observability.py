@@ -917,16 +917,40 @@ class Metrics:
         )
         self.launch_frontier_slots_total = prom.Counter(
             "keto_tpu_launch_frontier_slots_total",
-            "Frontier slots (F) of the single-device check launches, "
+            "Frontier slots (F) of the check launches, "
             "summed; over keto_tpu_launch_bucket_slots_total, the "
             "frontier slots a launch paid for each query slot",
             registry=self.registry,
         )
         self.launch_bucket_slots_total = prom.Counter(
             "keto_tpu_launch_bucket_slots_total",
-            "Query slots (the bucket B) of the single-device check "
-            "launches, summed",
+            "Query slots (the bucket B) of the check launches, summed",
             registry=self.registry,
+        )
+        self.mesh_devices = prom.Gauge(
+            "keto_tpu_mesh_devices",
+            "Devices the check engine's tables are sharded over "
+            "(check.mesh_devices; 1 where it serves from one device): "
+            "each BFS step of a launch merges that many shards' probe "
+            "hits, host-replay causes and candidate frontiers over ICI",
+            registry=self.registry,
+        )
+        self.mesh_launches_total = prom.Counter(
+            "keto_tpu_mesh_launches_total",
+            "Check launches of the sharded program (parallel/kernel.py): "
+            "one program over every device of the mesh",
+            registry=self.registry,
+        )
+        self.mesh_place_seconds = prom.Histogram(
+            "keto_tpu_mesh_place_seconds",
+            "Host seconds a sharded check launch spends placing its query "
+            "arrays on every device of the mesh (span keto.mesh.place, "
+            "inside stage dispatch and counted there)",
+            registry=self.registry,
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1,
+            ),
         )
         self.frontier_retry_total = prom.Counter(
             "keto_tpu_frontier_retry_total",
@@ -1508,12 +1532,16 @@ class Metrics:
         self.launch_edge_rows.observe(edge_rows)
         self.launch_padding_waste.observe(padding_waste)
 
-    def observe_frontier(self, bucket: int, frontier: int, demand: int) -> None:
-        """One single-device check launch's geometry: its query slots, its
-        frontier slots, and the frontier its steps asked for."""
+    def observe_frontier(
+        self, bucket: int, frontier: int, demand: int | None
+    ) -> None:
+        """One check launch's geometry: its query slots, its frontier
+        slots, and the frontier its steps asked for (None from the
+        sharded program, which reports no demand)."""
         self.launch_bucket_slots_total.inc(bucket)
         self.launch_frontier_slots_total.inc(frontier)
-        self.launch_demand_peak.observe(demand)
+        if demand is not None:
+            self.launch_demand_peak.observe(demand)
 
     def observe_stage(
         self, stage: str, seconds: float, trace_id: Optional[str] = None

@@ -66,8 +66,11 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
     ) = statics
     F = frontier_cap
 
+    # the program's name (`jit_sharded_check` in a trace's `XLA Modules`
+    # line) is this function's: it holds `check`, as the one-device
+    # program's does, so the benchmark's check_program_ms reads both
     @jax.named_scope("keto.check")
-    def run(shard_tabs, rep_tabs, q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid):
+    def sharded_check(shard_tabs, rep_tabs, q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid):
         tables = {k: v[0] for k, v in shard_tabs.items()}
         tables.update(rep_tabs)
         B = q_obj.shape[0]
@@ -166,7 +169,7 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
         return finalize(final, max_steps, B)
 
     mapped = shard_map(
-        run,
+        sharded_check,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P()),

@@ -22,7 +22,7 @@ import threading
 from typing import Optional
 
 from . import __version__
-from .config import Config
+from .config import Config, ConfigError
 from .engine.reference import ReferenceEngine
 from .errors import NamespaceNotFoundError
 from .ketoapi import CheckColumns, RelationQuery, RelationTuple
@@ -83,12 +83,11 @@ class Registry:
         self,
         config: Optional[Config] = None,
         nid: str = DEFAULT_NETWORK,
-        mesh=None,
         contextualizer=None,
     ):
         self.config = config or Config()
         self.nid = nid
-        self.mesh = mesh
+        self.mesh = None  # built from check.mesh_devices on first use
         self.version = __version__
         # operator platform pin: `check.platform: cpu` is an explicit
         # operator choice to serve from the host backend (a machine with
@@ -376,7 +375,7 @@ class Registry:
             # checkout then finds every kernel already built
             ensure_compile_cache()
             return TPUCheckEngine(
-                manager, self.config, nid=nid, mesh=self.mesh,
+                manager, self.config, nid=nid, mesh=self._check_mesh(),
                 metrics=self.metrics(), tracer=self.tracer(),
                 frontier_cap=int(
                     self.config.get("check.frontier_cap", 1 << 14)
@@ -392,6 +391,26 @@ class Registry:
                 metrics=self.metrics(),
             )
         raise ValueError(f"unknown check.engine: {kind!r}")
+
+    def _check_mesh(self):
+        """The mesh the check engines shard their tables over: one axis
+        over the first `check.mesh_devices` devices, built once; None
+        (one device) at the default of 1."""
+        if self.mesh is None:
+            n = int(self.config.get("check.mesh_devices", 1))
+            if n > 1:
+                import jax
+
+                from .parallel import default_mesh
+
+                seen = jax.device_count()
+                if seen < n:
+                    raise ConfigError(
+                        f"check.mesh_devices is {n}, but this process "
+                        f"sees {seen} device(s)"
+                    )
+                self.mesh = default_mesh(n)
+        return self.mesh
 
     def expand_engine(self, nid: Optional[str] = None):
         return self.check_engine(nid)

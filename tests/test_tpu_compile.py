@@ -443,3 +443,74 @@ def test_sharded_kernel_compiles_for_four_chips(
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_HBM_BYTES
     hlo = compiled.as_text()
     assert "all-reduce" in hlo or "all-gather" in hlo
+
+
+# drive-mesh4-4e6's store over four shards: a quarter of the 4,000,000-tuple
+# drive store a chip, every shard at the capacity of the 1e6 store's tables
+# (snapshot.table_capacity of 1,000,000 edges), the replicated object map of
+# the whole store, and a quarter of its 3,938,717 subject-set edges with
+# room for the hash's unevenness between shards
+MESH4_SHARD_SHAPES = {
+    "dh_pack": (4, 1 << 20, 64), "rh_pack": (4, 1 << 19, 64),
+    "e_pack": (4, 3938717 // 4 + 2048, 2),
+}
+MESH4_REPLICATED_SHAPES = {"objslot_ns": CHIP_SHARE_SHAPES["objslot_ns"]}
+
+
+def test_sharded_check_program_fits_four_chips_at_the_cell(
+    no_compile_cache, four_chips, drive, engine, sharded_engine
+):
+    """The sharded check program of `drive-mesh4-4e6.batch2048-c4` on the
+    described 2x2 host: bucket 2,048, frontier 8,192 (the mesh keeps four
+    slots a query slot), the cell's tables from their shapes alone, with
+    the probe depths of a one-device store of a shard's size (the 1e6
+    store's). Argument + temp fit one v5e a device, and the program's
+    name in a trace's `XLA Modules` line holds `check`, which the
+    benchmark's check_program_ms matches."""
+    args, kwargs = capture_launch(
+        parallel_kernel, "sharded_check_kernel",
+        lambda: sharded_engine.check_batch(smoke_batch(drive)),
+    )
+    _, sharded_tables, replicated_tables, *queries = args
+    assert queries[0].shape == (2048,)
+    _, one_device = capture_launch(
+        kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
+    )
+    statics = dict(zip(kernel._KERNEL_STATICS, kwargs["statics"]))
+    assert statics["frontier_cap"] == 8192
+    statics.update(
+        dh_probes=one_device["dh_probes"], rh_probes=one_device["rh_probes"]
+    )
+
+    def place(shape, dtype, spec):
+        sharding = NamedSharding(four_chips, spec)
+        layout = kernel.bucket_row_layout(shape, dtype)
+        if layout is not None:
+            sharding = Format(layout, sharding)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    split = {
+        k: place(MESH4_SHARD_SHAPES[k], v.dtype, P("x", *([None] * (v.ndim - 1))))
+        for k, v in sharded_tables.items()
+    }
+    replicated = {
+        k: place(MESH4_REPLICATED_SHAPES.get(k, v.shape), v.dtype, P())
+        for k, v in replicated_tables.items()
+    }
+    described_queries = [place(q.shape, q.dtype, P()) for q in queries]
+    program = parallel_kernel.get_sharded_kernel(
+        four_chips, tuple(statics[k] for k in kernel._KERNEL_STATICS)
+    )
+    compiled = program.lower(split, replicated, *described_queries).compile()
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    hlo = compiled.as_text()
+    module = re.match(r"HloModule (\S+?),", hlo).group(1)
+    print(f"\nsharded check at the cell: module={module} "
+          f"argument={memory.argument_size_in_bytes} "
+          f"temp={memory.temp_size_in_bytes} (a device)")
+    assert "check" in module
+    assert 512 * 1024**2 < memory.argument_size_in_bytes
+    assert total < V5E_HBM_BYTES
+    assert_no_table_relayout(memory)
+    assert "all-gather" in hlo and "all-reduce" in hlo
