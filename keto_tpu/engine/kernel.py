@@ -1263,15 +1263,28 @@ def check_kernel_packed(
     than the kernel. One upload + one readback per batch is the
     transfer-count floor. unpack/concat compile to free reshapes on
     device."""
-    ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = _check_kernel_impl(
-        tables,
-        qpack[0], qpack[1], qpack[2], qpack[3], qpack[4], qpack[5],
-        qpack[6].astype(bool),
+    return pack_results(*_check_kernel_impl(
+        tables, *unpack_queries(qpack),
         K=K, dh_probes=dh_probes, rh_probes=rh_probes, max_steps=max_steps,
         wildcard_rel=wildcard_rel, n_config_rels=n_config_rels,
         frontier_cap=frontier_cap, n_island_cap=n_island_cap,
         has_delta=has_delta,
+    ))
+
+
+def unpack_queries(qpack: jnp.ndarray) -> tuple:
+    """The seven query columns of a packed [7, B] query array, on the
+    device: rows of one buffer, no copy."""
+    return (
+        qpack[0], qpack[1], qpack[2], qpack[3], qpack[4], qpack[5],
+        qpack[6].astype(bool),
     )
+
+
+def pack_results(ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats):
+    """finalize's six results as the ONE int32 vector a check program
+    returns: [n_isl, ctx_hit(B + NI*K), needs_host(B), isl_parent(NI),
+    isl_pid(NI), stats(N_LAUNCH_STATS)]; unpack_results reads it back."""
     return jnp.concatenate([
         n_isl[None].astype(jnp.int32),
         ctx_hit.astype(jnp.int32),
@@ -1287,7 +1300,8 @@ def check_kernel_packed(
 def pack_queries(
     q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid
 ) -> np.ndarray:
-    """Host-side twin of check_kernel_packed's input layout."""
+    """Host-side twin of unpack_queries: the [7, B] int32 query array
+    of check_kernel_packed and the sharded check program."""
     import numpy as _np
 
     return _np.stack([
@@ -1297,7 +1311,7 @@ def pack_queries(
 
 
 def unpack_results(flat: np.ndarray, B: int, n_island_cap: int, K: int):
-    """Slice check_kernel_packed's result vector back into
+    """Slice a check program's result vector (pack_results) back into
     (ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats) numpy
     views. `stats` is the launch introspection counter vector
     (STAT_* slots; launch_stats_dict names them)."""

@@ -2626,6 +2626,14 @@ class TPUCheckEngine:
         with StageSpan("dispatch", launch_id) as dsp, self.tracer.span(
             "engine.kernel_launch", batch=B, frontier=launch_cap
         ):
+            from .kernel import pack_queries
+
+            # single-buffer I/O on one device and on a mesh alike: ONE
+            # host->device upload (the packed query array) and ONE
+            # device->host readback at resolve, in place of seven uploads
+            # and five or six readbacks that each pay a transfer's fixed
+            # cost (on a mesh, on every device)
+            qpack = pack_queries(*queries)
             if self.mesh is not None:
                 import jax
                 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -2644,41 +2652,35 @@ class TPUCheckEngine:
                 # over its own tables, side by side, so one chip's bytes
                 # are the one-device estimate at the same statics
                 cfg = dict(zip(_KERNEL_STATICS, statics))
-                # the query columns, replicated onto every device of the
-                # mesh in one call: a span of its own inside dispatch
+                # the packed query array, replicated onto every device of
+                # the mesh in one call: a span of its own inside dispatch
                 with StageSpan("mesh.place", launch_id) as place:
-                    queries = jax.device_put(
-                        queries, NamedSharding(self.mesh, P())
+                    qpack = jax.device_put(
+                        qpack, NamedSharding(self.mesh, P())
                     )
                 sharded_tables, replicated_tables = state.tables
                 outputs = sharded_check_kernel(
-                    self.mesh, sharded_tables, replicated_tables, *queries,
+                    self.mesh, sharded_tables, replicated_tables, qpack,
                     statics=statics, axis=self.mesh.axis_names[0],
                 )
                 if self.metrics is not None:
                     self.metrics.mesh_launches_total.inc()
                     self.metrics.mesh_place_seconds.observe(place.seconds)
             else:
-                from .kernel import check_kernel_packed, pack_queries
+                from .kernel import check_kernel_packed
 
                 cfg = kernel_static_config(
                     state.snapshot, global_max, launch_cap,
                     n_island_cap=island_cap, has_delta=state.has_delta,
                 )
-                # single-buffer I/O: ONE host->device upload (the packed
-                # query array) and ONE device->host readback at resolve,
-                # in place of seven uploads and five readbacks that each
-                # pay a transfer's fixed cost. A frontier retry launches
-                # the same array again.
-                meta["qpack"] = pack_queries(*queries)
+                # a frontier retry launches the same array again
+                meta["qpack"] = qpack
                 meta["cfg"] = cfg
-                outputs = check_kernel_packed(
-                    state.tables, meta["qpack"], **cfg
-                )
+                outputs = check_kernel_packed(state.tables, qpack, **cfg)
         # everything past the launch is deferred to resolve: touching the
         # outputs here would block on the device round-trip
         meta.update(
-            island_cap=island_cap if self.mesh is None else None,
+            island_cap=island_cap,
             launch_cap=launch_cap,
             frontier_top=frontier_top,
             step_cap=int(cfg["max_steps"]),
@@ -2960,32 +2962,21 @@ class TPUCheckEngine:
         n, B = meta["n"], meta["B"]
         q_valid = meta["q_valid"]
         with self._device_wait(meta) as waited:
-            if meta.get("island_cap") is not None:
-                # packed single-device result: ONE device->host readback —
-                # the launch stats vector rides the same transfer
-                from .kernel import unpack_results
+            # the packed result, on one device or replicated over a mesh:
+            # ONE device->host readback, the launch stats vector in it
+            from .kernel import unpack_results
 
-                ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = (
-                    unpack_results(
-                        # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                        np.asarray(outputs), B, meta["island_cap"],
-                        state.snapshot.K,
-                    )
+            ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = (
+                unpack_results(
+                    # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
+                    np.asarray(outputs), B, meta["island_cap"],
+                    state.snapshot.K,
                 )
-                ctx_hit = ctx_hit.copy()
-            else:
-                ctx_hit, needs_host, isl_parent, isl_pid, n_isl, stats = outputs
-                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                ctx_hit = np.asarray(ctx_hit).copy()
-                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                needs_host = np.asarray(needs_host)
-                # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                n_isl = int(n_isl)
-                # ketolint: allow[host-sync] reason=part of the same designated resolve sync point: the mesh path's replicated stats vector reads back with the batch results, not as an extra round-trip
-                stats = np.asarray(stats)
+            )
+            ctx_hit = ctx_hit.copy()
         retry = None
         demand = None  # the sharded kernel reports none
-        if meta.get("island_cap") is not None:
+        if self.mesh is None:
             demand = int(stats[STAT_DEMAND_MAX])
             self._note_demand(B, demand)
             # a demand within F is exactly no frontier overflow: only a
@@ -3012,8 +3003,7 @@ class TPUCheckEngine:
             # own, outside device_wait, so that stage stays the device's
             with StageSpan("islands", meta.get("launch_id")) as islands:
                 member = combine_islands(
-                    # ketolint: allow[host-sync] reason=this IS the batch's designated sync point: resolve is the synchronize phase of the split-phase submit/resolve contract, and the single-buffer I/O design makes this readback the ONE device->host transfer for the whole batch
-                    ctx_hit, np.asarray(isl_parent), np.asarray(isl_pid),
+                    ctx_hit, isl_parent, isl_pid,
                     n_isl, state.snapshot.island_circuits, B, state.snapshot.K,
                 )
         else:

@@ -11,10 +11,19 @@ slot and two ICI collectives per step:
 
 The frontier and per-query result masks stay replicated: every device
 runs the identical merged state, so the BFS loop's trip count agrees
-across the mesh and the host reads back one copy. This mirrors the
-scaling-book recipe — pick a mesh, shard the big arrays, let collectives
-ride ICI — rather than the reference's shared-SQL-database fan-out
-(SURVEY.md §2.11).
+across the mesh and the host reads back one copy.
+
+I/O is single-buffer, as `check_kernel_packed`'s: one upload (the packed
+[7, B] int32 query array, replicated over the mesh) and one readback
+(one replicated int32 vector, [n_isl, ctx_hit(B + NI*K), needs_host(B),
+isl_parent(NI), isl_pid(NI), stats], the layout of
+`engine/kernel.pack_results`). Every buffer transfer pays a fixed cost on
+each device it touches, so seven columns and six results would be 52
+buffers a launch on four chips where these are 8.
+
+This mirrors the scaling-book recipe — pick a mesh, shard the big
+arrays, let collectives ride ICI — rather than the reference's
+shared-SQL-database fan-out (SURVEY.md §2.11).
 """
 
 from __future__ import annotations
@@ -35,11 +44,13 @@ from ..engine.kernel import (
     finalize,
     flag_phase,
     kernel_static_config,
+    pack_results,
     probe_phase,
     program_lookup,
     raise_causes,
     run_bfs_loop,
     seed_state,
+    unpack_queries,
     update_launch_stats,
 )
 from .sharding import (
@@ -70,7 +81,8 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
     # line) is this function's: it holds `check`, as the one-device
     # program's does, so the benchmark's check_program_ms reads both
     @jax.named_scope("keto.check")
-    def sharded_check(shard_tabs, rep_tabs, q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid):
+    def sharded_check(shard_tabs, rep_tabs, qpack):
+        q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid = unpack_queries(qpack)
         tables = {k: v[0] for k, v in shard_tabs.items()}
         tables.update(rep_tabs)
         B = q_obj.shape[0]
@@ -166,13 +178,13 @@ def _build_kernel(mesh: Mesh, axis: str, statics: tuple):
         # aligned across the mesh.
         init = seed_state(q_obj, q_rel, q_depth, q_valid, F, n_island_cap, K)
         final = run_bfs_loop(step_fn, init, max_steps, B)
-        return finalize(final, max_steps, B)
+        return pack_results(*finalize(final, max_steps, B))
 
     mapped = shard_map(
         sharded_check,
         mesh=mesh,
-        in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), P(), P()),
+        in_specs=(P(axis), P(), P()),
+        out_specs=P(),
         check_vma=False,
     )
     return jax.jit(mapped)
@@ -297,19 +309,20 @@ def sharded_check_kernel(
     mesh: Mesh,
     sharded_tables: dict,
     replicated_tables: dict,
-    q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid,
+    qpack,
     *,
     statics: tuple,
     axis: str = "x",
 ):
-    """Returns (ctx_hit, needs_host[B] cause codes, isl_parent, isl_pid,
-    n_isl, stats); see engine/kernel.check_kernel."""
+    """One upload, one readback, as `check_kernel_packed`: `qpack` is the
+    [7, B] int32 query array of `pack_queries` (obj, rel, depth, skind,
+    sa, sb, valid), replicated over the mesh, and the result is ONE
+    replicated int32 vector [n_isl, ctx_hit(B + NI*K), needs_host(B),
+    isl_parent(NI), isl_pid(NI), stats(N_LAUNCH_STATS)], which
+    `unpack_results` slices; see engine/kernel.check_kernel."""
     assert set(sharded_tables) == set(_SHARDED_DEVICE_KEYS)
     assert set(replicated_tables) == set(_REPLICATED_KEYS) | set(
         _DELTA_DEVICE_KEYS
     )
     fn = get_sharded_kernel(mesh, statics, axis)
-    return fn(
-        sharded_tables, replicated_tables,
-        q_obj, q_rel, q_depth, q_skind, q_sa, q_sb, q_valid,
-    )
+    return fn(sharded_tables, replicated_tables, qpack)

@@ -547,15 +547,14 @@ class TestNoAdditionalDeviceSyncs:
     'just one more')."""
 
     # (function, expected allow[host-sync] count): the submit phase has
-    # ZERO syncs; resolve carries the pre-feature 6 (single packed
-    # readback + the mesh tuple's per-array readbacks) plus exactly ONE
-    # for the stats vector riding the same mesh resolve — that is the
-    # feature's whole device->host budget
+    # ZERO syncs; resolve carries ONE packed readback, on one device and
+    # on a mesh alike (the stats vector rides it), plus the
+    # batch_corrupt fault point's — that is the whole device->host budget
     EXPECTED = {
         "_check_batch_submit_inner": 0,
         "check_batch_submit": 0,
         "check_batch_resolve_v": 0,
-        "_check_batch_resolve_v_inner": 7,
+        "_check_batch_resolve_v_inner": 2,
         # the frontier retry: ONE packed readback of the second launch
         # of a batch whose queries overflowed a narrowed frontier
         "_frontier_retry": 1,

@@ -5,17 +5,22 @@ at `drive-mesh4-4e6`'s construction cut to 20,000 tuples."""
 
 import os
 import sys
+import threading
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from keto_tpu.api.client import ReadClient, open_channel
 from keto_tpu.api.daemon import Daemon
 from keto_tpu.config import Config, ConfigError
 from keto_tpu.engine.kernel import (
     _KERNEL_STATICS,
+    N_LAUNCH_STATS,
     estimate_step_gather_bytes,
     launch_stats_dict,
+    unpack_results,
 )
 from keto_tpu.engine.tpu_engine import _tables_devices
 from keto_tpu.observability import CHECK_STAGES
@@ -198,7 +203,10 @@ def test_a_mesh_launch_counts_one_chips_gathered_bytes(daemons, cell):
     state = engine._ensure_state()
     handle = engine.check_batch_submit(wl.request(3)[0], 0)
     _, outputs, meta = handle
-    steps = int(launch_stats_dict(np.asarray(outputs[-1]))["steps"])
+    *_, stats = unpack_results(
+        np.asarray(outputs), meta["B"], meta["island_cap"], state.snapshot.K
+    )
+    steps = int(launch_stats_dict(stats)["steps"])
     engine.check_batch_resolve(handle)
     statics = sharded_static_config(
         state.sharded, engine.config.max_read_depth(), 8192,
@@ -208,6 +216,67 @@ def test_a_mesh_launch_counts_one_chips_gathered_bytes(daemons, cell):
     assert meta["gather_step_bytes"] == one_device > 0
     entry = registry.flight_recorder().entries()[-1]
     assert entry["gather_bytes_est"] == steps * one_device
+
+
+def test_a_mesh_launch_puts_one_packed_array_and_reads_one_vector(
+    daemons, cell, monkeypatch
+):
+    """One `jax.device_put` a launch, of the one [7, B] int32 query array,
+    replicated over the mesh; the handle's outputs are one replicated
+    int32 vector in `check_kernel_packed`'s layout, not six arrays."""
+    _, registry, _ = daemons
+    _, wl, _ = cell
+    engine = registry.check_engine()
+    state = engine._ensure_state()
+    puts = []
+    launching = threading.get_ident()
+    real_put = jax.device_put
+
+    def put(x, *args, **kwargs):
+        if threading.get_ident() == launching:
+            puts.append((x, args[0] if args else kwargs.get("device")))
+        return real_put(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", put)
+    handle = engine.check_batch_submit(wl.request(4)[0], 0)
+    monkeypatch.undo()
+    engine.check_batch_resolve(handle)
+    assert len(puts) == 1
+    (qpack, sharding), = puts
+    assert isinstance(qpack, np.ndarray)
+    assert qpack.shape == (7, 2048) and qpack.dtype == np.int32
+    assert sharding.mesh == engine.mesh and sharding.spec == P()
+    _, outputs, meta = handle
+    assert isinstance(outputs, jax.Array) and outputs.dtype == np.int32
+    assert outputs.sharding.is_fully_replicated and len(outputs.devices()) == 4
+    island_cap = meta["island_cap"]
+    assert outputs.shape == (
+        1 + 2048 + island_cap * state.snapshot.K + 2048
+        + 2 * max(island_cap, 1) + N_LAUNCH_STATS,
+    )
+
+
+def test_the_demand_rule_records_nothing_on_a_mesh_launch(
+    daemons, cell, monkeypatch
+):
+    """The frontier's demand rule and its retry are one device's: a mesh
+    launch notes no demand and retries nothing, where the one-device
+    engine's same launch notes one."""
+    _, registry, one_daemon = daemons
+    _, wl, _ = cell
+    queries = wl.request(5)[0]
+    engine = registry.check_engine()
+    noted = []
+    monkeypatch.setattr(engine, "_note_demand", lambda B, d: noted.append(B))
+    monkeypatch.setattr(
+        engine, "_frontier_retry",
+        lambda *_: pytest.fail("a frontier retry on a mesh launch"),
+    )
+    engine.check_batch(queries)
+    assert noted == [] and engine._demand == {}
+    one_device = one_daemon.registry.check_engine()
+    one_device.check_batch(queries)
+    assert 2048 in one_device._demand
 
 
 def test_the_collective_bytes_are_counted_by_hand(bench_modules):

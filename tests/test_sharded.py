@@ -9,6 +9,7 @@ import pytest
 
 from keto_tpu.config import Config
 from keto_tpu.engine import Membership
+from keto_tpu.engine.kernel import unpack_results
 from keto_tpu.engine.tpu_engine import TPUCheckEngine
 from keto_tpu.ketoapi import RelationTuple
 from keto_tpu.namespace import Namespace
@@ -34,6 +35,62 @@ def make_mesh_engine(namespaces, tuples, max_depth=5, n_devices=8):
     m = MemoryManager()
     m.write_relation_tuples([RelationTuple.from_string(s) for s in tuples])
     return TPUCheckEngine(m, cfg, mesh=default_mesh(n_devices))
+
+
+def drive_smoke_case():
+    """chip_smoke's drive store at 20,000 tuples and its 2,048-item batch."""
+    import chip_smoke
+    from keto_tpu.storage.columnar import ColumnarStore
+
+    drive = chip_smoke.build_drive(chip_smoke.DEFAULT_SEED, 20_000)
+    store = ColumnarStore()
+    store.bulk_load(drive.cols)
+    queries, _ = chip_smoke.view_queries(drive, np.random.default_rng(1), 2048)
+    return store, chip_smoke.drive_config(serve=False), queries, 0
+
+
+def rewrite_case():
+    """test_islands_on_mesh_no_host_replay's store and queries: AND/NOT
+    islands allocated on the device."""
+    cfg = Config({"limit": {"max_read_depth": 100}})
+    cfg.set_namespaces(REWRITE_NAMESPACES)
+    m = MemoryManager()
+    m.write_relation_tuples([RelationTuple.from_string(s) for s in REWRITE_TUPLES])
+    return m, cfg, [RelationTuple.from_string(q) for q, _ in REWRITE_CASES], 100
+
+
+@pytest.mark.parametrize(
+    "case, islands",
+    [(drive_smoke_case, False), (rewrite_case, True)],
+    ids=["drive-smoke", "rewrite-islands"],
+)
+def test_packed_sharded_program_equals_packed_one_device_program(case, islands):
+    """The sharded check program's one result vector and
+    check_kernel_packed's, at the same statics (F 8,192), read back the
+    same n_isl, ctx_hit[:B] and needs_host, slot for slot."""
+    manager, cfg, queries, depth = case()
+    read = {}
+    for mesh in (None, default_mesh(4)):
+        engine = TPUCheckEngine(
+            manager, cfg, mesh=mesh, frontier_cap=8192, auto_frontier=False
+        )
+        handle = engine.check_batch_submit(queries, depth)
+        _, outputs, meta = handle
+        ctx_hit, needs_host, _, _, n_isl, _ = unpack_results(
+            np.asarray(outputs), meta["B"], meta["island_cap"],
+            meta["state"].snapshot.K,
+        )
+        engine.check_batch_resolve(handle)
+        read[mesh is None] = (
+            (meta["B"], meta["island_cap"], meta["launch_cap"], meta["step_cap"]),
+            n_isl, ctx_hit[: meta["B"]], needs_host,
+        )
+    (statics, n_isl, ctx_hit, needs_host) = read[True]
+    assert statics[2] == 8192
+    assert read[False][:2] == (statics, n_isl)
+    assert (n_isl > 0) == islands
+    np.testing.assert_array_equal(read[False][2], ctx_hit)
+    np.testing.assert_array_equal(read[False][3], needs_host)
 
 
 class TestShardedSnapshot:
@@ -436,6 +493,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 from keto_tpu.config import Config
 from keto_tpu.engine import Membership
+from keto_tpu.engine.kernel import unpack_results
 from keto_tpu.engine.tpu_engine import TPUCheckEngine
 from keto_tpu.ketoapi import RelationTuple
 from keto_tpu.namespace import Namespace

@@ -426,10 +426,14 @@ def test_sharded_kernel_compiles_for_four_chips(
 ):
     """The shard_map kernels of `chip_smoke.py --mesh4` on the described
     2x2 host: the sharded tables split over the mesh axis, everything else
-    replicated, and collectives present in what the compiler emits."""
+    replicated (the check's one packed [7, B] query array among it), and
+    collectives present in what the compiler emits."""
     args, kwargs = capture_launch(module, name, lambda: call(sharded_engine, drive))
     _, sharded_tables, replicated_tables, *queries = args
     assert all(v.shape[0] == 4 for v in sharded_tables.values())
+    if name == "sharded_check_kernel":
+        (qpack,) = queries
+        assert qpack.shape == (7, 2048) and qpack.dtype == np.int32
 
     def sharding_of(a):
         split = any(a is v for v in sharded_tables.values())
@@ -471,8 +475,8 @@ def test_sharded_check_program_fits_four_chips_at_the_cell(
         parallel_kernel, "sharded_check_kernel",
         lambda: sharded_engine.check_batch(smoke_batch(drive)),
     )
-    _, sharded_tables, replicated_tables, *queries = args
-    assert queries[0].shape == (2048,)
+    _, sharded_tables, replicated_tables, qpack = args
+    assert qpack.shape == (7, 2048)
     _, one_device = capture_launch(
         kernel, "check_kernel_packed", lambda: engine.check_batch(smoke_batch(drive))
     )
@@ -497,11 +501,11 @@ def test_sharded_check_program_fits_four_chips_at_the_cell(
         k: place(MESH4_REPLICATED_SHAPES.get(k, v.shape), v.dtype, P())
         for k, v in replicated_tables.items()
     }
-    described_queries = [place(q.shape, q.dtype, P()) for q in queries]
+    described_qpack = place(qpack.shape, qpack.dtype, P())
     program = parallel_kernel.get_sharded_kernel(
         four_chips, tuple(statics[k] for k in kernel._KERNEL_STATICS)
     )
-    compiled = program.lower(split, replicated, *described_queries).compile()
+    compiled = program.lower(split, replicated, described_qpack).compile()
     memory = compiled.memory_analysis()
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     hlo = compiled.as_text()
